@@ -28,9 +28,8 @@ with hard/soft/advisory counts — CI and humans parse the same line):
                its own try/except, so one bad row cannot take down the
                whole guard)
 
-Modes: ``hard`` exits 1 on any hard breach (pinned-jax CI leg),
-``soft`` prints breaches but exits 0 (latest-jax leg), ``off`` skips
-entirely.
+Modes: ``hard`` exits 1 on any hard breach (the CI default),
+``soft`` prints breaches but exits 0, ``off`` skips entirely.
 
   python -m benchmarks.compare bench_results.csv benchmarks/baseline.json \
       --mode hard

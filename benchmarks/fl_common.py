@@ -135,7 +135,8 @@ def run_fl(opt_name: str, task_id: str, *, alpha: Optional[float] = None,
             or comp_active) and opt_name == "delta_sgd":
         # pallas kernels on TPU; identical fused math via XLA elsewhere
         # (interpret-mode pallas in the round loop would distort timing)
-        flat = "pallas" if jax.default_backend() == "tpu" else "xla"
+        from repro.kernels import flat_backend
+        flat = flat_backend()
     rnd = jax.jit(make_fl_round(
         loss_fn, copt, sopt, num_rounds=rounds, weighted=weighted,
         flat=flat, scenario=scn, num_clients=num_clients,

@@ -308,7 +308,8 @@ def sharded(rounds=None):
 
     rng = np.random.default_rng(0)
     shape = (4, 2) if jax.device_count() >= 8 else (1, 1)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh(shape, ("data", "model"))
     spec = cross_device(mesh)
     D, C, K = 4096, 8, 4
 

@@ -13,9 +13,7 @@
 #                                   # conformance-artifacts/ for upload
 #
 # Knobs:
-#   BENCH_GUARD=hard|soft|off   benchmark guard mode (default hard) —
-#                               soft on the latest-jax CI leg, hard on
-#                               pinned (see .github/workflows/ci.yml)
+#   BENCH_GUARD=hard|soft|off   benchmark guard mode (default hard)
 #   PYTEST_ORDER_SEED=<n>       shuffled-order seed for the deflake leg
 #                               (conformance mode; default 1, CI passes
 #                               the run id so every run tries a fresh

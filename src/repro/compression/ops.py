@@ -38,10 +38,9 @@ def _kernels(backend: str, interpret: Optional[bool]):
     the fused kernels (interpret mode off-TPU), ``xla`` the pure-jnp
     oracle — identical math, which is what meshed/pjit callers use."""
     if backend == "pallas":
+        from repro.kernels import interpret_mode
         from repro.kernels.compress import compress as k
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        ip = interpret
+        ip = interpret_mode(interpret)
 
         def qdq(x):
             return k.dequantize_int8(*k.quantize_int8(x, interpret=ip),
@@ -87,7 +86,6 @@ def compress_flat_sharded(delta: jax.Array, spec: CompressionSpec, *,
     completes strictly before the client-mean psum."""
     from jax.sharding import PartitionSpec as PS
 
-    from repro.core.delta_sgd import _shard_map
     ca = pspec[0] if len(pspec) > 0 else None
     na = pspec[1] if len(pspec) > 1 else None
     buf, vec = PS(ca, na), PS(ca)
@@ -102,5 +100,6 @@ def compress_flat_sharded(delta: jax.Array, spec: CompressionSpec, *,
     if with_levels:
         ins.append(levels)
         specs.append(vec)
-    fn = _shard_map(local, mesh, tuple(specs), buf)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=buf, check_vma=False)
     return fn(*ins)

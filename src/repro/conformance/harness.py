@@ -248,7 +248,8 @@ class Harness:
     def _mesh_loops(self):
         from repro.core import make_fl_loop
         from repro.sharding.spec import FederationSpec
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         fed = FederationSpec(client_axes=("data",), fsdp_axes=(),
                              tp_axes=())
         kw = dict(params_like=self.params, num_rounds=self.num_rounds,

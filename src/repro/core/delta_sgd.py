@@ -24,9 +24,9 @@ Flat engine: ``FlatDeltaSGDState`` + ``flat_delta_sgd_step`` run the SAME
 rule for all C participating clients at once on packed ``(C, N)`` buffers
 (repro.core.flat) — two kernel launches per local step total, independent
 of leaf count and client count. ``backend="pallas"`` uses the batched
-Pallas kernels (interpret mode off-TPU); ``backend="xla"`` lowers the
-identical math through plain jnp on the flat buffers, which is what
-meshed/pjit callers use.
+Pallas kernels (interpret mode off-TPU, ``repro.kernels.interpret_mode``);
+``backend="xla"`` lowers the identical math through plain jnp on the
+flat buffers, which is what meshed/pjit callers use.
 
 Sharded flat engine: ``flat_delta_sgd_step_sharded`` is the mesh-native
 variant — the (C, N) buffer stays sharded per
@@ -244,9 +244,9 @@ def flat_delta_sgd_step(P: jax.Array, G: jax.Array,
     """
     first = (state.k == 0)
     if backend == "pallas":
+        from repro.kernels import interpret_mode
         from repro.kernels.delta_sgd import delta_sgd as k
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode(interpret)
         dg2, gg2 = k.batched_norms(G, state.prev_grads,
                                    interpret=interpret)
     else:
@@ -290,22 +290,6 @@ def _axis_names(entry):
     return tuple(entry) if isinstance(entry, tuple) else (entry,)
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map >= 0.6, experimental
-    before), with replication checking off — the Pallas kernels carry no
-    replication rules."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
-
-
 def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
                                 state: FlatDeltaSGDState, *, gamma: float,
                                 delta: float, eta0: float, mesh, pspec,
@@ -330,8 +314,9 @@ def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
     na = pspec[1] if len(pspec) > 1 else None
     na_names = _axis_names(na)
     buf, vec, rep = PS(ca, na), PS(ca), PS()
-    if backend == "pallas" and interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if backend == "pallas":
+        from repro.kernels import interpret_mode
+        interpret = interpret_mode(interpret)
     with_mask = mask is not None
     with_active = active is not None
 
@@ -387,8 +372,10 @@ def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
     if with_active:
         ins.append(active)
         specs.append(vec)
-    fn = _shard_map(local_step, mesh, tuple(specs),
-                    (buf, buf, vec, vec, vec, vec, vec))
+    # replication checking off: the Pallas kernels carry no rules for it
+    fn = jax.shard_map(local_step, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=(buf, buf, vec, vec, vec, vec, vec),
+                       check_vma=False)
     new_P, G_safe, eta, theta, grad_norm, valid, clips = fn(*ins)
     return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
                                     state.k + 1, valid, clips)

@@ -57,9 +57,8 @@ client-crossing collective is the per-round (N,) ``psum`` of the
 no full-precision per-client delta across client shards) hold on the
 block program, and per-client local math is bit-identical to the
 replicated engine (aggregation differs only by psum reassociation).
-Scenario draws for all R rounds are made ONCE at jit level, pinned
-replicated (partitioned threefry emits different bits per shard), and
-fed through the shard_map as replicated (R, C) operands.
+Scenario draws for all R rounds are made ONCE at jit level and fed
+through the shard_map as replicated (R, C) operands.
 
 Fleet loop (``make_fleet_loop``): the registered-vs-sampled split. A
 ``repro.federation.arena.ClientArena`` holds per-REGISTERED-client
@@ -92,9 +91,7 @@ class FlatFLState(NamedTuple):
     compression) is the packed (C, N) f32 reconstruction state.
     ``server_state`` and the async ``buffer`` keep their pytree form —
     the server's per-leaf dtypes are load-bearing for bit-exact
-    arithmetic, and the buffer's f32 delta tree is the known-good form
-    under SPMD meshes (partitioning a 1-D packed concatenate mis-
-    compiles on XLA CPU, see fed_round's ``pack1``).
+    arithmetic.
     """
     P: jax.Array
     server_state: Any
@@ -171,26 +168,21 @@ def make_fl_loop(loss_fn, client_opt, server_opt, *, params_like,
     leading axis of ``round_data`` (the tail block of a training run may
     be shorter).
 
-    State form (``loop_fn.state_form``): without a mesh the carry is the
-    persistent ``FlatFLState`` ("flat"). Under ``mesh``/``federation``
-    the scan carries the pytree ``FLState`` instead ("tree") and the
-    per-round flat conversions cancel inside each iteration: XLA CPU
-    SPMD mis-partitions a materialized 1-D packed concatenate
-    (jax<=0.4.37, see fed_round), so the (N,) carry cannot cross the
-    scan boundary under a mesh — the (C, N) round buffer, where the
-    real traffic lives, stays sharded either way (the HLO assertions
-    hold on the scanned computation).
+    State form (``loop_fn.state_form``): the carry is the persistent
+    ``FlatFLState`` ("flat"), with or without a mesh. Under
+    ``mesh``/``federation`` the (N,) params carry is sharded like the
+    flat dim of the (C, N) round buffer, which stays sharded inside the
+    scan (the HLO assertions hold on the scanned computation).
 
     ``block_sharded=True`` (requires ``mesh``/``federation`` in the
     client-axes-only regime, ``federation.flat_shards(mesh) == 1``):
     fold the whole R-round scan inside ONE shard_map instead of
     re-entering the mesh per kernel — see the module docstring. The
     carry is then the persistent ``FlatFLState`` ("flat" state form):
-    the (N,) flat params stay a plain replicated operand, so the 1-D
-    pack never meets the SPMD partitioner. Fault injection / robust
-    aggregation / quorum are not supported on the block path (their
-    order-statistic tails need cross-client data movement) — use the
-    per-round sharded engine for those.
+    the (N,) flat params stay a plain replicated operand. Fault
+    injection / robust aggregation / quorum are not supported on the
+    block path (their order-statistic tails need cross-client data
+    movement) — use the per-round sharded engine for those.
 
     ``telemetry`` (None/bool/repro.telemetry.TelemetrySpec): the
     in-scan distribution block rides the scanned metrics — extra
@@ -248,8 +240,6 @@ def make_fl_loop(loss_fn, client_opt, server_opt, *, params_like,
     shards = federation.flat_shards(mesh) if federation is not None else 1
     layout = flatlib.layout_of(params_like, shards=shards)
 
-    sharded = mesh is not None
-
     def loop_fn(carry, round_data, client_weights=None, arena=None):
         if gather is not None and arena is None:
             raise ValueError("this loop gathers batches from a staged "
@@ -259,12 +249,7 @@ def make_fl_loop(loss_fn, client_opt, server_opt, *, params_like,
             data, w_r = inp
             w_r = w_r if has_w else None
             batches = gather(arena, data) if gather is not None else data
-            if sharded:
-                st, metrics, _ = round_fn(st, batches,
-                                          client_weights=w_r)
-            else:
-                st, metrics, _ = body(st, batches, layout,
-                                      client_weights=w_r)
+            st, metrics, _ = body(st, batches, layout, client_weights=w_r)
             return st, metrics
 
         # scan xs must be arrays: a missing weight block rides along as
@@ -277,7 +262,7 @@ def make_fl_loop(loss_fn, client_opt, server_opt, *, params_like,
 
     loop_fn.layout = layout
     loop_fn.rounds_per_call = rounds_per_call
-    loop_fn.state_form = "tree" if sharded else "flat"
+    loop_fn.state_form = "flat"
     return loop_fn
 
 
@@ -298,15 +283,13 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
     to the replicated flat engine; the aggregate differs only by psum
     reassociation (<= ~1e-5 at f32, same tolerance the per-round
     sharded parity tests use). Scenario draws for all R rounds happen
-    ONCE at jit level, pinned replicated, and enter the shard_map as
-    replicated (R, C) operands — every shard sees the full vectors (for
-    wire accounting and FedBuff stats) and slices its local columns by
-    mesh position. The caller must jit ``loop_fn`` (the replication
-    pins need a jit context); donate_argnums=0 works as usual."""
-    from jax.sharding import NamedSharding, PartitionSpec as PS
+    ONCE at jit level and enter the shard_map as replicated (R, C)
+    operands — every shard sees the full vectors (for wire accounting
+    and FedBuff stats) and slices its local columns by mesh position.
+    Jit ``loop_fn``; donate_argnums=0 works as usual."""
+    from jax.sharding import PartitionSpec as PS
 
-    from repro.core.delta_sgd import (_shard_map, flat_delta_sgd_init,
-                                      flat_delta_sgd_step)
+    from repro.core.delta_sgd import flat_delta_sgd_init, flat_delta_sgd_step
     from repro.federation.heterogeneity import active_mask
     from repro.kernels.telemetry import lane_histogram_ref
     from repro.models.common import scan_unroll
@@ -363,26 +346,18 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
         C_loc = C // n_shards
         has_w = client_weights is not None
 
-        def rep(x):
-            # replicated pin: partitioned threefry (the default
-            # jax_threefry_partitionable=False) emits different bits per
-            # shard, so every scenario draw is forced replicated BEFORE
-            # it enters the shard_map
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, PS()))
-
         # all R rounds' scenario draws, once, at jit level
         r_idx = fstate.round + jnp.arange(R, dtype=jnp.int32)
         draws = {}
         if hetero:
-            draws["k"] = rep(jax.vmap(
-                lambda t: scenario.draw_step_counts(t, C, K))(r_idx))
+            draws["k"] = jax.vmap(
+                lambda t: scenario.draw_step_counts(t, C, K))(r_idx)
         if is_async:
-            draws["stale"] = rep(jax.vmap(
-                lambda t: scenario.draw_staleness(t, C))(r_idx))
+            draws["stale"] = jax.vmap(
+                lambda t: scenario.draw_staleness(t, C))(r_idx)
         if bw_hetero:
-            draws["lev"] = rep(jax.vmap(
-                lambda t: scenario.draw_compression_levels(t, C))(r_idx))
+            draws["lev"] = jax.vmap(
+                lambda t: scenario.draw_compression_levels(t, C))(r_idx)
         w = (client_weights if has_w
              else jnp.zeros((R, 0), jnp.float32))
 
@@ -596,15 +571,16 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                     jax.tree.map(lambda _: PS(), arena))
         # out_specs: exact state tree + a PS() prefix for the metrics
         # dict (everything psum'd/derived-from-replicated inside)
-        blk = _shard_map(block, mesh, in_specs, (fspec, PS()))
+        blk = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
+                            out_specs=(fspec, PS()), check_vma=False)
         new_fstate, metrics = blk(fstate, round_data, w, draws, arena)
 
         if num_clients is not None and scenario is not None:
             sch = scenario.make_scheduler(num_clients, C,
                                           sizes=client_sizes)
-            metrics["cohort_ids"] = rep(jax.vmap(
+            metrics["cohort_ids"] = jax.vmap(
                 lambda t: sch.sample(jax.random.key(scenario.seed), t)
-            )(r_idx))
+            )(r_idx)
         return new_fstate, metrics
 
     loop_fn.layout = layout

@@ -166,26 +166,36 @@ def _finish_round(state: FLState, agg, losses, etas,
 
 
 def _scenario_extras(scenario, round_idx, C, num_clients, client_sizes,
-                     step_counts, rep=lambda x: x):
-    """Cohort / effective-K metrics reported from inside the jitted round.
-
-    ``rep`` pins a draw to REPLICATED sharding under meshes: with
-    ``jax_threefry_partitionable=False`` (the default on the pinned jax)
-    a partitioned threefry emits different bits per shard, so any
-    scenario draw that may be sharded by propagation must be forced
-    replicated to agree with the host pipeline's draw."""
+                     step_counts):
+    """Cohort / effective-K metrics reported from inside the jitted round."""
     extra = {}
     if scenario is None:
         return extra
     if num_clients is not None:
         sch = scenario.make_scheduler(num_clients, C, sizes=client_sizes)
-        extra["cohort_ids"] = rep(sch.sample(
-            jax.random.key(scenario.seed), round_idx))
+        extra["cohort_ids"] = sch.sample(jax.random.key(scenario.seed),
+                                         round_idx)
     if step_counts is not None:
         sc = step_counts.astype(jnp.float32)
         extra.update(k_eff_mean=jnp.mean(sc), k_eff_min=jnp.min(sc),
                      k_eff_max=jnp.max(sc))
     return extra
+
+
+def _halving_mean(x):
+    """Mean over axis 0, summed by repeated halving: row i + row i+h.
+
+    The order is fixed by the program. A ``jnp.mean`` leaves it to
+    XLA:CPU, whose reduction emitter sums in a different order when the
+    int8 compression chain is fused into the reduction than when it is
+    not, so the per-round and the round-fused programs would round
+    apart."""
+    n = x.shape[0]
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        top = x[:h] + x[h:2 * h]
+        x = jnp.concatenate([top, x[2 * h:]]) if x.shape[0] % 2 else top
+    return x[0] / n
 
 
 def make_fl_round(loss_fn, client_opt: ClientOpt, server_opt: ServerOpt, *,
@@ -451,35 +461,22 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
             gp = flatlib.unpack(fstate.P, layout)
 
         def pack1(tree):
-            """Pytree -> (N,) f32 for the flat carry. The 1-D packed
-            concatenate stays UNCONSTRAINED: explicitly constraining it
-            (or routing through a batch-1 2-D concat) trips the XLA CPU
-            SPMD mis-partitioning (stride-shuffled buffer, jax<=0.4.37)
-            the round-start broadcast's comment documents; the plain
-            concat round-trips correctly under the mesh."""
-            return flatlib.pack(tree, layout)
+            """Pytree -> (N,) f32 for the flat carry, sharded like the
+            flat dim of the round buffer."""
+            return constrain(flatlib.pack(tree, layout), nspec)
         mask = flatlib.round_mask(layout)
         if mask is not None:
             mask = constrain(mask, nspec)
         leaves = jax.tree_util.tree_leaves(client_batches)
         C, K = leaves[0].shape[0], leaves[0].shape[1]
-        # scenario draws are constrained REPLICATED, not client-sharded:
-        # with jax_threefry_partitionable=False a partitioned threefry
-        # yields different bits per shard, which would make the sharded
-        # round disagree with the replicated engine and the host
-        # pipeline. The (C,) vectors are tiny; resharding at the
-        # shard_map boundary is free.
-        from jax.sharding import PartitionSpec as _PS
-        rep = (lambda x: constrain(x, _PS())) if sharded else (lambda x: x)
-        step_counts = (rep(scenario.draw_step_counts(fstate.round, C, K))
+        step_counts = (scenario.draw_step_counts(fstate.round, C, K)
                        if hetero else None)
         # fault lanes (repro.federation.faults): one deterministic draw
-        # per round off axis 4 of the round key, replicated like every
-        # other scenario draw. Drops fold into the SAME per-step lane
-        # mask heterogeneous K uses — a dropped client simply runs out
-        # of budget at its drop step — so the scan stays fixed-shape and
-        # the step stays at two kernel launches.
-        lanes = (jax.tree.map(rep, scenario.draw_faults(fstate.round, C, K))
+        # per round off axis 4 of the round key. Drops fold into the
+        # SAME per-step lane mask heterogeneous K uses — a dropped client
+        # simply runs out of budget at its drop step — so the scan stays
+        # fixed-shape and the step stays at two kernel launches.
+        lanes = (scenario.draw_faults(fstate.round, C, K)
                  if faults_on else None)
         drops_on = faults_on and fm.drop_rate > 0.0
         if drops_on:
@@ -493,17 +490,8 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
 
         # broadcast the round-start params to the client axis; the carry
         # is already flat, so no per-round pytree re-pack happens here
-        if sharded:
-            # broadcast leaves FIRST, then pack via the 2-D batched
-            # concatenate: constraining a 1-D packed concatenate trips an
-            # XLA CPU SPMD mis-partitioning (stride-shuffled buffer,
-            # jax<=0.4.37); the (C, N) axis-1 concatenate partitions
-            # correctly and is what the round materializes anyway.
-            bcast = jax.tree.map(
-                lambda l: jnp.broadcast_to(l[None], (C,) + l.shape), gp)
-            P = constrain(flatlib.pack_batched(bcast, layout), pspec)
-        else:
-            P = jnp.broadcast_to(fstate.P[None], (C, layout.padded_size))
+        P = constrain(jnp.broadcast_to(fstate.P[None],
+                                       (C, layout.padded_size)), pspec)
         P_start = P if (is_async or comp is not None or guard_tail) \
             else None
         S = flat_delta_sgd_init(C, layout, eta0=eta0, theta0=theta0)
@@ -549,7 +537,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
         losses = losses.T  # (K, C) -> (C, K), same layout as vmap engine
 
         extra = _scenario_extras(scenario, fstate.round, C, num_clients,
-                                 client_sizes, step_counts, rep=rep)
+                                 client_sizes, step_counts)
         # numerical-guard telemetry (always on for the flat engines):
         # how often η hit the ETA_CLAMP ceiling, and what fraction of
         # lanes the NaN guard dropped this round
@@ -566,8 +554,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
             # counts are exact integers either way.
             extra.update(round_telemetry(
                 tele, S.eta, losses, S.clips, S.valid, backend=backend,
-                use_kernel=(backend == "pallas" and not sharded),
-                rep=rep))
+                use_kernel=(backend == "pallas" and not sharded)))
 
         # survivor mask + byzantine factor for the fault/robust tails:
         # a client is excluded when its NaN guard latched, it dropped
@@ -592,7 +579,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
         if comp is not None:
             from repro.compression.ops import (compress_flat,
                                                compress_flat_sharded)
-            levels = (rep(scenario.draw_compression_levels(fstate.round, C))
+            levels = (scenario.draw_compression_levels(fstate.round, C)
                       if bw_hetero else None)
             delta = P - P_start
             if byz is not None:
@@ -652,6 +639,8 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                 w = client_weights / jnp.sum(client_weights)
                 agg_flat = jnp.tensordot(w.astype(jnp.float32), P_agg,
                                          axes=(0, 0))
+            elif comp is not None and not sharded:
+                agg_flat = _halving_mean(P_agg)
             else:
                 agg_flat = jnp.mean(P_agg, axis=0)
             agg = flatlib.unpack(constrain(agg_flat, nspec), layout)
@@ -735,7 +724,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
             # re-enter the flat carry.
             from repro.federation.buffer import (buffer_merge, buffer_step,
                                                  staleness_weights)
-            stale = rep(scenario.draw_staleness(fstate.round, C))
+            stale = scenario.draw_staleness(fstate.round, C)
             w = staleness_weights(stale, scenario.staleness_exp)
             if weighted and client_weights is not None:
                 w = w * client_weights.astype(jnp.float32)
@@ -769,7 +758,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                                                  staleness_weights)
             from repro.federation.faults import (robust_aggregate,
                                                  robust_aggregate_sharded)
-            stale = rep(scenario.draw_staleness(fstate.round, C))
+            stale = scenario.draw_staleness(fstate.round, C)
             if faults_on and fm.overstale_rate > 0.0:
                 stale = jnp.where(lanes.overstale,
                                   jnp.int32(fm.overstale), stale)

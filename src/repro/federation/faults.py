@@ -206,10 +206,10 @@ def robust_aggregate(delta, spec: RobustAgg, valid=None, *,
     if spec.kind in ("trimmed", "median"):
         t = spec.trim_count(C)
         if backend == "pallas":
+            from repro.kernels import interpret_mode
             from repro.kernels.robust_agg import robust_agg as k
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
-            agg = k.batched_trimmed_mean(zeroed, t, interpret=interpret)
+            agg = k.batched_trimmed_mean(zeroed, t,
+                                         interpret=interpret_mode(interpret))
         else:
             agg = _sorted_window_mean(zeroed, t)
         return agg, info
@@ -237,7 +237,7 @@ def robust_aggregate_sharded(delta, spec: RobustAgg, valid, *, mesh,
     data ever crosses the client shard boundary. Returns
     ((N,) delta, info dict)."""
     from jax.sharding import PartitionSpec as PS
-    from repro.core.delta_sgd import _axis_names, _shard_map
+    from repro.core.delta_sgd import _axis_names
     ca = pspec[0] if len(pspec) > 0 else None
     na = pspec[1] if len(pspec) > 1 else None
     c_names, na_names = _axis_names(ca), _axis_names(na)
@@ -272,7 +272,8 @@ def robust_aggregate_sharded(delta, spec: RobustAgg, valid, *, mesh,
 
     ins = [delta, valid] + ([weights] if with_w else [])
     specs = [PS(ca, na), PS(ca)] + ([PS(ca)] if with_w else [])
-    fn = _shard_map(local, mesh, tuple(specs), (PS(na), PS()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=(PS(na), PS()), check_vma=False)
     agg, clip_rate = fn(*ins)
     info = {}
     if spec.kind == "clip":

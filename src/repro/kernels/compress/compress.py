@@ -14,10 +14,11 @@ boundaries:
   dequantize_int8 — the server-side inverse, one pass.
   topk_mask       — magnitude top-k sparsification with a THRESHOLD
                     pass (no host gather): per chunk the k-th largest
-                    |x| is found by an in-register sort, then a
-                    vectorized keep-mask with first-index tie-break
-                    retains exactly k slots. Wire cost per chunk:
-                    k x (4 + 1) bytes (value + lane index).
+                    |x| is found by bisection on counts over its f32 bit
+                    pattern (Mosaic has no sort), then a vectorized
+                    keep-mask with first-index tie-break retains exactly
+                    k slots. Wire cost per chunk: k x (4 + 1) bytes
+                    (value + lane index).
 
 All three ops are chunk-local (chunk = one row of LANES consecutive
 elements), so a per-shard slab of the flat dim — a whole number of
@@ -27,9 +28,10 @@ under ``shard_map`` no cross-device traffic is ever generated.
 Launch-count math, per round: int8 costs exactly 2 launches
 (quantize + dequantize), top-k exactly 1, independent of leaf count,
 client count, and K — the Δ-SGD step pair (2/step) is untouched.
-Like the delta_sgd kernels, everything runs in interpret mode
-off-TPU, and ``repro.kernels.compress.ref`` is the pure-jnp oracle
-(used directly by the ``backend="xla"`` path of meshed callers).
+Like the delta_sgd kernels, everything runs in interpret mode off-TPU
+(``repro.kernels.interpret_mode``), and ``repro.kernels.compress.ref``
+is the pure-jnp oracle (used directly by the ``backend="xla"`` path of
+meshed callers).
 """
 from __future__ import annotations
 
@@ -67,28 +69,63 @@ def _grid_shapes(n: int):
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)              # (1, rows, LANES)
-    absmax = jnp.max(jnp.abs(x), axis=-1)           # (1, rows)
+    absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)   # (1, rows, 1)
     s_ref[...] = absmax / 127.0
     inv = jnp.where(absmax > 0.0, 127.0 / absmax, 0.0)
-    q = jnp.clip(jnp.round(x * inv[..., None]), -127.0, 127.0)
+    q = jnp.clip(jnp.round(x * inv), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
 
 
 def _dequantize_kernel(q_ref, s_ref, out_ref):
     q = q_ref[...].astype(jnp.float32)
-    out_ref[...] = q * s_ref[...][..., None]
+    # the same select as the ref: q * scale, never fused into an FMA
+    out_ref[...] = jnp.where(q != 0.0, q * s_ref[...], 0.0)
+
+
+def kth_smallest_key(keys, rank: int, *, bits: int = 32, axis=-1):
+    """``rank``-th smallest (0-based) of int32 order keys over ``axis``
+    (kept as size-1 dims), by bisection on counts: the smallest v with
+    ``count(keys <= v) > rank``. Sort-free, so it lowers on Mosaic; the
+    result is exact, hence equal to indexing a sorted copy. ``bits=31``
+    when every key is nonnegative."""
+    axes = (axis,) if isinstance(axis, int) else tuple(axis)
+    axes = tuple(a % keys.ndim for a in axes)
+    shape = tuple(1 if d in axes else n for d, n in enumerate(keys.shape))
+    lo = jnp.full(shape, 0 if bits == 31 else -2 ** 31, jnp.int32)
+    hi = jnp.full(shape, 2 ** 31 - 1, jnp.int32)
+
+    def body(_, lh):
+        lo, hi = lh
+        mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)   # floor avg, no overflow
+        n = jnp.sum((keys <= mid).astype(jnp.int32), axis=axes,
+                    keepdims=True)
+        up = n > rank
+        return jnp.where(up, lo, mid + 1), jnp.where(up, mid, hi)
+
+    lo, _ = jax.lax.fori_loop(0, bits, body, (lo, hi))
+    return lo
 
 
 def _topk_kernel(x_ref, out_ref, *, k: int):
-    x = x_ref[...].astype(jnp.float32)
+    x = x_ref[0].astype(jnp.float32)                # (rows, LANES)
     a = jnp.abs(x)
-    thr = jnp.sort(a, axis=-1)[..., LANES - k]      # (1, rows)
-    greater = a > thr[..., None]
-    n_greater = jnp.sum(greater, axis=-1, keepdims=True)
-    eq = a == thr[..., None]
-    eq_rank = jnp.cumsum(eq.astype(jnp.int32), axis=-1)
-    keep = greater | (eq & (eq_rank <= (k - n_greater)))
-    out_ref[...] = jnp.where(keep, x, 0.0)
+    # |x| >= 0, so its f32 bit pattern orders like the value (NaN last,
+    # as in a sort)
+    key = jax.lax.bitcast_convert_type(a, jnp.int32)
+    thr = jax.lax.bitcast_convert_type(
+        kth_smallest_key(key, LANES - k, bits=31), jnp.float32)
+    greater = a > thr
+    n_greater = jnp.sum(greater.astype(jnp.int32), axis=-1, keepdims=True)
+    eq = a == thr
+    # inclusive prefix count of ties along the lanes, as a matmul with an
+    # upper-triangular ones matrix (0/1 inputs: exact at any precision)
+    r = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    eq_rank = jax.lax.dot_general(
+        eq.astype(jnp.float32), (r <= c).astype(jnp.float32),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    keep = greater | (eq & (eq_rank <= (k - n_greater).astype(jnp.float32)))
+    out_ref[0] = jnp.where(keep, x, 0.0)
 
 
 def quantize_int8(x: jax.Array, *, interpret: bool = False):
@@ -106,12 +143,12 @@ def quantize_int8(x: jax.Array, *, interpret: bool = False):
         grid=(C, blocks),
         in_specs=[pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0))],
         out_specs=[pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0)),
-                   pl.BlockSpec((1, rows), lambda c, j: (c, j))],
+                   pl.BlockSpec((1, rows, 1), lambda c, j: (c, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((C, m, LANES), jnp.int8),
-                   jax.ShapeDtypeStruct((C, m), jnp.float32)],
+                   jax.ShapeDtypeStruct((C, m, 1), jnp.float32)],
         interpret=interpret,
     )(x3)
-    return q.reshape(C, n), s
+    return q.reshape(C, n), s.reshape(C, m)
 
 
 def dequantize_int8(q: jax.Array, scales: jax.Array, *,
@@ -125,11 +162,11 @@ def dequantize_int8(q: jax.Array, scales: jax.Array, *,
         _dequantize_kernel,
         grid=(C, blocks),
         in_specs=[pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0)),
-                  pl.BlockSpec((1, rows), lambda c, j: (c, j))],
+                  pl.BlockSpec((1, rows, 1), lambda c, j: (c, j, 0))],
         out_specs=pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0)),
         out_shape=jax.ShapeDtypeStruct((C, m, LANES), jnp.float32),
         interpret=interpret,
-    )(q3, scales)
+    )(q3, scales.reshape(C, m, 1))
     return out.reshape(C, n)
 
 

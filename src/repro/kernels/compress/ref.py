@@ -41,8 +41,12 @@ def quantize_int8_ref(x: jnp.ndarray):
 
 
 def dequantize_int8_ref(q: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
-    q3 = _chunked(q)
-    return (q3.astype(jnp.float32) * scales[..., None]).reshape(q.shape)
+    """q * scale. The per-element select keeps XLA:CPU from contracting
+    the product into an FMA with whatever consumes it (the EF21 add):
+    whether it does depends on what else shares the fusion, so the same
+    round compiled in two programs would otherwise round apart."""
+    q3 = _chunked(q).astype(jnp.float32)
+    return jnp.where(q3 != 0.0, q3 * scales[..., None], 0.0).reshape(q.shape)
 
 
 def topk_mask_ref(x: jnp.ndarray, k: int) -> jnp.ndarray:

@@ -13,7 +13,8 @@ exact multiple of the row-block. The kernel pair runs a 2-D grid over
 
   batched_norms  — ONE HBM pass over (G, G_prev) producing BOTH partial
                    sums per block, accumulated across the sequential
-                   row-block grid axis into per-client (C, 1, 1) outputs.
+                   row-block grid axis into per-client (C,) outputs held
+                   in SMEM (a TPU stores scalars there, not in VMEM).
                    No vmap, no per-leaf loop: the client axis is a grid
                    dimension, so the kernel is vmap-free by construction.
   batched_apply  — P ← P − η_c·G with per-client η, tiled through VMEM;
@@ -43,6 +44,7 @@ from collections import Counter
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # single source of truth for the tile geometry: the packer pads layouts
 # to exactly these block sizes, so both modules must agree
@@ -65,7 +67,13 @@ def launch_count() -> int:
 # packed (C, N) kernels — one launch per op for all leaves and all clients
 # --------------------------------------------------------------------------
 
+# Scalar results live in SMEM as whole (C,) arrays: a TPU cannot store a
+# scalar to VMEM, and a (1, 1) VMEM block breaks the (8, 128) tiling.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def _batched_norms_kernel(g_ref, gp_ref, dg_ref, gg_ref):
+    c = pl.program_id(0)
     j = pl.program_id(1)  # row-block axis: sequential, innermost
     g = g_ref[...].astype(jnp.float32)
     gp = gp_ref[...].astype(jnp.float32)
@@ -73,11 +81,11 @@ def _batched_norms_kernel(g_ref, gp_ref, dg_ref, gg_ref):
 
     @pl.when(j == 0)
     def _init():
-        dg_ref[0, 0, 0] = 0.0
-        gg_ref[0, 0, 0] = 0.0
+        dg_ref[c] = 0.0
+        gg_ref[c] = 0.0
 
-    dg_ref[0, 0, 0] += jnp.sum(d * d)
-    gg_ref[0, 0, 0] += jnp.sum(g * g)
+    dg_ref[c] += jnp.sum(d * d)
+    gg_ref[c] += jnp.sum(g * g)
 
 
 def _batched_apply_kernel(eta_ref, p_ref, g_ref, out_ref):
@@ -125,13 +133,12 @@ def batched_norms(g: jax.Array, g_prev: jax.Array, *,
         grid=(C, blocks),
         in_specs=[pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0)),
                   pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0))],
-        out_specs=[pl.BlockSpec((1, 1, 1), lambda c, j: (c, 0, 0)),
-                   pl.BlockSpec((1, 1, 1), lambda c, j: (c, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((C, 1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((C, 1, 1), jnp.float32)],
+        out_specs=[_SMEM, _SMEM],
+        out_shape=[jax.ShapeDtypeStruct((C,), jnp.float32),
+                   jax.ShapeDtypeStruct((C,), jnp.float32)],
         interpret=interpret,
     )(g3, gp3)
-    return dg[:, 0, 0], gg[:, 0, 0]
+    return dg, gg
 
 
 def batched_apply(p: jax.Array, g: jax.Array, eta: jax.Array, *,
@@ -189,11 +196,11 @@ def _norms_kernel(g_ref, gp_ref, dg_ref, gg_ref):
 
     @pl.when(i == 0)
     def _init():
-        dg_ref[0, 0] = 0.0
-        gg_ref[0, 0] = 0.0
+        dg_ref[0] = 0.0
+        gg_ref[0] = 0.0
 
-    dg_ref[0, 0] += dg
-    gg_ref[0, 0] += gg
+    dg_ref[0] += dg
+    gg_ref[0] += gg
 
 
 def _apply_kernel(eta_ref, p_ref, g_ref, out_ref):
@@ -231,13 +238,12 @@ def norms(g: jax.Array, g_prev: jax.Array, *, interpret: bool = False):
         grid=(grid,),
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
                   pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        out_specs=[_SMEM, _SMEM],
+        out_shape=[jax.ShapeDtypeStruct((1,), jnp.float32),
+                   jax.ShapeDtypeStruct((1,), jnp.float32)],
         interpret=interpret,
     )(g2, gp2)
-    return dg[0, 0], gg[0, 0]
+    return dg[0], gg[0]
 
 
 def apply_update(p: jax.Array, g: jax.Array, eta, *,

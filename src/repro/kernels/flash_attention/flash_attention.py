@@ -1,6 +1,10 @@
 """Flash attention for TPU (Pallas): causal GQA with optional sliding
 window, online-softmax accumulation over KV blocks.
 
+The kernel works head-major, on (B, H, S, hd): the wrapper transposes
+in and out, so each block is a (block, hd) tile whose last two dims
+meet the TPU's (8, 128) tiling rule (hd is the whole last dim).
+
 Grid: (B, H, num_q_blocks, num_kv_blocks). TPU executes the grid
 sequentially with the last dim innermost, so the (m, l, acc) running state
 for one (b, h, qi) lives in VMEM scratch across the kv sweep:
@@ -25,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -42,9 +48,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                 # (bq, hd)
+    k = k_ref[...].astype(jnp.float32)                 # (bk, hd)
+    v = v_ref[...].astype(jnp.float32)
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -74,7 +80,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == nk - 1)
     def _final():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -83,8 +89,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: int = DEFAULT_BLOCK_K,
                     interpret: Optional[bool] = None) -> jax.Array:
     """q: (B,S,H,hd), k/v: (B,T,KV,hd) -> (B,S,H,hd)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -104,20 +109,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     kernel = functools.partial(_fa_kernel, causal=causal, window=window,
                                block_q=block_q, block_k=block_k, nk=nk)
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))   # head-major
     out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((None, None, block_q, hd),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sp, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, hd),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
@@ -125,4 +131,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :S]
+    return jnp.swapaxes(out, 1, 2)[:, :S]

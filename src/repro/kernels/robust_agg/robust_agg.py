@@ -10,12 +10,13 @@ over row blocks.
 
 The sort is a BITONIC NETWORK along the client axis: C is padded to the
 next power of two with +inf rows (which sort past every real value, so
-the window [t, C−t) never sees them) and each compare-exchange stage is
-a vectorized ``jnp.minimum``/``jnp.maximum`` pair over a static reshape
-— no ``lax.sort``, no gathers, nothing Mosaic can't lower. For
-fleet-scale C the network costs O(log² C) vector passes over a block
-that is already resident in VMEM, so the kernel stays HBM-bound like
-the rest of the flat engine.
+the window [t, C−t) never sees them) and each compare-exchange is a
+``jnp.minimum``/``jnp.maximum`` pair between two whole (rows, LANES)
+client tiles, wired statically in Python — no ``lax.sort``, no gathers,
+no reshapes, nothing Mosaic can't lower. For fleet-scale C the network
+costs O(log² C) vector passes over a block that is already resident in
+VMEM, so the kernel stays HBM-bound like the rest of the flat engine.
+The row block shrinks as C grows so the block stays within VMEM.
 
 ``ref.py`` carries the ``jnp.sort`` oracle the kernel is parity-tested
 against.
@@ -44,27 +45,22 @@ def launch_count() -> int:
     return sum(LAUNCHES.values())
 
 
-def _bitonic_sort_axis0(x: jax.Array) -> jax.Array:
-    """Ascending bitonic sort along axis 0 (length must be a power of
-    two). Every stage is a static reshape + min/max compare-exchange —
-    the direction bit of a pair only depends on bits ABOVE the stage
-    stride, so it broadcasts from the leading group axis."""
-    P2 = x.shape[0]
-    tail = x.shape[1:]
+def _bitonic_sort(tiles: list) -> list:
+    """Ascending bitonic sort of a power-of-two list of equal-shape
+    tiles, elementwise: after it, ``out[i]`` holds the i-th smallest of
+    the inputs at every position."""
+    x = list(tiles)
+    n = len(x)
     k = 2
-    while k <= P2:
+    while k <= n:
         s = k // 2
         while s >= 1:
-            groups = P2 // (2 * s)
-            y = x.reshape((groups, 2, s) + tail)
-            lo, hi = y[:, 0], y[:, 1]
-            mn = jnp.minimum(lo, hi)
-            mx = jnp.maximum(lo, hi)
-            base = jnp.arange(groups) * (2 * s)
-            asc = ((base & k) == 0).reshape((groups,) + (1,) * (1 + len(tail)))
-            first = jnp.where(asc, mn, mx)
-            second = jnp.where(asc, mx, mn)
-            x = jnp.stack([first, second], axis=1).reshape((P2,) + tail)
+            for i in range(n):
+                j = i ^ s
+                if j > i:
+                    lo, hi = x[i], x[j]
+                    mn, mx = jnp.minimum(lo, hi), jnp.maximum(lo, hi)
+                    x[i], x[j] = (mn, mx) if (i & k) == 0 else (mx, mn)
             s //= 2
         k *= 2
     return x
@@ -79,21 +75,32 @@ def _next_pow2(c: int) -> int:
 
 def _make_trimmed_kernel(c: int, t: int):
     def kernel(x_ref, out_ref):
-        xs = _bitonic_sort_axis0(x_ref[...].astype(jnp.float32))
+        xs = _bitonic_sort([x_ref[i] for i in range(x_ref.shape[0])])
         # pad rows are +inf and sort past index c−1; the surviving
         # window [t, c−t) is all real values
-        win = xs[t:c - t]
-        out_ref[...] = jnp.sum(win, axis=0) / jnp.float32(c - 2 * t)
+        acc = xs[t]
+        for w in xs[t + 1:c - t]:
+            acc = acc + w
+        out_ref[...] = acc / jnp.float32(c - 2 * t)
     return kernel
 
 
-def _grid_shapes(n: int):
+# bytes of one (P2, rows, LANES) f32 input block; Pallas double-buffers
+# it, and the scoped VMEM default on v5e is 16 MiB
+_BLOCK_BYTES = 4 * 2 ** 20
+
+
+def _grid_shapes(n: int, clients: int):
     """(M, rows, blocks) for a lane-aligned flat length n — same
-    geometry contract as the Δ-SGD kernels (FlatLayout pre-pads)."""
+    geometry contract as the Δ-SGD kernels (FlatLayout pre-pads), with
+    the row block halved while ``clients`` tiles of it exceed
+    _BLOCK_BYTES."""
     assert n % LANES == 0, f"flat length {n} not lane-aligned"
     m = n // LANES
     rows = min(BLOCK_ROWS, m)
     assert m % rows == 0, f"flat length {n} not row-block aligned"
+    while clients * rows * LANES * 4 > _BLOCK_BYTES and rows % 16 == 0:
+        rows //= 2
     return m, rows, m // rows
 
 
@@ -108,8 +115,8 @@ def batched_trimmed_mean(x: jax.Array, t: int, *,
     C, n = x.shape
     if not 0 <= 2 * t < C:
         raise ValueError(f"trim count {t} leaves no window for C={C}")
-    m, rows, blocks = _grid_shapes(n)
     P2 = _next_pow2(C)
+    m, rows, blocks = _grid_shapes(n, P2)
     x3 = x.astype(jnp.float32).reshape(C, m, LANES)
     if P2 > C:
         x3 = jnp.concatenate(

@@ -11,8 +11,10 @@ summary cheap enough to ride inside the round-fused ``lax.scan``:
                   nowhere) to a (rows, LANES) tile and every bin's
                   [lo, hi) band is summed in one VMEM pass.
   lane_quantiles  (C,) values -> (Q,) order statistics (min, deciles,
-                  max at Q=11). One launch: pad with +inf, one in-VMEM
-                  sort, static nearest-rank gather.
+                  max at Q=11). One launch: pad with +inf, then each
+                  static nearest-rank order statistic is found by
+                  bisection on counts over an order-preserving int32 key
+                  of the f32 values (Mosaic has no sort).
 
 Launch accounting mirrors ``kernels/delta_sgd``: a module-level
 ``LAUNCHES`` counter incremented per ``pallas_call`` built, with its
@@ -31,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.flat import LANES
+from repro.kernels import interpret_mode
+from repro.kernels.compress.compress import kth_smallest_key
 
 from .ref import quantile_indices
 
@@ -81,8 +85,7 @@ def lane_histogram(x: jax.Array, edges, *,
     exact integers in f32: bit-identical to the ref and stable under
     cross-shard psum.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     e = jnp.asarray(edges, jnp.float32).reshape(1, -1)
     B = e.shape[1] - 1
     x2 = _pad_rows(x, float("nan"))
@@ -106,19 +109,27 @@ def lane_quantiles(x: jax.Array, Q: int = 11, *,
     spaced quantile fractions (min, deciles, max for Q=11).
 
     ONE pallas launch: +inf padding keeps the real values in the first
-    C sorted slots, so the static nearest-rank gather is exact. Finite
-    inputs only (NaNs sort after +inf and can displace top quantiles).
+    C order statistics, so the static nearest-rank selection is exact.
+    Finite inputs only (NaNs sort after +inf and can displace top
+    quantiles).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     C = x.shape[0]
     idx = quantile_indices(C, Q)        # static python ints
     x2 = _pad_rows(x, float("inf"))
     rows = x2.shape[0]
 
     def _quantile_kernel(x_ref, out_ref):
-        xs = jnp.sort(x_ref[...].reshape(-1))
-        out_ref[...] = jnp.stack([xs[i] for i in idx]).reshape(1, -1)
+        b = jax.lax.bitcast_convert_type(x_ref[...], jnp.int32)
+        # order-preserving key: flip the magnitude bits of negatives
+        key = b ^ ((b >> 31) & 0x7FFFFFFF)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
+        out = jnp.zeros((1, Q), jnp.int32)
+        for q, r in enumerate(idx):
+            kq = kth_smallest_key(key, r, axis=(0, 1))       # (1, 1)
+            out = jnp.where(lane == q, kq, out)
+        out = out ^ ((out >> 31) & 0x7FFFFFFF)                # key -> bits
+        out_ref[...] = jax.lax.bitcast_convert_type(out, jnp.float32)
 
     LAUNCHES["lane_quantiles"] += 1
     out = pl.pallas_call(

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro import roofline
 from repro.configs import ARCH_IDS, FLConfig, INPUT_SHAPES, get_config
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.specs import (decode_specs, decode_window, federation_kind,
                                 prefill_specs, train_specs)
 from repro.launch.steps import (abstract_fl_state, make_prefill_step,
@@ -171,7 +171,7 @@ def _compile_step(cfg, shape, mesh, spec, fl, *, unroll, remat,
                 raise ValueError("rounds_per_call > 1 on a mesh requires "
                                  "the sharded flat engine (flat_fed=True, "
                                  "flat_sharded=True): the mesh-form loop "
-                                 "carries the tree FLState whose "
+                                 "carries the flat state whose "
                                  "shardings this driver derives")
             from repro.launch.steps import make_train_loop
             loop, sopt, scn, comp = make_train_loop(
@@ -181,9 +181,11 @@ def _compile_step(cfg, shape, mesh, spec, fl, *, unroll, remat,
                 federation=spec if flat_sharded else None,
                 scenario=scenario, compression=compression)
             C = clients or spec.clients_on(mesh)
-            # under a mesh the fused loop carries the tree-form FLState
-            # (fed_loop.state_form) — the single-round state shardings
-            # apply verbatim; batches just gain the leading R axis
+            # the fused loop carries the FlatFLState: the (N,) params
+            # and the (C, N) EF21 slab shard like the round buffer, the
+            # rest keeps the single-round state shardings; batches just
+            # gain the leading R axis
+            from repro.core import flatten_fl_state
             state_struct = abstract_fl_state(model, sopt, scn, comp, C)
             R = rounds_per_call
             round_batch = train_specs(model, shape, fl, C)
@@ -198,9 +200,18 @@ def _compile_step(cfg, shape, mesh, spec, fl, *, unroll, remat,
                 is_leaf=lambda x: isinstance(x, NamedSharding))
             analytic = analytic_memory(cfg, shape, spec, mesh,
                                        state_struct.params, param_sh, fl)
-            lowered = jax.jit(loop, in_shardings=(state_sh, batch_sh),
+            flat_struct = jax.eval_shape(
+                lambda s: flatten_fl_state(s, loop.layout), state_struct)
+            pspec = spec.flat_spec(mesh)
+            flat_sh = flat_struct._replace(
+                P=NamedSharding(mesh, P(pspec[1])),
+                server_state=state_sh.server_state, round=state_sh.round,
+                buffer=state_sh.buffer,
+                ef=(NamedSharding(mesh, pspec)
+                    if flat_struct.ef is not None else None))
+            lowered = jax.jit(loop, in_shardings=(flat_sh, batch_sh),
                               donate_argnums=0
-                              ).lower(state_struct, batch)
+                              ).lower(flat_struct, batch)
         elif shape.kind == "train":
             step, sopt, scn, comp = make_train_step(
                 model, fl, use_pallas=use_pallas, remat=remat, flat=flat_fed,
@@ -367,7 +378,7 @@ def scenario_smoke(verbose: bool = True):
 
     cfg = get_config("tinyllama-1.1b").reduced(num_layers=2, d_model=256)
     shape = ShapeConfig("train_smoke", "train", 256, 8)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     fl = FLConfig(local_steps=2, flat_engine=True)
     model = build_model(cfg, jnp.bfloat16)

@@ -99,7 +99,8 @@ def _row_extras(cfg, rng):
 def run(args) -> dict:
     """Serve one batch (or a load-gen stream); returns {"tokens":
     (B, gen) int32 array, "tok_per_s": float, "ckpt_step": int | None,
-    "metrics": engine counters, "report": load-gen report | None}."""
+    "metrics": engine counters, "report": load-gen report | None,
+    "completed": every Completion, load-gen requests first}."""
     from repro.serving import (DecodeEngine, ModelRegistry,
                                PersonalizationStore, Workload, run_load)
 
@@ -163,7 +164,8 @@ def run(args) -> dict:
                       personalized_frac=0.25 if store else 0.0,
                       client_ids=tuple(store.client_ids()) if store
                       else (0,), seed=args.seed)
-        report = run_load(engine, wl, cfg.vocab_size)
+        report = run_load(engine, wl, cfg.vocab_size,
+                          extras=_row_extras(cfg, rng))
         print(f"loadgen: {report['requests']} requests, "
               f"{report['tok_per_s']:.1f} tok/s, "
               f"p50 {report['p50_s'] * 1e3:.1f}ms "
@@ -187,11 +189,15 @@ def run(args) -> dict:
         events.close()
     return {"tokens": toks, "tok_per_s": gen * B / max(dt, 1e-9),
             "ckpt_step": ckpt_step, "metrics": engine.metrics(),
-            "report": report, "history": engine.history}
+            "report": report, "history": engine.history,
+            "completed": engine.completed}
 
 
 def main():
-    run(build_parser().parse_args())
+    args = build_parser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -113,9 +113,7 @@ def make_train_loop(model: Model, fl: FLConfig, *, num_rounds: int = 1000,
     is REQUIRED (the loop carries the packed flat state), so
     ``fl.client_opt`` must be ``delta_sgd``. Returns
     (train_loop, sopt, scenario, compression); the loop exposes
-    ``.layout`` (for flatten/unflatten at block boundaries) and
-    ``.state_form`` ("flat", or "tree" under meshes — see
-    core.fed_loop). Jit the loop with ``donate_argnums=0`` so the
+    ``.layout`` (for flatten/unflatten at block boundaries). Jit the loop with ``donate_argnums=0`` so the
     carried buffers update in place.
     """
     if fl.client_opt != "delta_sgd":

@@ -70,6 +70,7 @@ from repro.core import (get_client_opt, get_server_opt, init_fl_state,
                         make_fl_round, make_loss)
 from repro.data.pipeline import FederatedDataset, lm_round_batches
 from repro.data.synthetic import get_task
+from repro.kernels import flat_backend, on_tpu
 
 
 def _resolve_scenario(args):
@@ -374,14 +375,15 @@ def train_lm(args):
                   server_opt=args.server_opt, lr=args.lr,
                   fedprox_mu=args.fedprox_mu, scenario=args.scenario,
                   num_clients=args.num_clients)
-    copt = get_client_opt(fl.client_opt, fl, use_pallas=args.use_pallas)
+    copt = get_client_opt(fl.client_opt, fl, use_pallas=on_tpu())
     sopt = get_server_opt(fl.server_opt)
     loss_fn = make_loss(lambda p, b: model.loss(p, b),
                         fedprox_mu=fl.fedprox_mu)
     comp = _resolve_compression(args)
     comp_active = comp.active(scn)
-    flat = ("xla" if (args.flat or (scn is not None and scn.is_async)
-                      or comp_active) else False)
+    flat = (flat_backend() if (args.flat or (scn is not None
+                                           and scn.is_async)
+                               or comp_active) else False)
     params = model.init(jax.random.key(args.seed))
     state = init_fl_state(params, sopt, scn, compression=comp,
                           cohort=args.clients_per_round)
@@ -427,7 +429,7 @@ def train_lm(args):
         loop = make_fl_loop(loss_fn, copt, sopt, params_like=params,
                             num_rounds=args.rounds,
                             rounds_per_call=args.rounds_per_call,
-                            flat="pallas" if args.use_pallas else "xla",
+                            flat=flat_backend(),
                             scenario=scn, num_clients=args.num_clients,
                             compression=comp, telemetry=telemetry)
 
@@ -568,8 +570,9 @@ def train_paper_task(args):
     K = fed.epoch_steps(args.batch)
     comp = _resolve_compression(args)
     comp_active = comp.active(scn)
-    flat = ("xla" if (args.flat or (scn is not None and scn.is_async)
-                      or comp_active) else False)
+    flat = (flat_backend() if (args.flat or (scn is not None
+                                           and scn.is_async)
+                               or comp_active) else False)
     state = init_fl_state(init_fn(jax.random.key(args.seed)), sopt, scn,
                           compression=comp, cohort=fl.clients_per_round)
     state = _maybe_resume(args, state)
@@ -607,7 +610,7 @@ def train_paper_task(args):
             params_like=jax.eval_shape(init_fn, jax.random.key(args.seed)),
             num_rounds=args.rounds, num_registered=fl.registered_clients,
             rounds_per_call=max(1, args.rounds_per_call),
-            flat="pallas" if args.use_pallas else "xla", scenario=scn,
+            flat=flat_backend(), scenario=scn,
             client_sizes=(jnp.asarray(fed.registered_sizes())
                           if scn else None),
             compression=comp, gather=arena_gather,
@@ -650,7 +653,7 @@ def train_paper_task(args):
             loss_fn, copt, sopt,
             params_like=jax.eval_shape(init_fn, jax.random.key(args.seed)),
             num_rounds=args.rounds, rounds_per_call=args.rounds_per_call,
-            flat="pallas" if args.use_pallas else "xla", scenario=scn,
+            flat=flat_backend(), scenario=scn,
             num_clients=args.num_clients,
             client_sizes=fed.client_sizes() if scn else None,
             compression=comp, gather=arena_gather,
@@ -777,7 +780,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "clients survive the round's faults")
     ap.add_argument("--lr", type=float, default=0.05)
     ap.add_argument("--fedprox-mu", type=float, default=0.0)
-    ap.add_argument("--use-pallas", action="store_true")
     ap.add_argument("--rounds-per-call", type=int, default=1,
                     help="R > 1 fuses R rounds into one jitted lax.scan "
                          "on persistent flat state (repro.core.fed_loop); "
@@ -816,6 +818,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main():
     ap = build_parser()
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.profile and args.rounds_per_call <= 1:
         ap.error("--profile needs the round-fused engine: pass "
                  "--rounds-per-call > 1")

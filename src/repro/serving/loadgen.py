@@ -57,9 +57,12 @@ def make_requests(workload: Workload, vocab: int
     return out
 
 
-def run_load(engine, workload: Workload, vocab: int) -> dict:
+def run_load(engine, workload: Workload, vocab: int, *,
+             extras: Optional[dict] = None) -> dict:
     """Drive ``workload`` through ``engine``; returns the report dict
-    (tok_per_s, p50/p99 latency, occupancy, swap counters)."""
+    (tok_per_s, p50/p99 latency, occupancy, swap counters).
+    ``extras`` (e.g. an encoder-decoder's frame embeddings) rides along
+    with every request."""
     reqs = make_requests(workload, vocab)
     done: list = []
     t0 = time.time()
@@ -67,7 +70,7 @@ def run_load(engine, workload: Workload, vocab: int) -> dict:
         pending = list(reqs)
         for _ in range(min(workload.concurrency, len(pending))):
             prompt, gen, cid, _at = pending.pop(0)
-            engine.submit(prompt, gen, client_id=cid)
+            engine.submit(prompt, gen, client_id=cid, extras=extras)
         while engine.has_work() or pending:
             done.extend(engine.step())
             while pending and engine.queue == [] and \
@@ -78,14 +81,14 @@ def run_load(engine, workload: Workload, vocab: int) -> dict:
                 if in_flight >= workload.concurrency:
                     break
                 prompt, gen, cid, _at = pending.pop(0)
-                engine.submit(prompt, gen, client_id=cid)
+                engine.submit(prompt, gen, client_id=cid, extras=extras)
     else:
         i = 0
         while i < len(reqs) or engine.has_work():
             now = time.time() - t0
             while i < len(reqs) and reqs[i][3] <= now:
                 prompt, gen, cid, _at = reqs[i]
-                engine.submit(prompt, gen, client_id=cid)
+                engine.submit(prompt, gen, client_id=cid, extras=extras)
                 i += 1
             if engine.has_work():
                 done.extend(engine.step())

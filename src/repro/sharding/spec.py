@@ -333,8 +333,5 @@ class LogicalRules:
         dims = [self.map.get(n) if n else None for n in names]
         if len(dims) != x.ndim:
             return x
-        try:
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(self.mesh, _dedupe(P(*dims))))
-        except Exception:
-            return x
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(self.mesh, _dedupe(P(*dims))))

@@ -9,12 +9,11 @@ perf regression shows up in the JSONL artifact even when the run
 itself is too short to time.
 
 ``trace_block`` wraps one block execution in a ``jax.profiler`` trace
-(uploaded as a CI artifact); failures degrade to a warning — profiling
-must never take the run down.
+(uploaded as a CI artifact). A failed trace raises: the block's carry is
+donated, so running it a second time would read deleted buffers.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Callable, Dict, Optional
 
 
@@ -71,15 +70,10 @@ def reset_kernel_launches() -> None:
 
 def trace_block(fn: Callable, logdir: str):
     """Run ``fn()`` under a ``jax.profiler`` trace written to
-    ``logdir``; returns fn's result. Trace failures warn, never raise."""
+    ``logdir``; returns fn's result once it is ready."""
     import jax
 
-    try:
-        with jax.profiler.trace(logdir):
-            out = fn()
-            jax.block_until_ready(out)
-        return out
-    except Exception as e:  # profiling is best-effort by contract
-        warnings.warn(f"jax.profiler trace failed ({e!r}); "
-                      f"running block untraced")
-        return fn()
+    with jax.profiler.trace(logdir):
+        out = fn()
+        jax.block_until_ready(out)
+    return out

@@ -61,8 +61,7 @@ def resolve_telemetry(telemetry: Union[None, bool, TelemetrySpec]
 
 def round_telemetry(tele: TelemetrySpec, etas, losses, clips=None,
                     valid=None, *, backend: str = "xla",
-                    use_kernel: Optional[bool] = None, rep=lambda x: x
-                    ) -> dict:
+                    use_kernel: Optional[bool] = None) -> dict:
     """The in-scan telemetry block for one round: η histogram over
     client lanes, per-client mean-loss deciles, absolute guard/clip hit
     counts. Pure read-only function of round-end values — adding it to
@@ -71,9 +70,7 @@ def round_telemetry(tele: TelemetrySpec, etas, losses, clips=None,
     ``use_kernel`` selects the Pallas kernels (kernels/telemetry, own
     LAUNCHES counter); default: only on the un-meshed pallas engine —
     jnp ref math elsewhere (meshed/pjit callers and ``backend="xla"``),
-    mirroring how the Δ-SGD engines pick their backend. ``rep`` pins
-    outputs replicated under meshes (same contract as the scenario
-    draws)."""
+    mirroring how the Δ-SGD engines pick their backend."""
     import jax.numpy as jnp
 
     if not tele.enabled:
@@ -84,17 +81,17 @@ def round_telemetry(tele: TelemetrySpec, etas, losses, clips=None,
     edges = jnp.asarray(tele.eta_edges())
     out = {}
     if use_kernel:
-        out["eta_hist"] = rep(tk.lane_histogram(etas, edges))
+        out["eta_hist"] = tk.lane_histogram(etas, edges)
     else:
-        out["eta_hist"] = rep(tk.lane_histogram_ref(etas, edges))
+        out["eta_hist"] = tk.lane_histogram_ref(etas, edges)
     if tele.loss_deciles:
         client_loss = jnp.mean(losses.astype(jnp.float32), axis=1)
         if use_kernel:
-            out["loss_deciles"] = rep(
-                tk.lane_quantiles(client_loss, tele.quantiles))
+            out["loss_deciles"] = tk.lane_quantiles(client_loss,
+                                                    tele.quantiles)
         else:
-            out["loss_deciles"] = rep(
-                tk.lane_quantiles_ref(client_loss, tele.quantiles))
+            out["loss_deciles"] = tk.lane_quantiles_ref(client_loss,
+                                                        tele.quantiles)
     if clips is not None:
         out["eta_clip_count"] = jnp.sum(clips.astype(jnp.float32))
     if valid is not None:

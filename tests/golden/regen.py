@@ -5,7 +5,7 @@
 Run this ONLY when a numeric change to the round engines is intended —
 the fixture diff is the review artifact that makes the change visible.
 Fixtures record the jax version they were generated under; the test
-asserts bit-exact on the same version and <= 1e-6 across versions.
+asserts them bit-exact, so regenerate them when the pinned jax moves.
 """
 import json
 import os

@@ -14,6 +14,7 @@ from repro.core import (get_client_opt, get_server_opt, init_fl_state,
 from repro.core import flat as fp
 from repro.kernels.compress import compress as ck
 from repro.kernels.compress import ref as cr
+from repro.launch.mesh import make_mesh
 
 LANES = fp.LANES
 
@@ -350,7 +351,7 @@ def test_sharded_compressed_round_matches_replicated(kind, rng):
     included."""
     from repro.federation import get_scenario
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     quad, params, batches = _fl_problem(rng)
     copt, sopt = get_client_opt("delta_sgd"), get_server_opt("fedavg")
@@ -385,7 +386,7 @@ def test_sharded_compressed_round_hlo_assertions(kind, rng):
     from repro.sharding.hlo import (assert_flat_buffer_sharded,
                                     assert_no_fullprec_delta_collective)
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     quad, params, batches = _fl_problem(rng)
     copt, sopt = get_client_opt("delta_sgd"), get_server_opt("fedavg")
@@ -412,7 +413,7 @@ def test_fullprec_collective_report_has_teeth():
     mentions are not, unparseable groups are conservative."""
     from repro.sharding.hlo import (_client_coords,
                                     fullprec_collective_report)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     coords = _client_coords(mesh, ("data",))
     cross = ('  %all-gather = f32[2,256]{1,0} all-gather(f32[2,64] %p), '
              'replica_groups={{0,2,4,6},{1,3,5,7}}, dimensions={1}')

@@ -24,6 +24,7 @@ from repro.federation import get_scenario
 from repro.federation.faults import (FaultModel, RobustAgg,
                                      robust_aggregate,
                                      robust_aggregate_sharded)
+from repro.launch.mesh import make_mesh
 
 needs8 = pytest.mark.skipif(jax.device_count() < 8,
                             reason="needs >= 8 devices "
@@ -216,7 +217,7 @@ def test_robust_aggregate_sharded_bucketed(rng):
     math (per-client norms are psum-exact); trimmed is the BUCKETED
     variant — shard-local trimmed means averaged across client shards."""
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     pspec = spec.flat_spec(mesh)
     C, N = 16, 256
@@ -478,7 +479,7 @@ def test_sharded_faulty_round_matches_metrics_shape(rng):
     the same telemetry keys as the replicated engine, and the quorum
     cond keeps params finite."""
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     quad, params, batches = _problem(rng, rounds=1, clients=8)
     loss = make_loss(quad)

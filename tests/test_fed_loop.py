@@ -15,6 +15,7 @@ from repro.core import (arena_gather, flatten_fl_state, get_client_opt,
                         get_server_opt, init_fl_state, make_fl_loop,
                         make_fl_round, make_loss, unflatten_fl_state)
 from repro.core import flat as fp
+from repro.launch.mesh import make_mesh
 
 needs8 = pytest.mark.skipif(jax.device_count() < 8,
                             reason="needs >= 8 devices "
@@ -244,12 +245,12 @@ def test_flat_state_roundtrip_all_slots(rng):
 @pytest.mark.parametrize("scenario", [None, "dirichlet_stragglers",
                                       "zipf_async"])
 def test_sharded_fused_matches_sharded_host(scenario, rng):
-    """8-device mesh: the fused scan (tree-form carry, see
-    fed_loop.state_form) == the sharded host loop bit-exact, and the
-    packed (C, N) buffer never materializes in the SCANNED HLO."""
+    """8-device mesh: the fused scan (flat carry, sharded over the
+    flat dim) == the sharded host loop bit-exact, and the packed (C, N)
+    buffer never materializes in the SCANNED HLO."""
     from repro.sharding.hlo import assert_flat_buffer_sharded
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     quad, params, batches = _problem(rng)
     loss = make_loss(quad)
@@ -262,13 +263,13 @@ def test_sharded_fused_matches_sharded_host(scenario, rng):
                         num_rounds=10, rounds_per_call=R, flat="xla",
                         mesh=mesh, federation=spec, scenario=scn,
                         num_clients=20)
-    assert loop.state_form == "tree"
+    assert loop.state_form == "flat"
     with mesh:
-        st2 = init_fl_state(
-            jax.tree.map(lambda x: jnp.array(x, copy=True), params),
-            sopt, scn)
-        compiled = jax.jit(loop).lower(st2, batches).compile()
-        st2, _ = compiled(st2, batches)
+        fst = flatten_fl_state(init_fl_state(params, sopt, scn),
+                               loop.layout)
+        compiled = jax.jit(loop).lower(fst, batches).compile()
+        fst, _ = compiled(fst, batches)
+    st2 = unflatten_fl_state(fst, loop.layout)
     _assert_states_equal(st.params, st2.params)
     assert_flat_buffer_sharded(compiled, C, loop.layout.padded_size)
 
@@ -284,7 +285,7 @@ def test_sharded_fused_compressed_hlo_boundary(rng):
     from repro.sharding.hlo import (assert_flat_buffer_sharded,
                                     assert_no_fullprec_delta_collective)
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     quad, params, batches = _problem(rng)
     loss = make_loss(quad)
@@ -299,11 +300,12 @@ def test_sharded_fused_compressed_hlo_boundary(rng):
                         mesh=mesh, federation=spec, scenario=scn,
                         num_clients=20, compression=comp)
     with mesh:
-        st2 = init_fl_state(
-            jax.tree.map(lambda x: jnp.array(x, copy=True), params),
-            sopt, scn, compression=comp, cohort=C)
-        compiled = jax.jit(loop).lower(st2, batches).compile()
-        st2, _ = compiled(st2, batches)
+        fst = flatten_fl_state(
+            init_fl_state(params, sopt, scn, compression=comp, cohort=C),
+            loop.layout)
+        compiled = jax.jit(loop).lower(fst, batches).compile()
+        fst, _ = compiled(fst, batches)
+    st2 = unflatten_fl_state(fst, loop.layout)
     _assert_states_equal(st.params, st2.params)
     _assert_states_equal(st.ef, st2.ef)
     assert_flat_buffer_sharded(compiled, C, loop.layout.padded_size)

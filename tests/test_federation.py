@@ -16,6 +16,7 @@ from repro.federation import (SCENARIOS, Scenario, buffer_init,
                               get_scenario, make_scheduler,
                               staleness_weights)
 from repro.kernels.delta_sgd import delta_sgd as dk
+from repro.launch.mesh import make_mesh
 
 GAMMA, DELTA, ETA0, THETA0 = 2.0, 0.1, 0.2, 1.0
 D = 5
@@ -199,10 +200,16 @@ def test_hetero_round_matches_literal_reference(flat, rng):
     """Round-level acceptance: make_fl_round under a straggler scenario
     == mean of per-client literal K_c-step oracles."""
     C, K = 4, 4
-    scn = get_scenario("dirichlet_stragglers", straggler_frac=0.5, seed=3)
-    step_counts = np.asarray(scn.draw_step_counts(0, C, K))
     # mixed draw: at least one masked lane AND one full-K lane, so the
-    # parity test really exercises frozen clients next to running ones
+    # parity test really exercises frozen clients next to running ones.
+    # The first scenario seed from 3 up that draws one: which seeds do
+    # depends on the PRNG's bit layout, which jax versions change.
+    for seed in range(3, 64):
+        scn = get_scenario("dirichlet_stragglers", straggler_frac=0.5,
+                           seed=seed)
+        step_counts = np.asarray(scn.draw_step_counts(0, C, K))
+        if step_counts.min() < K and step_counts.max() == K:
+            break
     assert step_counts.min() < K and step_counts.max() == K, step_counts
     batches = _mk_batches(rng, C, K)
     x0 = jnp.asarray(rng.normal(size=D), jnp.float32)
@@ -428,7 +435,7 @@ def test_sharded_scenario_round_matches_replicated(scn_name, rng):
     rematerializes in the compiled HLO (assert_flat_buffer_sharded)."""
     from repro.sharding.hlo import assert_flat_buffer_sharded
     from repro.sharding.spec import cross_device
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     spec = cross_device(mesh)
     scn = get_scenario(scn_name)
     quad, params, batches = _fl_problem(rng)

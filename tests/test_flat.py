@@ -12,6 +12,7 @@ from repro.core.delta_sgd import (delta_sgd_init, delta_sgd_reset,
                                   flat_delta_sgd_step)
 from repro.kernels.delta_sgd import delta_sgd as dk
 from repro.kernels.delta_sgd import ref as dref
+from repro.launch.mesh import make_mesh
 
 GAMMA, DELTA, ETA0, THETA0 = 2.0, 0.1, 0.2, 1.0
 
@@ -259,7 +260,7 @@ needs8 = pytest.mark.skipif(jax.device_count() < 8,
 
 
 def _mesh8():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2), ("data", "model"))
 
 
 def _fl_problem(rng, C=8, K=3, D=300, E=40):

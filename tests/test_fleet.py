@@ -33,6 +33,7 @@ from repro.core import (flatten_fl_state, get_client_opt, get_server_opt,
                         init_fl_state, make_fl_loop, make_fleet_loop)
 from repro.federation import (ClientArena, arena_init, arena_take,
                               arena_update, get_scenario, make_scheduler)
+from repro.launch.mesh import make_mesh
 from repro.sharding.hlo import (assert_cohort_only_materialization,
                                 cohort_materialization_report)
 
@@ -350,7 +351,7 @@ def test_fleet_memory_ceiling_cohort_only(rng):
 def _block_loops(loss, params, scenario=None, num_clients=None):
     from repro.sharding.spec import FederationSpec
     copt, sopt = _opts()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     fed = FederationSpec(client_axes=("data",), fsdp_axes=(), tp_axes=())
     kw = dict(params_like=params, num_rounds=100, flat="xla",
               scenario=scenario)

@@ -4,8 +4,8 @@ against committed fixtures (tests/golden/*.json, regenerated only by
 
 Assertions per the regression contract:
   * flat engines (flat_xla, flat_scenario, flat_int8_ef21) reproduce
-    their fixture BIT-EXACTLY on the fixture's jax version (<= 1e-6
-    across versions — the latest-jax CI leg);
+    their fixture BIT-EXACTLY (fixtures are recorded on the jax version
+    the repo runs on, which the fixture names);
   * the seed vmap engine reproduces its fixture the same way;
   * cross-engine (flat vs the seed vmap trajectory) stays <= 1e-5 —
     the engine-parity envelope the repo has tested since PR 1.
@@ -18,19 +18,11 @@ from _golden_common import CASES, load_fixture, run_case
 TRACE_KEYS = ("loss", "loss_last_step", "eta_mean")
 
 
-def _assert_trace(got, fixture, *, exact):
-    import jax
-    same_version = fixture.get("jax") == jax.__version__
+def _assert_trace(got, fixture):
     for k in TRACE_KEYS + ("params_l2",):
-        a = np.asarray(got[k], np.float32)
-        b = np.asarray(fixture[k], np.float32)
-        if exact and same_version:
-            np.testing.assert_array_equal(a, b, err_msg=k)
-        else:
-            # cross-jax-version leg: identical math, but XLA is free to
-            # re-fuse — hold the trace to a tight numerical envelope
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6,
-                                       err_msg=k)
+        np.testing.assert_array_equal(np.asarray(got[k], np.float32),
+                                      np.asarray(fixture[k], np.float32),
+                                      err_msg=k)
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +32,7 @@ def traces():
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_golden_trajectory(name, traces):
-    _assert_trace(traces[name], load_fixture(name), exact=True)
+    _assert_trace(traces[name], load_fixture(name))
 
 
 def test_cross_engine_envelope(traces):

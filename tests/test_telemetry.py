@@ -15,6 +15,7 @@ import pytest
 from repro.core import (flatten_fl_state, get_client_opt, get_server_opt,
                         init_fl_state, make_fl_loop, make_fl_round,
                         make_loss, unflatten_fl_state)
+from repro.launch.mesh import make_mesh
 from repro.telemetry import (EventLog, SpanTimer, TelemetrySpec,
                              config_hash, kernel_launch_snapshot,
                              load_events, reset_kernel_launches,
@@ -182,7 +183,7 @@ def test_block_sharded_bit_exact_and_hist_parity(rng):
     from repro.sharding.spec import FederationSpec
     loss, params, batches = _problem(rng)
     copt, sopt = _opts()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     fed = FederationSpec(client_axes=("data",), fsdp_axes=(), tp_axes=())
 
     def run(block, tele):
