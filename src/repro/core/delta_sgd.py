@@ -36,6 +36,10 @@ local slab. The dual norm reduction completes with ONE psum of the two
 partial sums over the N-shard axes (2·C_local floats on the wire); the
 (C, N) buffer itself is never gathered, and the apply is purely
 shard-local.
+
+The three flat entry points run under the named scope ``delta_sgd``: on a
+device trace it holds the kernel pair and the jnp around it (the
+``where(valid, G, 0)`` select, the η/θ rule and guards).
 """
 from __future__ import annotations
 
@@ -182,15 +186,16 @@ class FlatDeltaSGDState(NamedTuple):
 
 def flat_delta_sgd_init(num_clients: int, layout: flatlib.FlatLayout, *,
                         eta0: float, theta0: float) -> FlatDeltaSGDState:
-    C, N = num_clients, layout.padded_size
-    return FlatDeltaSGDState(
-        jnp.zeros((C, N), jnp.float32),
-        jnp.full((C,), eta0, jnp.float32),
-        jnp.full((C,), theta0, jnp.float32),
-        jnp.zeros((C,), jnp.float32),
-        jnp.asarray(0, jnp.int32),
-        jnp.ones((C,), bool),
-        jnp.zeros((C,), jnp.int32))
+    with jax.named_scope("delta_sgd"):
+        C, N = num_clients, layout.padded_size
+        return FlatDeltaSGDState(
+            jnp.zeros((C, N), jnp.float32),
+            jnp.full((C,), eta0, jnp.float32),
+            jnp.full((C,), theta0, jnp.float32),
+            jnp.zeros((C,), jnp.float32),
+            jnp.asarray(0, jnp.int32),
+            jnp.ones((C,), bool),
+            jnp.zeros((C,), jnp.int32))
 
 
 def _guard(eta, dg_norm, grad_norm, valid_prev):
@@ -242,41 +247,42 @@ def flat_delta_sgd_step(P: jax.Array, G: jax.Array,
     heterogeneous step counts: inactive clients apply η=0 and keep their
     state frozen, at no extra launch cost. Returns (new_P, new_state).
     """
-    first = (state.k == 0)
-    if backend == "pallas":
-        from repro.kernels import interpret_mode
-        from repro.kernels.delta_sgd import delta_sgd as k
-        interpret = interpret_mode(interpret)
-        dg2, gg2 = k.batched_norms(G, state.prev_grads,
-                                   interpret=interpret)
-    else:
-        from repro.kernels.delta_sgd import ref as kref
-        dg2, gg2 = kref.batched_norms_ref(G, state.prev_grads)
-    dg_norm = jnp.sqrt(dg2)
-    grad_norm = jnp.sqrt(gg2)
-    dx_norm = state.eta * state.prev_grad_norm
-    eta, theta = _eta_rule(state.eta, state.theta, dx_norm, dg_norm,
-                           gamma, delta)
-    eta = jnp.where(first, jnp.asarray(eta0, jnp.float32), eta)
-    theta = jnp.where(first, state.theta, theta)
-    eta, valid, clip_hit = _guard(eta, dg_norm, grad_norm, state.valid)
-    act = valid if active is None else (active & valid)
-    eta_applied, eta, theta, grad_norm = _mask_inactive(
-        act, eta, theta, grad_norm, state)
-    clips = (jnp.zeros_like(valid, jnp.int32) if state.clips is None
-             else state.clips) + (clip_hit & act).astype(jnp.int32)
-    # sanitize: η=0 alone can't stop a NaN gradient (0·NaN = NaN in the
-    # apply), so invalid lanes are zeroed before both the apply and the
-    # prev_grads roll. where(True, G, 0) is G bitwise on healthy lanes,
-    # and it is an XLA select — the step stays at two kernel launches.
-    G_safe = jnp.where(valid[:, None], G, jnp.float32(0.0))
-    if backend == "pallas":
-        new_P = k.batched_apply(P, G_safe, eta_applied, mask=mask,
-                                interpret=interpret)
-    else:
-        new_P = kref.batched_apply_ref(P, G_safe, eta_applied, mask)
-    return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
-                                    state.k + 1, valid, clips)
+    with jax.named_scope("delta_sgd"):
+        first = (state.k == 0)
+        if backend == "pallas":
+            from repro.kernels import interpret_mode
+            from repro.kernels.delta_sgd import delta_sgd as k
+            interpret = interpret_mode(interpret)
+            dg2, gg2 = k.batched_norms(G, state.prev_grads,
+                                       interpret=interpret)
+        else:
+            from repro.kernels.delta_sgd import ref as kref
+            dg2, gg2 = kref.batched_norms_ref(G, state.prev_grads)
+        dg_norm = jnp.sqrt(dg2)
+        grad_norm = jnp.sqrt(gg2)
+        dx_norm = state.eta * state.prev_grad_norm
+        eta, theta = _eta_rule(state.eta, state.theta, dx_norm, dg_norm,
+                               gamma, delta)
+        eta = jnp.where(first, jnp.asarray(eta0, jnp.float32), eta)
+        theta = jnp.where(first, state.theta, theta)
+        eta, valid, clip_hit = _guard(eta, dg_norm, grad_norm, state.valid)
+        act = valid if active is None else (active & valid)
+        eta_applied, eta, theta, grad_norm = _mask_inactive(
+            act, eta, theta, grad_norm, state)
+        clips = (jnp.zeros_like(valid, jnp.int32) if state.clips is None
+                 else state.clips) + (clip_hit & act).astype(jnp.int32)
+        # sanitize: η=0 alone can't stop a NaN gradient (0·NaN = NaN in the
+        # apply), so invalid lanes are zeroed before both the apply and the
+        # prev_grads roll. where(True, G, 0) is G bitwise on healthy lanes,
+        # and it is an XLA select — the step stays at two kernel launches.
+        G_safe = jnp.where(valid[:, None], G, jnp.float32(0.0))
+        if backend == "pallas":
+            new_P = k.batched_apply(P, G_safe, eta_applied, mask=mask,
+                                    interpret=interpret)
+        else:
+            new_P = kref.batched_apply_ref(P, G_safe, eta_applied, mask)
+        return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
+                                        state.k + 1, valid, clips)
 
 
 # --------------------------------------------------------------------------
@@ -358,24 +364,25 @@ def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
                                            mask_l)
         return new_P, G_safe, eta_n, theta_n, grad_norm, valid_n, clips_n
 
-    C = P.shape[0]
-    valid = (state.valid if state.valid is not None
-             else jnp.ones((C,), bool))
-    clips = (state.clips if state.clips is not None
-             else jnp.zeros((C,), jnp.int32))
-    ins = [P, G, state.prev_grads, state.eta, state.theta,
-           state.prev_grad_norm, state.k, valid, clips]
-    specs = [buf, buf, buf, vec, vec, vec, rep, vec, vec]
-    if with_mask:
-        ins.append(mask)
-        specs.append(PS(na))
-    if with_active:
-        ins.append(active)
-        specs.append(vec)
-    # replication checking off: the Pallas kernels carry no rules for it
-    fn = jax.shard_map(local_step, mesh=mesh, in_specs=tuple(specs),
-                       out_specs=(buf, buf, vec, vec, vec, vec, vec),
-                       check_vma=False)
-    new_P, G_safe, eta, theta, grad_norm, valid, clips = fn(*ins)
-    return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
-                                    state.k + 1, valid, clips)
+    with jax.named_scope("delta_sgd"):
+        C = P.shape[0]
+        valid = (state.valid if state.valid is not None
+                 else jnp.ones((C,), bool))
+        clips = (state.clips if state.clips is not None
+                 else jnp.zeros((C,), jnp.int32))
+        ins = [P, G, state.prev_grads, state.eta, state.theta,
+               state.prev_grad_norm, state.k, valid, clips]
+        specs = [buf, buf, buf, vec, vec, vec, rep, vec, vec]
+        if with_mask:
+            ins.append(mask)
+            specs.append(PS(na))
+        if with_active:
+            ins.append(active)
+            specs.append(vec)
+        # replication checking off: the Pallas kernels carry no rules for it
+        fn = jax.shard_map(local_step, mesh=mesh, in_specs=tuple(specs),
+                           out_specs=(buf, buf, vec, vec, vec, vec, vec),
+                           check_vma=False)
+        new_P, G_safe, eta, theta, grad_norm, valid, clips = fn(*ins)
+        return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
+                                        state.k + 1, valid, clips)

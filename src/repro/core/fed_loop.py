@@ -394,7 +394,8 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                 batches = (gather(arena_l, data_r) if gather is not None
                            else data_r)
                 gp = flatlib.unpack(st.P, layout)
-                P = jnp.broadcast_to(st.P[None], (C_loc, N))
+                with jax.named_scope("flat"):
+                    P = jnp.broadcast_to(st.P[None], (C_loc, N))
                 P_start = P if (is_async or comp is not None) else None
                 S = flat_delta_sgd_init(C_loc, layout, eta0=eta0,
                                         theta0=theta0)
@@ -408,9 +409,10 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                     batch_k, k_idx = inp
                     P, S = carry
                     params_c = flatlib.unpack_batched(P, layout)
-                    (l, _), g = jax.vmap(
-                        grad_fn, in_axes=(0, 0, None, None)
-                    )(params_c, batch_k, gp, None)
+                    with jax.named_scope("client_grad"):
+                        (l, _), g = jax.vmap(
+                            grad_fn, in_axes=(0, 0, None, None)
+                        )(params_c, batch_k, gp, None)
                     G = flatlib.pack_batched(g, layout)
                     active = ((k_idx < budget) if budget is not None
                               else None)
@@ -423,140 +425,142 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                     step, (P, S),
                     (batches_t, jnp.arange(K, dtype=jnp.int32)),
                     unroll=scan_unroll())
-                losses = losses.T       # (C_loc, K)
+                # everything past the local steps is the round tail
+                with jax.named_scope("round_tail"):
+                    losses = losses.T       # (C_loc, K)
 
-                # collective budget: every client-crossing SUM rides
-                # ONE packed (N+5,) psum together with the round's
-                # aggregate, and both η extrema share ONE pmin — 2
-                # collectives per round total, which is what keeps the
-                # sharded block's per-round cost near the replicated
-                # loop's on rendezvous-priced meshes. The concat lives
-                # inside the shard_map body (a per-device program, no
-                # SPMD partitioner), so the 1-D packed-concat jit
-                # gotcha (core/flat.py) does not apply.
-                if k_full is not None:
-                    am_l = active_mask(budget, K)
-                    loss_num = jnp.sum(losses * am_l)
-                    loss_den = jnp.sum(active_mask(k_full, K))
-                    last_num = jnp.sum(jnp.take_along_axis(
-                        losses, (budget - 1)[:, None], axis=1)[:, 0])
-                else:
-                    loss_num = jnp.sum(losses)
-                    loss_den = jnp.float32(C * K)
-                    last_num = jnp.sum(losses[:, -1])
-                scal = jnp.stack([
-                    loss_num, last_num, jnp.sum(S.eta),
-                    jnp.sum(S.clips.astype(jnp.float32)),
-                    jnp.sum((~S.valid).astype(jnp.float32))])
-                if tele.enabled:
-                    # per-shard η-histogram counts ride the SAME packed
-                    # psum (exact integer sums in f32, so the summed
-                    # histogram is bit-identical to the replicated
-                    # engine's) — the collective budget stays at 2/round
-                    scal = jnp.concatenate([
-                        scal,
-                        lane_histogram_ref(
-                            S.eta, jnp.asarray(tele.eta_edges()))])
-                ext = cpmin(jnp.stack([jnp.min(S.eta),
-                                       -jnp.max(S.eta)]))
-                extra = {}
-                if k_full is not None:
-                    kf = k_full.astype(jnp.float32)
-                    extra.update(k_eff_mean=jnp.mean(kf),
-                                 k_eff_min=jnp.min(kf),
-                                 k_eff_max=jnp.max(kf))
-
-                new_ef = st.ef
-                if comp is not None:
-                    from repro.compression.ops import compress_flat
-                    lev_full = d_r.get("lev")
-                    lev_loc = (local_cols(lev_full)
-                               if lev_full is not None else None)
-                    delta_c = P - P_start
-                    resid = (delta_c - st.ef) if use_ef else delta_c
-                    chat = compress_flat(resid, comp, levels=lev_loc,
-                                         backend=backend)
-                    delta_hat = (st.ef + chat) if use_ef else chat
-                    if use_ef:
-                        new_ef = delta_hat
-                    # wire accounting on the FULL level vector — every
-                    # shard reports the identical cohort-total bytes
-                    wire = comp.wire_bytes(layout.size, levels=lev_full,
-                                           num_clients=C)
-                    extra.update(
-                        wire_bytes=jnp.sum(wire),
-                        comp_ratio=(4.0 * layout.size * C)
-                        / jnp.sum(wire))
-                    if lev_full is not None:
-                        extra["comp_level_mean"] = jnp.mean(
-                            lev_full.astype(jnp.float32))
-                    P_agg = P_start + delta_hat
-                else:
-                    delta_hat = None
-                    P_agg = P
-
-                if not is_async:
-                    if weighted and has_w:
-                        wn = w_r.astype(jnp.float32)
-                        wn = wn / jnp.sum(wn)
-                        agg_local = jnp.tensordot(local_cols(wn), P_agg,
-                                                  axes=(0, 0))
-                        agg_div = jnp.float32(1.0)
+                    # collective budget: every client-crossing SUM rides
+                    # ONE packed (N+5,) psum together with the round's
+                    # aggregate, and both η extrema share ONE pmin — 2
+                    # collectives per round total, which is what keeps the
+                    # sharded block's per-round cost near the replicated
+                    # loop's on rendezvous-priced meshes. The concat lives
+                    # inside the shard_map body (a per-device program, no
+                    # SPMD partitioner), so the 1-D packed-concat jit
+                    # gotcha (core/flat.py) does not apply.
+                    if k_full is not None:
+                        am_l = active_mask(budget, K)
+                        loss_num = jnp.sum(losses * am_l)
+                        loss_den = jnp.sum(active_mask(k_full, K))
+                        last_num = jnp.sum(jnp.take_along_axis(
+                            losses, (budget - 1)[:, None], axis=1)[:, 0])
                     else:
-                        agg_local = jnp.sum(P_agg, axis=0)
-                        agg_div = Cf
-                    packed = cpsum(jnp.concatenate([agg_local, scal]))
-                    scal_g = packed[N:]
-                    agg = flatlib.unpack(packed[:N] / agg_div, layout)
-                    new_params, sstate = server_opt.update(
-                        gp, agg, st.server_state)
-                    new_st = FlatFLState(
-                        flatlib.pack(new_params, layout), sstate,
-                        st.round + 1, st.buffer, new_ef)
-                else:
-                    from repro.federation.buffer import (
-                        buffer_merge, buffer_step, staleness_weights)
-                    stale_full = d_r["stale"]
-                    wst = staleness_weights(stale_full,
-                                            scenario.staleness_exp)
-                    if weighted and has_w:
-                        wst = wst * w_r.astype(jnp.float32)
-                    agg_local = jnp.tensordot(
-                        local_cols(wst),
-                        delta_hat if comp is not None else (P - P_start),
-                        axes=(0, 0))
-                    packed = cpsum(jnp.concatenate([agg_local, scal]))
-                    scal_g = packed[N:]
-                    delta_tree = flatlib.unpack(packed[:N], layout,
-                                                cast=False)
-                    # buffer math runs on the full replicated vectors,
-                    # so the buffer state stays identical on every shard
-                    buf = buffer_merge(st.buffer, delta_tree,
-                                       jnp.sum(wst), C, stale_full)
-                    new_params, sstate, buf, flushed = buffer_step(
-                        gp, st.server_state, buf, server_opt,
-                        scenario.buffer_size)
-                    sf = stale_full.astype(jnp.float32)
-                    extra.update(
-                        stale_mean=jnp.mean(sf), stale_max=jnp.max(sf),
-                        buffer_fill=buf.count.astype(jnp.float32),
-                        flushed=flushed)
-                    new_st = FlatFLState(
-                        flatlib.pack(new_params, layout), sstate,
-                        st.round + 1, buf, new_ef)
-                metrics = {
-                    "loss": scal_g[0] / loss_den,
-                    "loss_last_step": scal_g[1] / Cf,
-                    "eta_mean": scal_g[2] / Cf,
-                    "eta_min": ext[0], "eta_max": -ext[1],
-                    "eta_clip_rate": scal_g[3] / jnp.float32(C * K),
-                    "nan_guard_rate": scal_g[4] / Cf}
-                if tele.enabled:
-                    metrics.update(eta_hist=scal_g[5:],
-                                   eta_clip_count=scal_g[3],
-                                   nan_guard_count=scal_g[4])
-                metrics.update(extra)
-                return new_st, metrics
+                        loss_num = jnp.sum(losses)
+                        loss_den = jnp.float32(C * K)
+                        last_num = jnp.sum(losses[:, -1])
+                    scal = jnp.stack([
+                        loss_num, last_num, jnp.sum(S.eta),
+                        jnp.sum(S.clips.astype(jnp.float32)),
+                        jnp.sum((~S.valid).astype(jnp.float32))])
+                    if tele.enabled:
+                        # per-shard η-histogram counts ride the SAME packed
+                        # psum (exact integer sums in f32, so the summed
+                        # histogram is bit-identical to the replicated
+                        # engine's) — the collective budget stays at 2/round
+                        scal = jnp.concatenate([
+                            scal,
+                            lane_histogram_ref(
+                                S.eta, jnp.asarray(tele.eta_edges()))])
+                    ext = cpmin(jnp.stack([jnp.min(S.eta),
+                                           -jnp.max(S.eta)]))
+                    extra = {}
+                    if k_full is not None:
+                        kf = k_full.astype(jnp.float32)
+                        extra.update(k_eff_mean=jnp.mean(kf),
+                                     k_eff_min=jnp.min(kf),
+                                     k_eff_max=jnp.max(kf))
+
+                    new_ef = st.ef
+                    if comp is not None:
+                        from repro.compression.ops import compress_flat
+                        lev_full = d_r.get("lev")
+                        lev_loc = (local_cols(lev_full)
+                                   if lev_full is not None else None)
+                        delta_c = P - P_start
+                        resid = (delta_c - st.ef) if use_ef else delta_c
+                        chat = compress_flat(resid, comp, levels=lev_loc,
+                                             backend=backend)
+                        delta_hat = (st.ef + chat) if use_ef else chat
+                        if use_ef:
+                            new_ef = delta_hat
+                        # wire accounting on the FULL level vector — every
+                        # shard reports the identical cohort-total bytes
+                        wire = comp.wire_bytes(layout.size, levels=lev_full,
+                                               num_clients=C)
+                        extra.update(
+                            wire_bytes=jnp.sum(wire),
+                            comp_ratio=(4.0 * layout.size * C)
+                            / jnp.sum(wire))
+                        if lev_full is not None:
+                            extra["comp_level_mean"] = jnp.mean(
+                                lev_full.astype(jnp.float32))
+                        P_agg = P_start + delta_hat
+                    else:
+                        delta_hat = None
+                        P_agg = P
+
+                    if not is_async:
+                        if weighted and has_w:
+                            wn = w_r.astype(jnp.float32)
+                            wn = wn / jnp.sum(wn)
+                            agg_local = jnp.tensordot(local_cols(wn), P_agg,
+                                                      axes=(0, 0))
+                            agg_div = jnp.float32(1.0)
+                        else:
+                            agg_local = jnp.sum(P_agg, axis=0)
+                            agg_div = Cf
+                        packed = cpsum(jnp.concatenate([agg_local, scal]))
+                        scal_g = packed[N:]
+                        agg = flatlib.unpack(packed[:N] / agg_div, layout)
+                        new_params, sstate = server_opt.update(
+                            gp, agg, st.server_state)
+                        new_st = FlatFLState(
+                            flatlib.pack(new_params, layout), sstate,
+                            st.round + 1, st.buffer, new_ef)
+                    else:
+                        from repro.federation.buffer import (
+                            buffer_merge, buffer_step, staleness_weights)
+                        stale_full = d_r["stale"]
+                        wst = staleness_weights(stale_full,
+                                                scenario.staleness_exp)
+                        if weighted and has_w:
+                            wst = wst * w_r.astype(jnp.float32)
+                        agg_local = jnp.tensordot(
+                            local_cols(wst),
+                            delta_hat if comp is not None else (P - P_start),
+                            axes=(0, 0))
+                        packed = cpsum(jnp.concatenate([agg_local, scal]))
+                        scal_g = packed[N:]
+                        delta_tree = flatlib.unpack(packed[:N], layout,
+                                                    cast=False)
+                        # buffer math runs on the full replicated vectors,
+                        # so the buffer state stays identical on every shard
+                        buf = buffer_merge(st.buffer, delta_tree,
+                                           jnp.sum(wst), C, stale_full)
+                        new_params, sstate, buf, flushed = buffer_step(
+                            gp, st.server_state, buf, server_opt,
+                            scenario.buffer_size)
+                        sf = stale_full.astype(jnp.float32)
+                        extra.update(
+                            stale_mean=jnp.mean(sf), stale_max=jnp.max(sf),
+                            buffer_fill=buf.count.astype(jnp.float32),
+                            flushed=flushed)
+                        new_st = FlatFLState(
+                            flatlib.pack(new_params, layout), sstate,
+                            st.round + 1, buf, new_ef)
+                    metrics = {
+                        "loss": scal_g[0] / loss_den,
+                        "loss_last_step": scal_g[1] / Cf,
+                        "eta_mean": scal_g[2] / Cf,
+                        "eta_min": ext[0], "eta_max": -ext[1],
+                        "eta_clip_rate": scal_g[3] / jnp.float32(C * K),
+                        "nan_guard_rate": scal_g[4] / Cf}
+                    if tele.enabled:
+                        metrics.update(eta_hist=scal_g[5:],
+                                       eta_clip_count=scal_g[3],
+                                       nan_guard_count=scal_g[4])
+                    metrics.update(extra)
+                    return new_st, metrics
 
             return jax.lax.scan(one_round, fst,
                                 (data, w_all, draws_all))

@@ -490,8 +490,9 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
 
         # broadcast the round-start params to the client axis; the carry
         # is already flat, so no per-round pytree re-pack happens here
-        P = constrain(jnp.broadcast_to(fstate.P[None],
-                                       (C, layout.padded_size)), pspec)
+        with jax.named_scope("flat"):
+            P = constrain(jnp.broadcast_to(fstate.P[None],
+                                           (C, layout.padded_size)), pspec)
         P_start = P if (is_async or comp is not None or guard_tail) \
             else None
         S = flat_delta_sgd_init(C, layout, eta0=eta0, theta0=theta0)
@@ -512,11 +513,12 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
             batch_k, k_idx = inp
             P, S = carry
             params_c = flatlib.unpack_batched(P, layout)
-            (l, _), g = jax.vmap(
-                grad_fn, in_axes=(0, 0, None,
-                                  0 if prev_local_params is not None
-                                  else None)
-            )(params_c, batch_k, gp, prev_local_params)
+            with jax.named_scope("client_grad"):
+                (l, _), g = jax.vmap(
+                    grad_fn, in_axes=(0, 0, None,
+                                      0 if prev_local_params is not None
+                                      else None)
+                )(params_c, batch_k, gp, prev_local_params)
             G = constrain(flatlib.pack_batched(g, layout), pspec)
             if faults_on and fm.nan_rate > 0.0:
                 # NaN/Inf gradient corruption: from the drawn step on,
@@ -534,297 +536,300 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
         (P, S), losses = jax.lax.scan(
             step, (P, S), (batches_t, jnp.arange(K, dtype=jnp.int32)),
             unroll=scan_unroll())
-        losses = losses.T  # (K, C) -> (C, K), same layout as vmap engine
+        # everything past the local steps is the round tail
+        with jax.named_scope("round_tail"):
+            losses = losses.T  # (K, C) -> (C, K), same layout as vmap engine
 
-        extra = _scenario_extras(scenario, fstate.round, C, num_clients,
-                                 client_sizes, step_counts)
-        # numerical-guard telemetry (always on for the flat engines):
-        # how often η hit the ETA_CLAMP ceiling, and what fraction of
-        # lanes the NaN guard dropped this round
-        extra.update(
-            eta_clip_rate=(jnp.sum(S.clips.astype(jnp.float32))
-                           / jnp.float32(C * K)),
-            nan_guard_rate=jnp.mean((~S.valid).astype(jnp.float32)))
-        if tele.enabled:
-            # in-scan distribution block (repro.telemetry): read-only
-            # over round-end values, so the trajectory is unperturbed.
-            # The Pallas kernels only run on the un-meshed pallas
-            # engine; meshed/pjit rounds use the jnp ref math (sharding
-            # constraints inside pallas_call don't compose), and the
-            # counts are exact integers either way.
-            extra.update(round_telemetry(
-                tele, S.eta, losses, S.clips, S.valid, backend=backend,
-                use_kernel=(backend == "pallas" and not sharded)))
-
-        # survivor mask + byzantine factor for the fault/robust tails:
-        # a client is excluded when its NaN guard latched, it dropped
-        # mid-round, or (async, below) its update arrived over-stale
-        byz = valid = None
-        if guard_tail:
-            valid = S.valid
-            if drops_on:
-                valid = valid & (lanes.drop_step >= K)
-            if faults_on and fm.byzantine_rate > 0.0:
-                byz = jnp.where(lanes.byzantine,
-                                jnp.float32(fm.byzantine_scale),
-                                jnp.float32(1.0))
-
-        # delta compression (repro.compression): compress each client's
-        # round delta before ANY aggregation — only the reconstructed
-        # Δ̂_c (and, under meshes, the post-mean (N,) aggregate) exists
-        # past this point. EF21: the client ships C(Δ_c − g_c) and both
-        # sides roll g_c ← g_c + C(Δ_c − g_c), so Δ̂_c = new g_c and the
-        # compression error does not accumulate across rounds.
-        new_ef = None
-        if comp is not None:
-            from repro.compression.ops import (compress_flat,
-                                               compress_flat_sharded)
-            levels = (scenario.draw_compression_levels(fstate.round, C)
-                      if bw_hetero else None)
-            delta = P - P_start
-            if byz is not None:
-                # byzantine corruption happens CLIENT-side, before the
-                # (honest) compression transport — the server only ever
-                # sees the reconstructed corrupted delta
-                delta = delta * byz[:, None]
-            if use_ef:
-                if fstate.ef is None:
-                    raise ValueError(
-                        "error-feedback compression needs FLState.ef — "
-                        "allocate it via init_fl_state(..., compression="
-                        "spec, cohort=C)")
-                E = fstate.ef
-                if sharded:
-                    E = constrain(E, pspec)
-                resid = delta - E
-            else:
-                E, resid = None, delta
-            if sharded:
-                chat = compress_flat_sharded(resid, comp, mesh=mesh,
-                                             pspec=pspec, levels=levels,
-                                             backend=backend)
-            else:
-                chat = compress_flat(resid, comp, levels=levels,
-                                     backend=backend)
-            delta_hat = (E + chat) if E is not None else chat
-            if sharded:
-                delta_hat = constrain(delta_hat, pspec)
-            if use_ef:
-                new_ef = delta_hat      # (C, N) flat — the EF21 carry
-            # wire accounting over the VALID elements (layout.size):
-            # tail padding never ships, so sharded and replicated
-            # layouts (different padded_size) report identical bytes
-            wire = comp.wire_bytes(layout.size, levels=levels,
-                                   num_clients=C)
+            extra = _scenario_extras(scenario, fstate.round, C, num_clients,
+                                     client_sizes, step_counts)
+            # numerical-guard telemetry (always on for the flat engines):
+            # how often η hit the ETA_CLAMP ceiling, and what fraction of
+            # lanes the NaN guard dropped this round
             extra.update(
-                wire_bytes=jnp.sum(wire),
-                comp_ratio=(4.0 * layout.size * C) / jnp.sum(wire))
-            if levels is not None:
-                extra["comp_level_mean"] = jnp.mean(
-                    levels.astype(jnp.float32))
-            # what the server aggregates: round-start params + the
-            # reconstructed deltas (≡ P exactly when the spec is inert —
-            # inert specs never reach this branch)
-            P_agg = P_start + delta_hat
-        else:
-            delta_hat = None
-            P_agg = P
+                eta_clip_rate=(jnp.sum(S.clips.astype(jnp.float32))
+                               / jnp.float32(C * K)),
+                nan_guard_rate=jnp.mean((~S.valid).astype(jnp.float32)))
+            if tele.enabled:
+                # in-scan distribution block (repro.telemetry): read-only
+                # over round-end values, so the trajectory is unperturbed.
+                # The Pallas kernels only run on the un-meshed pallas
+                # engine; meshed/pjit rounds use the jnp ref math (sharding
+                # constraints inside pallas_call don't compose), and the
+                # counts are exact integers either way.
+                extra.update(round_telemetry(
+                    tele, S.eta, losses, S.clips, S.valid, backend=backend,
+                    use_kernel=(backend == "pallas" and not sharded)))
 
-        if not is_async and not guard_tail:
-            # aggregate: single (weighted) mean over the packed client
-            # axis — under the sharded engine XLA lowers this to the
-            # FedAvg all-reduce over the client mesh axes; the (N,)
-            # result keeps the flat-dim sharding.
-            if weighted and client_weights is not None:
-                w = client_weights / jnp.sum(client_weights)
-                agg_flat = jnp.tensordot(w.astype(jnp.float32), P_agg,
-                                         axes=(0, 0))
-            elif comp is not None and not sharded:
-                agg_flat = _halving_mean(P_agg)
+            # survivor mask + byzantine factor for the fault/robust tails:
+            # a client is excluded when its NaN guard latched, it dropped
+            # mid-round, or (async, below) its update arrived over-stale
+            byz = valid = None
+            if guard_tail:
+                valid = S.valid
+                if drops_on:
+                    valid = valid & (lanes.drop_step >= K)
+                if faults_on and fm.byzantine_rate > 0.0:
+                    byz = jnp.where(lanes.byzantine,
+                                    jnp.float32(fm.byzantine_scale),
+                                    jnp.float32(1.0))
+
+            # delta compression (repro.compression): compress each client's
+            # round delta before ANY aggregation — only the reconstructed
+            # Δ̂_c (and, under meshes, the post-mean (N,) aggregate) exists
+            # past this point. EF21: the client ships C(Δ_c − g_c) and both
+            # sides roll g_c ← g_c + C(Δ_c − g_c), so Δ̂_c = new g_c and the
+            # compression error does not accumulate across rounds.
+            new_ef = None
+            if comp is not None:
+                from repro.compression.ops import (compress_flat,
+                                                   compress_flat_sharded)
+                levels = (scenario.draw_compression_levels(fstate.round, C)
+                          if bw_hetero else None)
+                delta = P - P_start
+                if byz is not None:
+                    # byzantine corruption happens CLIENT-side, before the
+                    # (honest) compression transport — the server only ever
+                    # sees the reconstructed corrupted delta
+                    delta = delta * byz[:, None]
+                if use_ef:
+                    if fstate.ef is None:
+                        raise ValueError(
+                            "error-feedback compression needs FLState.ef — "
+                            "allocate it via init_fl_state(..., compression="
+                            "spec, cohort=C)")
+                    E = fstate.ef
+                    if sharded:
+                        E = constrain(E, pspec)
+                    resid = delta - E
+                else:
+                    E, resid = None, delta
+                if sharded:
+                    chat = compress_flat_sharded(resid, comp, mesh=mesh,
+                                                 pspec=pspec, levels=levels,
+                                                 backend=backend)
+                else:
+                    chat = compress_flat(resid, comp, levels=levels,
+                                         backend=backend)
+                delta_hat = (E + chat) if E is not None else chat
+                if sharded:
+                    delta_hat = constrain(delta_hat, pspec)
+                if use_ef:
+                    new_ef = delta_hat      # (C, N) flat — the EF21 carry
+                # wire accounting over the VALID elements (layout.size):
+                # tail padding never ships, so sharded and replicated
+                # layouts (different padded_size) report identical bytes
+                wire = comp.wire_bytes(layout.size, levels=levels,
+                                       num_clients=C)
+                extra.update(
+                    wire_bytes=jnp.sum(wire),
+                    comp_ratio=(4.0 * layout.size * C) / jnp.sum(wire))
+                if levels is not None:
+                    extra["comp_level_mean"] = jnp.mean(
+                        levels.astype(jnp.float32))
+                # what the server aggregates: round-start params + the
+                # reconstructed deltas (≡ P exactly when the spec is inert —
+                # inert specs never reach this branch)
+                P_agg = P_start + delta_hat
             else:
-                agg_flat = jnp.mean(P_agg, axis=0)
-            agg = flatlib.unpack(constrain(agg_flat, nspec), layout)
-            new_params, sstate = server_opt.update(gp, agg,
-                                                   fstate.server_state)
-            metrics = _round_metrics(losses, S.eta, step_counts)
-            metrics.update(extra)
-            new_fstate = FlatFLState(
-                pack1(new_params), sstate, fstate.round + 1,
-                fstate.buffer, fstate.ef if new_ef is None else new_ef)
-        elif not is_async:
-            # fault/robust synchronous tail: the server works in DELTA
-            # space — the RobustAgg ladder (repro.federation.faults)
-            # aggregates the survivors' deltas (clip / trimmed / median /
-            # valid-masked mean) and the result re-anchors on the round-
-            # start params. Under meshes the ladder runs inside
-            # shard_map before/with the client-mean psum, so only (N_loc,)
-            # aggregates ever cross the client shard boundary.
-            from repro.federation.faults import (robust_aggregate,
-                                                 robust_aggregate_sharded)
-            delta_eff = delta_hat if comp is not None else (P - P_start)
-            if byz is not None and comp is None:
-                delta_eff = delta_eff * byz[:, None]
-            w_raw = (client_weights.astype(jnp.float32)
-                     if weighted and client_weights is not None else None)
-            if sharded:
-                agg_delta, rinfo = robust_aggregate_sharded(
-                    delta_eff, ragg, valid, mesh=mesh, pspec=pspec,
-                    weights=w_raw)
-            else:
-                agg_delta, rinfo = robust_aggregate(
-                    delta_eff, ragg, valid, weights=w_raw,
-                    backend=backend)
-            n_valid = jnp.sum(valid.astype(jnp.float32))
-            # round-start flat params: the replicated engines carry them
-            # exactly in the flat state; sharded re-derives them from the
-            # (identical-row) broadcast buffer to stay on nspec sharding
-            P0 = (constrain(jnp.mean(P_start, axis=0), nspec)
-                  if sharded else fstate.P)
-            agg = flatlib.unpack(constrain(P0 + agg_delta, nspec), layout)
+                delta_hat = None
+                P_agg = P
 
-            def do_update(_):
-                p, s = server_opt.update(gp, agg, fstate.server_state)
-                return pack1(p), s
+            if not is_async and not guard_tail:
+                # aggregate: single (weighted) mean over the packed client
+                # axis — under the sharded engine XLA lowers this to the
+                # FedAvg all-reduce over the client mesh axes; the (N,)
+                # result keeps the flat-dim sharding.
+                if weighted and client_weights is not None:
+                    w = client_weights / jnp.sum(client_weights)
+                    agg_flat = jnp.tensordot(w.astype(jnp.float32), P_agg,
+                                             axes=(0, 0))
+                elif comp is not None and not sharded:
+                    agg_flat = _halving_mean(P_agg)
+                else:
+                    agg_flat = jnp.mean(P_agg, axis=0)
+                agg = flatlib.unpack(constrain(agg_flat, nspec), layout)
+                new_params, sstate = server_opt.update(gp, agg,
+                                                       fstate.server_state)
+                metrics = _round_metrics(losses, S.eta, step_counts)
+                metrics.update(extra)
+                new_fstate = FlatFLState(
+                    pack1(new_params), sstate, fstate.round + 1,
+                    fstate.buffer, fstate.ef if new_ef is None else new_ef)
+            elif not is_async:
+                # fault/robust synchronous tail: the server works in DELTA
+                # space — the RobustAgg ladder (repro.federation.faults)
+                # aggregates the survivors' deltas (clip / trimmed / median /
+                # valid-masked mean) and the result re-anchors on the round-
+                # start params. Under meshes the ladder runs inside
+                # shard_map before/with the client-mean psum, so only (N_loc,)
+                # aggregates ever cross the client shard boundary.
+                from repro.federation.faults import (robust_aggregate,
+                                                     robust_aggregate_sharded)
+                delta_eff = delta_hat if comp is not None else (P - P_start)
+                if byz is not None and comp is None:
+                    delta_eff = delta_eff * byz[:, None]
+                w_raw = (client_weights.astype(jnp.float32)
+                         if weighted and client_weights is not None else None)
+                if sharded:
+                    agg_delta, rinfo = robust_aggregate_sharded(
+                        delta_eff, ragg, valid, mesh=mesh, pspec=pspec,
+                        weights=w_raw)
+                else:
+                    agg_delta, rinfo = robust_aggregate(
+                        delta_eff, ragg, valid, weights=w_raw,
+                        backend=backend)
+                n_valid = jnp.sum(valid.astype(jnp.float32))
+                # round-start flat params: the replicated engines carry them
+                # exactly in the flat state; sharded re-derives them from the
+                # (identical-row) broadcast buffer to stay on nspec sharding
+                P0 = (constrain(jnp.mean(P_start, axis=0), nspec)
+                      if sharded else fstate.P)
+                agg = flatlib.unpack(constrain(P0 + agg_delta, nspec), layout)
 
-            def skip_update(_):
-                return fstate.P, fstate.server_state
+                def do_update(_):
+                    p, s = server_opt.update(gp, agg, fstate.server_state)
+                    return pack1(p), s
 
-            if quorum > 0:
-                # quorum degradation: with < Q valid clients the round
-                # is a no-op carrying the previous params/server state
-                skipped = n_valid < quorum
-                newP, sstate = jax.lax.cond(skipped, skip_update,
-                                            do_update, None)
-                if new_ef is not None:
-                    new_ef = jnp.where(skipped, E, new_ef)
-            else:
-                skipped = jnp.asarray(False)
-                newP, sstate = do_update(None)
-            metrics = _round_metrics(losses, S.eta, mcounts)
-            extra.update(rinfo)
-            extra.update(valid_count=n_valid,
-                         round_skipped=skipped.astype(jnp.float32))
-            if drops_on:
-                extra["drop_frac"] = jnp.mean(
-                    (lanes.drop_step < K).astype(jnp.float32))
-            if byz is not None:
-                extra["byz_frac"] = jnp.mean(
-                    lanes.byzantine.astype(jnp.float32))
-            metrics.update(extra)
-            new_fstate = FlatFLState(
-                newP, sstate, fstate.round + 1, fstate.buffer,
-                fstate.ef if new_ef is None else new_ef)
-        elif not guard_tail:
-            # FedBuff-style async aggregation: one staleness-weighted
-            # reduction over the packed client axis produces the cohort's
-            # delta sum; the server only steps when the buffer holds M
-            # updates (repro.federation.buffer). The buffer keeps its
-            # param-shaped f32 delta tree (layout-independent, and the
-            # known-good form under SPMD meshes); only the params
-            # re-enter the flat carry.
-            from repro.federation.buffer import (buffer_merge, buffer_step,
-                                                 staleness_weights)
-            stale = scenario.draw_staleness(fstate.round, C)
-            w = staleness_weights(stale, scenario.staleness_exp)
-            if weighted and client_weights is not None:
-                w = w * client_weights.astype(jnp.float32)
-            delta_flat = jnp.tensordot(
-                w, delta_hat if comp is not None else (P - P_start),
-                axes=(0, 0))
-            delta_tree = flatlib.unpack(constrain(delta_flat, nspec),
-                                        layout, cast=False)
-            buf = buffer_merge(fstate.buffer, delta_tree, jnp.sum(w), C,
-                               stale)
-            params, sstate, buf, flushed = buffer_step(
-                gp, fstate.server_state, buf, server_opt,
-                scenario.buffer_size)
-            metrics = _round_metrics(losses, S.eta, step_counts)
-            sf = stale.astype(jnp.float32)
-            extra.update(stale_mean=jnp.mean(sf), stale_max=jnp.max(sf),
-                         buffer_fill=buf.count.astype(jnp.float32),
-                         flushed=flushed)
-            metrics.update(extra)
-            new_fstate = FlatFLState(pack1(params), sstate,
-                                     fstate.round + 1, buf,
-                                     fstate.ef if new_ef is None else new_ef)
-        else:
-            # fault/robust async tail: over-stale updates are REJECTED
-            # by the server (valid &= fresh enough), the RobustAgg
-            # ladder aggregates the survivors' deltas, and the buffer
-            # accumulates the robust mean scaled back to Σ wΔ form so
-            # the flush's Σ wΔ / Σ w recovers it. Quorum failures skip
-            # the merge entirely (buffer, params, server state frozen).
-            from repro.federation.buffer import (buffer_merge, buffer_step,
-                                                 staleness_weights)
-            from repro.federation.faults import (robust_aggregate,
-                                                 robust_aggregate_sharded)
-            stale = scenario.draw_staleness(fstate.round, C)
-            if faults_on and fm.overstale_rate > 0.0:
-                stale = jnp.where(lanes.overstale,
-                                  jnp.int32(fm.overstale), stale)
-            valid = valid & (stale <= scenario.staleness_max)
-            w = staleness_weights(stale, scenario.staleness_exp)
-            if weighted and client_weights is not None:
-                w = w * client_weights.astype(jnp.float32)
-            d = delta_hat if comp is not None else (P - P_start)
-            if byz is not None and comp is None:
-                d = d * byz[:, None]
-            if sharded:
-                rob, rinfo = robust_aggregate_sharded(
-                    d, ragg, valid, mesh=mesh, pspec=pspec, weights=w)
-            else:
-                rob, rinfo = robust_aggregate(d, ragg, valid, weights=w,
-                                              backend=backend)
-            vf = valid.astype(jnp.float32)
-            wsum = jnp.sum(w * vf)
-            n_valid = jnp.sum(vf)
-            delta_flat = rob * wsum
-            delta_tree = flatlib.unpack(constrain(delta_flat, nspec),
-                                        layout, cast=False)
+                def skip_update(_):
+                    return fstate.P, fstate.server_state
 
-            def do_round(_):
-                buf = buffer_merge(fstate.buffer, delta_tree, wsum,
-                                   n_valid.astype(jnp.int32), stale)
+                if quorum > 0:
+                    # quorum degradation: with < Q valid clients the round
+                    # is a no-op carrying the previous params/server state
+                    skipped = n_valid < quorum
+                    newP, sstate = jax.lax.cond(skipped, skip_update,
+                                                do_update, None)
+                    if new_ef is not None:
+                        new_ef = jnp.where(skipped, E, new_ef)
+                else:
+                    skipped = jnp.asarray(False)
+                    newP, sstate = do_update(None)
+                metrics = _round_metrics(losses, S.eta, mcounts)
+                extra.update(rinfo)
+                extra.update(valid_count=n_valid,
+                             round_skipped=skipped.astype(jnp.float32))
+                if drops_on:
+                    extra["drop_frac"] = jnp.mean(
+                        (lanes.drop_step < K).astype(jnp.float32))
+                if byz is not None:
+                    extra["byz_frac"] = jnp.mean(
+                        lanes.byzantine.astype(jnp.float32))
+                metrics.update(extra)
+                new_fstate = FlatFLState(
+                    newP, sstate, fstate.round + 1, fstate.buffer,
+                    fstate.ef if new_ef is None else new_ef)
+            elif not guard_tail:
+                # FedBuff-style async aggregation: one staleness-weighted
+                # reduction over the packed client axis produces the cohort's
+                # delta sum; the server only steps when the buffer holds M
+                # updates (repro.federation.buffer). The buffer keeps its
+                # param-shaped f32 delta tree (layout-independent, and the
+                # known-good form under SPMD meshes); only the params
+                # re-enter the flat carry.
+                from repro.federation.buffer import (buffer_merge, buffer_step,
+                                                     staleness_weights)
+                stale = scenario.draw_staleness(fstate.round, C)
+                w = staleness_weights(stale, scenario.staleness_exp)
+                if weighted and client_weights is not None:
+                    w = w * client_weights.astype(jnp.float32)
+                delta_flat = jnp.tensordot(
+                    w, delta_hat if comp is not None else (P - P_start),
+                    axes=(0, 0))
+                delta_tree = flatlib.unpack(constrain(delta_flat, nspec),
+                                            layout, cast=False)
+                buf = buffer_merge(fstate.buffer, delta_tree, jnp.sum(w), C,
+                                   stale)
                 params, sstate, buf, flushed = buffer_step(
                     gp, fstate.server_state, buf, server_opt,
                     scenario.buffer_size)
-                return pack1(params), sstate, buf, flushed
-
-            def skip_round(_):
-                return (fstate.P, fstate.server_state, fstate.buffer,
-                        jnp.float32(0.0))
-
-            if quorum > 0:
-                skipped = n_valid < quorum
-                newP, sstate, buf, flushed = jax.lax.cond(
-                    skipped, skip_round, do_round, None)
-                if new_ef is not None:
-                    new_ef = jnp.where(skipped, E, new_ef)
+                metrics = _round_metrics(losses, S.eta, step_counts)
+                sf = stale.astype(jnp.float32)
+                extra.update(stale_mean=jnp.mean(sf), stale_max=jnp.max(sf),
+                             buffer_fill=buf.count.astype(jnp.float32),
+                             flushed=flushed)
+                metrics.update(extra)
+                new_fstate = FlatFLState(
+                    pack1(params), sstate, fstate.round + 1, buf,
+                    fstate.ef if new_ef is None else new_ef)
             else:
-                skipped = jnp.asarray(False)
-                newP, sstate, buf, flushed = do_round(None)
-            metrics = _round_metrics(losses, S.eta, mcounts)
-            sf = stale.astype(jnp.float32)
-            extra.update(stale_mean=jnp.mean(sf), stale_max=jnp.max(sf),
-                         buffer_fill=buf.count.astype(jnp.float32),
-                         flushed=flushed)
-            extra.update(rinfo)
-            extra.update(valid_count=n_valid,
-                         round_skipped=skipped.astype(jnp.float32))
-            if drops_on:
-                extra["drop_frac"] = jnp.mean(
-                    (lanes.drop_step < K).astype(jnp.float32))
-            if byz is not None:
-                extra["byz_frac"] = jnp.mean(
-                    lanes.byzantine.astype(jnp.float32))
-            if faults_on and fm.overstale_rate > 0.0:
-                extra["overstale_frac"] = jnp.mean(
-                    lanes.overstale.astype(jnp.float32))
-            metrics.update(extra)
-            new_fstate = FlatFLState(newP, sstate, fstate.round + 1, buf,
-                                     fstate.ef if new_ef is None else new_ef)
+                # fault/robust async tail: over-stale updates are REJECTED
+                # by the server (valid &= fresh enough), the RobustAgg
+                # ladder aggregates the survivors' deltas, and the buffer
+                # accumulates the robust mean scaled back to Σ wΔ form so
+                # the flush's Σ wΔ / Σ w recovers it. Quorum failures skip
+                # the merge entirely (buffer, params, server state frozen).
+                from repro.federation.buffer import (buffer_merge, buffer_step,
+                                                     staleness_weights)
+                from repro.federation.faults import (robust_aggregate,
+                                                     robust_aggregate_sharded)
+                stale = scenario.draw_staleness(fstate.round, C)
+                if faults_on and fm.overstale_rate > 0.0:
+                    stale = jnp.where(lanes.overstale,
+                                      jnp.int32(fm.overstale), stale)
+                valid = valid & (stale <= scenario.staleness_max)
+                w = staleness_weights(stale, scenario.staleness_exp)
+                if weighted and client_weights is not None:
+                    w = w * client_weights.astype(jnp.float32)
+                d = delta_hat if comp is not None else (P - P_start)
+                if byz is not None and comp is None:
+                    d = d * byz[:, None]
+                if sharded:
+                    rob, rinfo = robust_aggregate_sharded(
+                        d, ragg, valid, mesh=mesh, pspec=pspec, weights=w)
+                else:
+                    rob, rinfo = robust_aggregate(d, ragg, valid, weights=w,
+                                                  backend=backend)
+                vf = valid.astype(jnp.float32)
+                wsum = jnp.sum(w * vf)
+                n_valid = jnp.sum(vf)
+                delta_flat = rob * wsum
+                delta_tree = flatlib.unpack(constrain(delta_flat, nspec),
+                                            layout, cast=False)
 
-        return new_fstate, metrics, RoundAux(P, S.eta, S.valid)
+                def do_round(_):
+                    buf = buffer_merge(fstate.buffer, delta_tree, wsum,
+                                       n_valid.astype(jnp.int32), stale)
+                    params, sstate, buf, flushed = buffer_step(
+                        gp, fstate.server_state, buf, server_opt,
+                        scenario.buffer_size)
+                    return pack1(params), sstate, buf, flushed
+
+                def skip_round(_):
+                    return (fstate.P, fstate.server_state, fstate.buffer,
+                            jnp.float32(0.0))
+
+                if quorum > 0:
+                    skipped = n_valid < quorum
+                    newP, sstate, buf, flushed = jax.lax.cond(
+                        skipped, skip_round, do_round, None)
+                    if new_ef is not None:
+                        new_ef = jnp.where(skipped, E, new_ef)
+                else:
+                    skipped = jnp.asarray(False)
+                    newP, sstate, buf, flushed = do_round(None)
+                metrics = _round_metrics(losses, S.eta, mcounts)
+                sf = stale.astype(jnp.float32)
+                extra.update(stale_mean=jnp.mean(sf), stale_max=jnp.max(sf),
+                             buffer_fill=buf.count.astype(jnp.float32),
+                             flushed=flushed)
+                extra.update(rinfo)
+                extra.update(valid_count=n_valid,
+                             round_skipped=skipped.astype(jnp.float32))
+                if drops_on:
+                    extra["drop_frac"] = jnp.mean(
+                        (lanes.drop_step < K).astype(jnp.float32))
+                if byz is not None:
+                    extra["byz_frac"] = jnp.mean(
+                        lanes.byzantine.astype(jnp.float32))
+                if faults_on and fm.overstale_rate > 0.0:
+                    extra["overstale_frac"] = jnp.mean(
+                        lanes.overstale.astype(jnp.float32))
+                metrics.update(extra)
+                new_fstate = FlatFLState(
+                    newP, sstate, fstate.round + 1, buf,
+                    fstate.ef if new_ef is None else new_ef)
+
+            return new_fstate, metrics, RoundAux(P, S.eta, S.valid)
 
     def round_fn(state: FLState, client_batches, client_weights=None,
                  prev_local_params=None):

@@ -12,7 +12,9 @@ buffer that one 2-D-grid kernel sweeps in a single launch.
 Layout is computed once per (treedef, shapes, dtypes) and cached; packing
 is one concatenate, unpacking is slice + reshape + cast views. Tail
 padding is zero-filled so global norm reductions over the padded buffer
-are exact.
+are exact. Every pack and unpack runs under the named scope ``flat``, so
+a device trace can tell the flat layer's passes from the work around
+them (``perfbench/metrics/flat_ms_per_round.py`` reads it).
 
 Mixed precision: the buffer is always f32. Leaves whose dtype is narrower
 (bf16) are tracked by ``round_mask`` — a per-element mask the fused apply
@@ -115,11 +117,12 @@ def pack(tree, layout: Optional[FlatLayout] = None) -> jax.Array:
     """Pytree -> (N,) f32 buffer (zero tail padding). One concatenate."""
     layout = layout or layout_of(tree)
     leaves = jax.tree_util.tree_leaves(tree)
-    parts = [l.reshape(-1).astype(jnp.float32) for l in leaves]
-    pad = layout.padded_size - layout.size
-    if pad:
-        parts.append(jnp.zeros((pad,), jnp.float32))
-    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+    with jax.named_scope("flat"):
+        parts = [l.reshape(-1).astype(jnp.float32) for l in leaves]
+        pad = layout.padded_size - layout.size
+        if pad:
+            parts.append(jnp.zeros((pad,), jnp.float32))
+        return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
 def unpack(buf: jax.Array, layout: FlatLayout, *, cast: bool = True):
@@ -128,11 +131,12 @@ def unpack(buf: jax.Array, layout: FlatLayout, *, cast: bool = True):
     ``cast=False`` keeps every leaf in the buffer's f32 — used by the
     async aggregation buffer, whose delta accumulator must not lose the
     sub-bf16 bits of a weighted delta sum."""
-    leaves = [buf[s.offset:s.offset + s.size].reshape(s.shape)
-              for s in layout.leaves]
-    if cast:
-        leaves = [l.astype(s.dtype)
-                  for l, s in zip(leaves, layout.leaves)]
+    with jax.named_scope("flat"):
+        leaves = [buf[s.offset:s.offset + s.size].reshape(s.shape)
+                  for s in layout.leaves]
+        if cast:
+            leaves = [l.astype(s.dtype)
+                      for l, s in zip(leaves, layout.leaves)]
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)
 
 
@@ -141,11 +145,13 @@ def pack_batched(tree, layout: Optional[FlatLayout] = None) -> jax.Array:
     layout = layout or layout_of(tree, batched=True)
     leaves = jax.tree_util.tree_leaves(tree)
     C = leaves[0].shape[0]
-    parts = [l.reshape(C, -1).astype(jnp.float32) for l in leaves]
-    pad = layout.padded_size - layout.size
-    if pad:
-        parts.append(jnp.zeros((C, pad), jnp.float32))
-    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+    with jax.named_scope("flat"):
+        parts = [l.reshape(C, -1).astype(jnp.float32) for l in leaves]
+        pad = layout.padded_size - layout.size
+        if pad:
+            parts.append(jnp.zeros((C, pad), jnp.float32))
+        return (jnp.concatenate(parts, axis=1) if len(parts) > 1
+                else parts[0])
 
 
 def unpack_batched(buf: jax.Array, layout: FlatLayout, *,
@@ -156,8 +162,10 @@ def unpack_batched(buf: jax.Array, layout: FlatLayout, *,
     per-client EF21 error-feedback state (repro.compression), whose
     reconstruction tree must not lose sub-bf16 bits between rounds."""
     C = buf.shape[0]
-    leaves = [buf[:, s.offset:s.offset + s.size].reshape((C,) + s.shape)
-              for s in layout.leaves]
-    if cast:
-        leaves = [l.astype(s.dtype) for l, s in zip(leaves, layout.leaves)]
+    with jax.named_scope("flat"):
+        leaves = [buf[:, s.offset:s.offset + s.size].reshape((C,) + s.shape)
+                  for s in layout.leaves]
+        if cast:
+            leaves = [l.astype(s.dtype)
+                      for l, s in zip(leaves, layout.leaves)]
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)

@@ -266,11 +266,17 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
     metrics device_get after the block executes, and the JSONL
     ``events`` sink flushes exactly there (tests/test_telemetry.py runs
     a block under ``jax.transfer_guard("disallow")`` to pin this).
-    ``spans`` accumulates pack/stage/block_execute/convert/ckpt
-    wall-clock. ``--profile r`` profiles the block containing (1-based)
-    round r: an HLO-derived static telemetry row — collective count +
-    payload bytes per round (roofline.parse_collectives), Pallas launch
-    counts per namespace — is emitted at compile time via an AOT
+    ``spans`` accumulates host wall-clock per span, once a block for
+    ``stage`` (the block's batches onto the device), ``dispatch`` (the
+    enqueue of the block program, and any compile), ``wait`` (the host
+    waiting for the device) and ``fetch`` (the metrics transfer), plus
+    ``pack``/``unpack`` once a run and ``ckpt`` per save; under a
+    ``jax.profiler`` trace each span is also a ``repro.<name>`` host
+    event (telemetry.SpanTimer). ``--profile r`` profiles the block
+    containing (1-based) round r: an HLO-derived static telemetry row —
+    collective count + payload bytes per round
+    (roofline.parse_collectives), Pallas launch counts per namespace —
+    is emitted at compile time via an AOT
     lower+compile (one extra XLA compile, profiling runs only), and the
     block executes under a ``jax.profiler`` trace written to
     ``--profile-dir``."""
@@ -318,7 +324,7 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
                 return jloop((fs, c), d, arena=a)
             return jloop(fs, d, arena=a)
 
-        with spans.span("block_execute"):
+        with spans.span("dispatch"):
             if do_profile:
                 out = trace_block(call, getattr(args, "profile_dir",
                                                 "experiments/profile"))
@@ -329,9 +335,11 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
             (fstate, car), mets = out
         else:
             fstate, mets = out
-        # the block boundary is the host-sync point: ONE batched
-        # device_get for all R rounds' metric rows
-        with spans.span("convert"):
+        # the block boundary is the host-sync point: wait for the device,
+        # then ONE batched device_get for all R rounds' metric rows
+        with spans.span("wait"):
+            jax.block_until_ready(mets)
+        with spans.span("fetch"):
             mets = jax.device_get(mets)
         for r in range(n):
             row = {k: v[r] for k, v in mets.items()}

@@ -35,7 +35,6 @@ def static_telemetry(compiled, *, rounds: int = 1,
         "collective_bytes_per_round": sum(c.bytes for c in colls) / rounds,
         "collective_wire_bytes": float(sum(c.wire_bytes for c in colls)),
         "collective_kinds": sorted({c.kind for c in colls}),
-        "hlo_instructions": hlo.count("\n"),
     }
     if launches is not None:
         row["pallas_launches"] = dict(launches)

@@ -1,10 +1,14 @@
 """Lightweight span timing for the launch drivers.
 
 Wall-clock accounting over named phases (compile / pack / stage /
-block-execute / eval / ckpt) with near-zero overhead: one
-``perf_counter`` pair per span, accumulated in a dict. The summary
-lands in the event log's ``spans`` event and the end-of-run print —
-the coarse picture a ``--profile`` trace then drills into.
+dispatch / wait / fetch / eval / ckpt; ``launch/train._run_fused`` says
+what each of the block driver's spans holds) with near-zero overhead:
+one ``perf_counter`` pair per span, accumulated in a dict. The summary
+lands in the event log's ``spans`` event and the end-of-run print.
+Each span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``, so a ``--profile`` trace carries the host spans on
+the device trace's clock; with no trace running the annotation costs
+a check of a flag.
 """
 from __future__ import annotations
 
@@ -12,9 +16,13 @@ import time
 from contextlib import contextmanager
 from typing import Dict
 
+import jax
+
+PREFIX = "repro."
+
 
 class SpanTimer:
-    """Accumulating span timer: ``with spans.span("block_execute"): ...``."""
+    """Accumulating span timer: ``with spans.span("dispatch"): ...``."""
 
     def __init__(self):
         self._acc: Dict[str, list] = {}
@@ -23,19 +31,13 @@ class SpanTimer:
     def span(self, name: str):
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(PREFIX + name):
+                yield
         finally:
             dt = time.perf_counter() - t0
             cell = self._acc.setdefault(name, [0.0, 0])
             cell[0] += dt
             cell[1] += 1
-
-    def add(self, name: str, seconds: float) -> None:
-        """Manual accumulation for spans not expressible as a with
-        block (e.g. compile time split out of the first block call)."""
-        cell = self._acc.setdefault(name, [0.0, 0])
-        cell[0] += seconds
-        cell[1] += 1
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {k: {"s": round(v[0], 6), "n": v[1]}
