@@ -261,6 +261,15 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
     resume-parity test in tests/test_serving.py pins bit-exactness
     across a mid-run restart).
 
+    Staging runs one block ahead: the first block stages up front, and
+    every later block's ``stage_block`` call comes right after the
+    dispatch of the block before it, while that block runs on the
+    device, so the host's staging overlaps the device's work. The calls
+    come one a block, in round order, each with its block's ``(round0,
+    n)``, and never past ``rounds``; the wait, fetch, ``on_round``,
+    events and checkpoint of block n all run before block n+1 is
+    dispatched.
+
     Observability (repro.telemetry): the block is the host-sync
     boundary — the ONLY host transfer per block is the single batched
     metrics device_get after the block executes, and the JSONL
@@ -269,10 +278,14 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
     ``spans`` accumulates host wall-clock per span, once a block for
     ``stage`` (the block's batches onto the device), ``dispatch`` (the
     enqueue of the block program, and any compile), ``wait`` (the host
-    waiting for the device) and ``fetch`` (the metrics transfer), plus
-    ``pack``/``unpack`` once a run and ``ckpt`` per save; under a
-    ``jax.profiler`` trace each span is also a ``repro.<name>`` host
-    event (telemetry.SpanTimer). ``--profile r`` profiles the block
+    waiting for the device) and ``fetch`` (the metrics transfer);
+    ``stage_ahead``, nested inside ``stage``, marks each staging that
+    runs while the block before it is in flight: that of every block
+    but the first and the one after a profiled block (the profiler's
+    trace has waited for that block to end); plus ``pack``/``unpack``
+    once a run and ``ckpt`` per save; under a ``jax.profiler`` trace
+    each span is also a ``repro.<name>`` host event
+    (telemetry.SpanTimer). ``--profile r`` profiles the block
     containing (1-based) round r: an HLO-derived static telemetry row —
     collective count + payload bytes per round
     (roofline.parse_collectives), Pallas launch counts per namespace —
@@ -296,10 +309,19 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
     base, t = int(state.round), 0
     profile_round = getattr(args, "profile", 0)
     profiled = False
+
+    def stage(t0, ahead):
+        n0 = min(R, rounds - t0)
+        with spans.span("stage"):
+            if not ahead:
+                return stage_block(base + t0, n0)
+            with spans.span("stage_ahead"):
+                return stage_block(base + t0, n0)
+
+    staged = stage(0, ahead=False) if rounds > 0 else None
     while t < rounds:
         n = min(R, rounds - t)
-        with spans.span("stage"):
-            data, arena = stage_block(base + t, n)
+        data, arena = staged
 
         do_profile = (profile_round > 0 and not profiled
                       and t <= profile_round - 1 < t + n)
@@ -335,6 +357,10 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
             (fstate, car), mets = out
         else:
             fstate, mets = out
+        # the next block's batches while this one runs (a profiled
+        # block has already run: its trace waited for it)
+        if t + n < rounds:
+            staged = stage(t + n, ahead=not do_profile)
         # the block boundary is the host-sync point: wait for the device,
         # then ONE batched device_get for all R rounds' metric rows
         with spans.span("wait"):

@@ -1,9 +1,10 @@
 """Lightweight span timing for the launch drivers.
 
 Wall-clock accounting over named phases (compile / pack / stage /
-dispatch / wait / fetch / eval / ckpt; ``launch/train._run_fused`` says
-what each of the block driver's spans holds) with near-zero overhead:
-one ``perf_counter`` pair per span, accumulated in a dict. The summary
+stage_ahead / dispatch / wait / fetch / eval / ckpt;
+``launch/train._run_fused`` says what each of the block driver's spans
+holds) with near-zero overhead: one ``perf_counter`` pair per span,
+accumulated in a dict. The summary
 lands in the event log's ``spans`` event and the end-of-run print.
 Each span is also a ``jax.profiler.TraceAnnotation`` named
 ``repro.<name>``, so a ``--profile`` trace carries the host spans on

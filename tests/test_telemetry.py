@@ -5,8 +5,10 @@ plus kernel-vs-reference parity, launch-counter namespacing (the Δ-SGD
 2-launch/step budget is counted separately from telemetry launches),
 the zero-host-transfer guarantee inside a fused block, the typed
 schema registry, the JSONL event log, and the report-layer guards."""
+import contextlib
 import json
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -276,7 +278,6 @@ def test_round_bodies_name_their_layers(engine, rng):
 def _drive_blocks(rng, rounds, spans):
     """``rounds`` rounds of the tiny problem through the fused block
     driver, R rounds a block."""
-    import types
     from repro.launch.train import _run_fused
     loss, params, batches = _problem(rng)
     copt, sopt = _opts()
@@ -294,14 +295,16 @@ def _drive_blocks(rng, rounds, spans):
 
 def test_run_fused_spans_once_a_block(rng):
     """The block driver's host spans: stage, dispatch, wait and fetch
-    once a block, pack and unpack once a run, nothing else."""
+    once a block, stage_ahead once a block but the first, pack and
+    unpack once a run, nothing else."""
     spans = SpanTimer()
     _drive_blocks(rng, 3 * R, spans)
     s = spans.summary()
-    assert set(s) == {"pack", "stage", "dispatch", "wait", "fetch",
-                      "unpack"}
+    assert set(s) == {"pack", "stage", "stage_ahead", "dispatch", "wait",
+                      "fetch", "unpack"}
     for name in ("stage", "dispatch", "wait", "fetch"):
         assert s[name]["n"] == 3, name
+    assert s["stage_ahead"]["n"] == 3 - 1
     assert s["pack"]["n"] == s["unpack"]["n"] == 1
 
 
@@ -324,7 +327,154 @@ def test_span_timer_writes_host_events_to_a_trace(rng, tmp_path):
              if e.name.startswith("repro.")]
     for span in ("stage", "dispatch", "wait", "fetch"):
         assert names.count("repro." + span) == 2, span
+    assert names.count("repro.stage_ahead") == 2 - 1
     assert "repro.pack" in names and "repro.unpack" in names
+
+
+class _SpanLog:
+    """A span object for the block driver that logs each span's entry
+    and exit, in order, into a list it shares with the stage function."""
+
+    def __init__(self, log):
+        self.log = log
+
+    @contextlib.contextmanager
+    def span(self, name):
+        self.log.append(("enter", name))
+        try:
+            yield
+        finally:
+            self.log.append(("exit", name))
+
+
+def _logged_problem(rng, rounds):
+    """The tiny problem's loop and state, with distinct batches for
+    every round, so a block staged with the wrong rounds shows."""
+    loss, params, _ = _problem(rng)
+    copt, sopt = _opts()
+    loop = make_fl_loop(loss, copt, sopt, params_like=params,
+                        num_rounds=rounds, rounds_per_call=R, flat="xla")
+    pool = [{"A": rng.normal(size=(C, K, 4, D)).astype(np.float32),
+             "b": rng.normal(size=(C, K, 4)).astype(np.float32)}
+            for _ in range(rounds)]
+
+    def block(round0, n):
+        return {k: jnp.asarray(np.stack([pool[round0 + i][k]
+                                         for i in range(n)]))
+                for k in pool[0]}
+
+    return loop, init_fl_state(params, sopt), block
+
+
+def _drive_logged(loop, state, block, rounds, log=None, **args):
+    """``rounds`` rounds through the block driver with a logging span
+    object and a logging ``stage_block``; returns the final state, the
+    metric rows and the log."""
+    from repro.launch.train import _run_fused
+    log = [] if log is None else log
+    rows = []
+
+    def stage_block(round0, n):
+        log.append(("stage_block", round0, n))
+        return block(round0, n), None
+
+    args = types.SimpleNamespace(**{"rounds_per_call": R,
+                                    "ckpt_dir": None,
+                                    "ckpt_every": 10 ** 9,
+                                    "profile": 0, **args})
+    state = _run_fused(args, loop, state, rounds, stage_block,
+                       lambda t, row: rows.append(row),
+                       spans=_SpanLog(log))
+    return state, rows, log
+
+
+def _where(log, entry):
+    return [i for i, e in enumerate(log) if e == entry]
+
+
+@pytest.mark.parametrize("rounds", [3 * R, 2 * R + 1])
+def test_run_fused_stages_one_block_ahead(rng, rounds):
+    """``stage_block`` runs once a block, in round order, never past
+    ``rounds`` (a short last block gets its own length); every block
+    but the first is staged after the dispatch of the block before it
+    and before the wait for it, inside a ``stage_ahead`` span."""
+    loop, state, block = _logged_problem(rng, rounds)
+    _, rows, log = _drive_logged(loop, state, block, rounds)
+    assert len(rows) == rounds
+    blocks = [(r0, min(R, rounds - r0)) for r0 in range(0, rounds, R)]
+    calls = [i for i, e in enumerate(log) if e[0] == "stage_block"]
+    assert [log[i][1:] for i in calls] == blocks
+    dispatched = _where(log, ("exit", "dispatch"))
+    waited = _where(log, ("enter", "wait"))
+    assert len(dispatched) == len(waited) == len(blocks)
+    assert calls[0] < _where(log, ("enter", "dispatch"))[0]
+    assert log[calls[0] - 1] == ("enter", "stage")
+    for k in range(1, len(blocks)):
+        assert dispatched[k - 1] < calls[k] < waited[k - 1], k
+        assert log[calls[k] - 2:calls[k]] == [("enter", "stage"),
+                                              ("enter", "stage_ahead")]
+    assert len(_where(log, ("enter", "stage_ahead"))) == len(blocks) - 1
+
+
+def test_run_fused_matches_serial_blocks_bit_exact(rng):
+    """Staging ahead changes no number: params and every metric row
+    equal those of the jitted loop called block by block, serially, on
+    the same batches (a short last block included)."""
+    rounds = 2 * R + 1
+    loop, state, block = _logged_problem(rng, rounds)
+    got, rows, _ = _drive_logged(loop, state, block, rounds)
+    jloop = jax.jit(loop, donate_argnums=0)
+    fs = flatten_fl_state(state, loop.layout)
+    want = []
+    for r0 in range(0, rounds, R):
+        n = min(R, rounds - r0)
+        fs, mets = jloop(fs, block(r0, n))
+        mets = jax.device_get(mets)
+        want += [{k: v[r] for k, v in mets.items()} for r in range(n)]
+    _assert_trees_equal(got.params, unflatten_fl_state(fs, loop.layout)
+                        .params)
+    assert len(rows) == len(want) == rounds
+    for a, b in zip(rows, want):
+        assert a.keys() == b.keys()
+        for k in a:
+            np.testing.assert_array_equal(np.asarray(a[k]),
+                                          np.asarray(b[k]), err_msg=k)
+
+
+def test_run_fused_profiles_one_block_with_staging_ahead(rng, tmp_path,
+                                                         monkeypatch):
+    """``--profile`` lowers, compiles and traces exactly one block: no
+    staging runs inside its trace, and the block after it is staged
+    once the trace has ended, outside ``stage_ahead``."""
+    import repro.telemetry as telemetry
+    rounds = 3 * R
+    loop, state, block = _logged_problem(rng, rounds)
+    real = telemetry.trace_block
+    log = []
+
+    def logged_trace(fn, logdir):
+        log.append(("enter", "trace_block"))
+        try:
+            return real(fn, logdir)
+        finally:
+            log.append(("exit", "trace_block"))
+
+    monkeypatch.setattr(telemetry, "trace_block", logged_trace)
+    _, rows, _ = _drive_logged(loop, state, block, rounds, log=log,
+                               profile=R + 1, profile_dir=str(tmp_path))
+    assert len(rows) == rounds
+    calls = [i for i, e in enumerate(log) if e[0] == "stage_block"]
+    assert [log[i][1:] for i in calls] == [(0, R), (R, R), (2 * R, R)]
+    assert len(_where(log, ("enter", "compile"))) == 1
+    [start] = _where(log, ("enter", "trace_block"))
+    [end] = _where(log, ("exit", "trace_block"))
+    assert not [i for i in calls if start < i < end]
+    # block 1 (rounds R+1..2R) is the profiled one: it was staged ahead
+    # while block 0 ran, and block 2 after the trace, as a plain stage
+    assert calls[2] > end
+    assert log[calls[2] - 1] == ("enter", "stage")
+    assert log[calls[1] - 1] == ("enter", "stage_ahead")
+    assert len(_where(log, ("enter", "stage_ahead"))) == 1
 
 
 # ----------------------------------------------------- spec + registry
