@@ -41,11 +41,13 @@ def change_norms(new, old):
         new, old))
 
 
-def run_rounds(loss_fn, params, rounds, hyper):
+def run_rounds(loss_fn, params, rounds, hyper, step_counts=None):
     """``rounds``: list of client batch dicts with leaves (C, K, ...).
     ``loss_fn(params, batch) -> scalar``, already jitted or jittable;
     it fixes the precision. ``hyper``: ``gamma``, ``delta``, ``eta0``
-    and ``theta0``.
+    and ``theta0``. ``step_counts``, where given, holds per round the
+    (C,) local step budgets: client c takes only its first K_c steps,
+    and the round's loss is the mean over the steps that ran.
 
     Returns (final params, per-round list of {loss, eta_mean}, per-leaf
     norms of the first round's change)."""
@@ -63,13 +65,14 @@ def run_rounds(loss_fn, params, rounds, hyper):
         lambda a, b: (a / n).astype(b.dtype), s, like))
     rows, first = [], None
     P = params
-    for batches in rounds:
+    for r, batches in enumerate(rounds):
         C = jax.tree.leaves(batches)[0].shape[0]
         K = jax.tree.leaves(batches)[0].shape[1]
         acc, losses, etas = None, [], []
         for c in range(C):
             x, gprev, eta, theta, pgn = P, None, eta0, theta0, 0.0
-            for k in range(K):
+            steps = K if step_counts is None else int(step_counts[r][c])
+            for k in range(steps):
                 b = jax.tree.map(lambda a: a[c, k], batches)
                 l, g = vg(x, b)
                 losses.append(float(l))

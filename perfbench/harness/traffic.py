@@ -3,8 +3,8 @@ file (``perfbench/traffic/<mix>.json``) and makes, from the seed, the
 inputs of a run.
 
 Every seed gets the same sizes, so the work of a run does not depend on
-the seed; what the seed changes is the token ids and frames
-themselves.
+the seed; what the seed changes is the token ids and frames, or the
+examples and the fleet's starting round, themselves.
 """
 from __future__ import annotations
 
@@ -43,3 +43,33 @@ def stack_rounds(pool, round0, n):
     leading round axis, as host arrays."""
     rows = [pool[(round0 + i) % len(pool)] for i in range(n)]
     return {k: np.stack([r[k] for r in rows]) for k in rows[0]}
+
+
+# ---------------------------------------------------------------------------
+# fleet: the examples every registered client's partition is cut from
+# ---------------------------------------------------------------------------
+def gaussian_mixture(seed, task):
+    """Training examples of a Gaussian-mixture task from the mix's
+    ``task`` block: ``classes`` means of norm ``margin`` in ``dim``
+    dimensions, ``scale``-wide noise, a random rotation, and with
+    ``nonlinear`` the warp tanh(x) + 0.1 x^2. Returns (x (n, dim)
+    float32, y (n,) int32), ``n_train`` rows."""
+    rng = rng_for(seed, 2)
+    k, d = task["classes"], task["dim"]
+    means = rng.normal(size=(k, d)).astype(np.float32)
+    means *= task["margin"] / np.linalg.norm(means, axis=1, keepdims=True)
+    rot = np.linalg.qr(rng.normal(size=(d, d)))[0].astype(np.float32)
+    y = rng.integers(0, k, task["n_train"]).astype(np.int32)
+    x = means[y] + task["scale"] * rng.normal(
+        size=(task["n_train"], d)).astype(np.float32)
+    x = x @ rot
+    if task["nonlinear"]:
+        x = np.tanh(x) + 0.1 * x ** 2
+    return x.astype(np.float32), y
+
+
+def start_round(seed, mix):
+    """The round the fleet's state starts at: drawn from the seed, so
+    that each seed's rounds draw other cohorts and budgets while the
+    compiled program, whose scenario seed is fixed, stays the same."""
+    return int(rng_for(seed, 3).integers(0, mix["start_round_max"]))

@@ -5,8 +5,10 @@ import sys
 
 import pytest
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
+sys.path.insert(0, HERE)
 
 from harness.common import load_cell, load_module, peaks_for  # noqa: E402
 
@@ -42,6 +44,27 @@ def test_round_counts_of_the_training_cells():
     n = 56_458_752
     # per local step: norms read G, G_prev; apply reads P, G, writes P
     assert fedtune.pair_bytes_per_round(lm, n) == 5 * 4 * n * 4 * 2
+
+
+def test_fleet_round_counts():
+    """The fleet cell's round at C x K_max client steps: 50 clients x 7
+    local steps of the 32-64-64-10 MLP over batches of 64."""
+    from types import SimpleNamespace
+
+    from drivers import fleet
+    from fleet_pending import fleet_cell
+    cell = fleet_cell()
+    ref = cell.reference
+    # 32*64 + 64*64 + 64*10 = 6784 multiply-adds an example, x 2 x 3
+    assert ref.train_flops(cell.config, 64) == 6 * 64 * 6784
+    assert ref.num_params(cell.config) == 2112 + 4160 + 650
+    b = SimpleNamespace(C=50, K=7, b=64, cfg=cell.config, ref=ref,
+                        loop=SimpleNamespace(
+                            layout=SimpleNamespace(padded_size=7040)))
+    got = fleet.counts(b)
+    assert got["flops_per_round"] == 6 * 64 * 6784 * 350
+    assert got["pair_bytes_per_round"] == 5 * 4 * 6922 * 350
+    assert got["pair_shape"] == "f32[50,55,128]"
 
 
 def reader(name):

@@ -8,10 +8,13 @@ import sys
 
 import pytest
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
+sys.path.insert(0, HERE)
 
+from fleet_pending import bench_with_fleet, fleet_cell  # noqa: E402
 from harness.common import load_cell, load_json, load_module  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -109,6 +112,55 @@ def test_every_name_has_its_files(bench):
         mod = load_module(os.path.join(BENCH, "metrics",
                                        m["name"] + ".py"))
         assert callable(mod.read)
+
+
+def test_the_held_back_fleet_entries_keep_the_contract():
+    """With the fleet cell's entries added, the file still keeps every
+    rule above, and every name the entries use has its file."""
+    merged = bench_with_fleet()
+    test_top_level(merged)
+    test_names_and_units(merged)
+    test_bounds(merged)
+    test_cells_report_what_their_metrics_move(merged)
+    test_chips_and_configs(merged)
+    cell = fleet_cell()
+    assert os.path.exists(os.path.join(BENCH, "drivers",
+                                       cell.mix["kind"] + ".py"))
+    assert hasattr(cell.reference, "init_params") and cell.limits
+    assert {m["name"] for m in cell.per_layer} >= {
+        "grad_eval_ms_per_round", "flat_ms_per_round",
+        "delta_sgd_step_ms_per_round", "round_tail_ms_per_round"}
+    for m in cell.per_layer:
+        mod = load_module(os.path.join(BENCH, "metrics",
+                                       m["name"] + ".py"))
+        assert callable(mod.read)
+
+
+def test_fleet_files_match_the_program():
+    """The fleet cell's configuration file holds the sizes the program's
+    paper-task MLP has, its traffic the fleet_zipf preset's values, and
+    its limits an exact comparison of cohorts and arena rows."""
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    from repro.configs.paper_tasks import MLP_SMALL
+    from repro.federation import get_scenario
+    cell = fleet_cell()
+    cfg, mix = cell.config, cell.mix
+    assert cfg["program"]["config"] == "MLP_SMALL"
+    assert (cfg["input_dim"], tuple(cfg["hidden_dims"]),
+            cfg["num_classes"]) == (MLP_SMALL.input_dim,
+                                    MLP_SMALL.hidden_dims,
+                                    MLP_SMALL.num_classes)
+    preset = get_scenario(mix["scenario"]["preset"])
+    for k in ("scheduler", "zipf_s", "speed", "k_min_frac",
+              "aggregation"):
+        assert getattr(preset, k) == mix["scenario"][k], k
+    assert mix["registered"] == preset.registered_hint
+    assert mix["participation"] == preset.participation_hint
+    assert mix["alpha"] == preset.alpha
+    assert round(mix["participation"] * mix["registered"]) == 50
+    assert mix["samples_per_partition"] // mix["batch"] == 7
+    assert cell.limits["cohort"] == 0 and cell.limits["arena"] == 0
+    assert {"loss", "eta", "change"} <= set(cell.limits)
 
 
 def test_a_cell_added_by_files_alone(tmp_path):

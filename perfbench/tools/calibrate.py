@@ -6,7 +6,12 @@ cell's own size, many seeds in one process (one set-up, one compile):
 * ``control``: the reference in bfloat16 put in the program's place,
   compared the same way (the configuration states float32);
 * ``half_batch``: a fault planted in the reference put in the program's
-  place, the loss over half of each batch's tokens.
+  place, the loss over half of each batch's tokens;
+* fleet cells: ``control`` as above, and the faults ``cohort_shifted``
+  (each round trains the cohort drawn for the next), ``budgets_ignored``
+  (every client runs K_max steps) and ``scatter_skipped`` (the arena
+  keeps its rows) and ``half_batch``, each planted in the reference put
+  in the program's place.
 
     python3 perfbench/tools/calibrate.py --workload <name> \
         --seeds 1 2 3 ... [--controls]
@@ -56,6 +61,32 @@ def fedtune(cell, seeds, controls):
         print(json.dumps(out), flush=True)
 
 
+def fleet(cell, seeds, controls):
+    import jax.numpy as jnp
+    from drivers.fleet import Build, compare
+    from harness.common import Spans
+    b = Build(cell)
+    faults = {"control": {"dtype": jnp.bfloat16},
+              "cohort_shifted": {"cohort_shift": 1},
+              "budgets_ignored": {"full_budgets": True},
+              "scatter_skipped": {"skip_scatter": True},
+              "half_batch": {"half_batch": True}}
+    for seed in seeds:
+        t0 = time.perf_counter()
+        prog = b.first_block(seed, Spans())
+        t1 = time.perf_counter()
+        ref = b.reference(seed, prog["t0"])
+        t2 = time.perf_counter()
+        out = {"seed": seed, "start_round": prog["t0"],
+               "program": compare(prog, ref), "block_s": t1 - t0,
+               "reference_s": t2 - t1}
+        if controls:
+            for name, kw in faults.items():
+                got = b.reference(seed, prog["t0"], **kw)
+                out[name] = compare(got, ref)
+        print(json.dumps(out), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -69,6 +100,8 @@ def main():
     enable_compile_cache()
     if cell.mix["kind"] == "fedtune":
         fedtune(cell, args.seeds, args.controls)
+    elif cell.mix["kind"] == "fleet":
+        fleet(cell, args.seeds, args.controls)
     else:
         raise SystemExit(f"no calibration for kind {cell.mix['kind']!r}")
 
