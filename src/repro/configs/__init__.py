@@ -8,6 +8,7 @@ _ARCH_MODULES = {
     "codeqwen1.5-7b": "codeqwen1_5_7b",
     "olmoe-1b-7b": "olmoe_1b_7b",
     "deepseek-v3-671b": "deepseek_v3_671b",
+    "kanana-2-30b-a3b": "kanana_2_30b_a3b",
     "qwen2.5-14b": "qwen2_5_14b",
     "whisper-tiny": "whisper_tiny",
     "xlstm-1.3b": "xlstm_1_3b",

@@ -45,12 +45,26 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     qk_nope_head_dim: int = 0
     v_head_dim: int = 0
+    rope_interleave: bool = False    # rotary pairs (2i, 2i+1), not (i, i+d/2)
     # --- MoE ---
     num_experts: int = 0
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim (d_ff used if 0)
-    router_aux_coef: float = 0.01
+    router_aux_coef: float = 0.01    # softmax router's load-balance loss
+    # router scores: "softmax" (top-k of the softmax, Switch aux loss) or
+    # "sigmoid" (DeepSeek-V3 noaux_tc: top-k of sigmoid + a per-expert
+    # correction bias that only selects; no aux loss)
+    router_scoring: str = "softmax"
+    router_groups: int = 1           # n_group of group-limited routing
+    router_topk_groups: int = 1      # topk_group: groups a token may use
+    norm_topk_prob: bool = True      # renormalise the selected scores
+    routed_scaling_factor: float = 1.0
+    # expert share (expert parallelism): the layer routes over all
+    # num_experts and holds/computes experts [first_expert,
+    # first_expert + experts_held); 0 holds them all
+    experts_held: int = 0
+    first_expert: int = 0
     # --- SSM (Mamba2 / xLSTM) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -68,6 +82,9 @@ class ModelConfig:
     # --- activation / norm flavour ---
     mlp_variant: str = "swiglu"      # swiglu | gelu
     norm_variant: str = "rmsnorm"    # rmsnorm | layernorm
+    # per-block rematerialisation in training (models.common.remat_blocks
+    # turns it on for any config)
+    remat: bool = False
     citation: str = ""
 
     def __post_init__(self):
@@ -76,6 +93,14 @@ class ModelConfig:
                 raise ValueError(f"unknown block type {b!r}")
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.router_scoring!r}")
+        if self.num_experts and not (
+                0 <= self.first_expert
+                and self.first_expert + self.held_experts <= self.num_experts):
+            raise ValueError(f"expert share [{self.first_expert}, "
+                             f"{self.first_expert + self.held_experts}) is "
+                             f"outside the {self.num_experts} experts")
 
     # ---- derived ----
     @property
@@ -95,6 +120,11 @@ class ModelConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        """Experts this layer holds and computes (its share)."""
+        return self.experts_held or self.num_experts
 
     @property
     def is_decoder_only(self) -> bool:
@@ -136,9 +166,12 @@ class ModelConfig:
         )
         if self.num_experts:
             upd.update(num_experts=4, num_experts_per_tok=2,
-                       moe_d_ff=d_model, num_shared_experts=min(1, self.num_shared_experts))
+                       moe_d_ff=d_model, num_shared_experts=min(1, self.num_shared_experts),
+                       experts_held=0, first_expert=0, router_groups=1,
+                       router_topk_groups=1)
         if self.use_mla:
-            upd.update(q_lora_rank=64, kv_lora_rank=32, qk_rope_head_dim=16,
+            upd.update(q_lora_rank=64 if self.q_lora_rank else 0,
+                       kv_lora_rank=32, qk_rope_head_dim=16,
                        qk_nope_head_dim=16, v_head_dim=d_model // nh)
         if self.ssm_state:
             upd.update(ssm_state=16, ssm_head_dim=32)

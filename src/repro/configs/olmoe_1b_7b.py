@@ -1,6 +1,8 @@
 """OLMoE-1B-7B — 64 experts, top-8. [arXiv:2409.02060]
 
 16L, d_model=2048, 16H (kv=16), per-expert d_ff=1024, vocab=50304.
+Softmax router with the selected scores used as they are
+(``norm_topk_prob`` false in the released config.json).
 """
 from repro.configs.base import ModelConfig
 
@@ -17,6 +19,7 @@ CONFIG = ModelConfig(
     num_experts=64,
     num_experts_per_tok=8,
     moe_d_ff=1024,
+    norm_topk_prob=False,
     sliding_window=8192,
     citation="arXiv:2409.02060",
 )

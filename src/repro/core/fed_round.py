@@ -151,6 +151,14 @@ def _round_metrics(losses, etas, step_counts=None):
             "eta_max": jnp.max(etas)}
 
 
+def _round_counts(counts):
+    """The model's counters (``metrics["counts"]`` of its loss, (K, C)
+    over the round's local steps and clients) as round totals
+    (``models.common.counter_total``)."""
+    from repro.models.common import counter_total
+    return {k: counter_total(k, v) for k, v in counts.items()}
+
+
 def _finish_round(state: FLState, agg, losses, etas,
                   server_opt: ServerOpt, *, step_counts=None, extra=None,
                   ef=None):
@@ -514,7 +522,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
             P, S = carry
             params_c = flatlib.unpack_batched(P, layout)
             with jax.named_scope("client_grad"):
-                (l, _), g = jax.vmap(
+                (l, lm), g = jax.vmap(
                     grad_fn, in_axes=(0, 0, None,
                                       0 if prev_local_params is not None
                                       else None)
@@ -530,10 +538,10 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                                         jnp.float32(jnp.nan), G), pspec)
             active = (k_idx < budget) if budget is not None else None
             P, S = flat_step(P, G, S, mask, active, eta0_c)
-            return (P, S), l
+            return (P, S), (l, lm.get("counts", {}))
 
         from repro.models.common import scan_unroll
-        (P, S), losses = jax.lax.scan(
+        (P, S), (losses, counts) = jax.lax.scan(
             step, (P, S), (batches_t, jnp.arange(K, dtype=jnp.int32)),
             unroll=scan_unroll())
         # everything past the local steps is the round tail
@@ -542,6 +550,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
 
             extra = _scenario_extras(scenario, fstate.round, C, num_clients,
                                      client_sizes, step_counts)
+            extra.update(_round_counts(counts))
             # numerical-guard telemetry (always on for the flat engines):
             # how often η hit the ETA_CLAMP ceiling, and what fraction of
             # lanes the NaN guard dropped this round

@@ -111,9 +111,9 @@ def analytic_memory(cfg, shape, spec, mesh, pstruct, param_sh, fl,
             * max(1, cfg.num_heads // tp) * 4 * b // fsdp
         blk = att
         if cfg.num_experts:
-            cap = max(4, int(tok * cfg.num_experts_per_tok * 1.25
-                             / cfg.num_experts))
-            blk = max(blk, 3 * (cfg.num_experts // max(1, tp)) * cap * D * 2)
+            # dropless dispatch: every token-expert pair has a row
+            pairs = tok * cfg.num_experts_per_tok
+            blk = max(blk, 3 * pairs * max(D, cfg.expert_d_ff) * 2)
         logits = 2 * tok * Vt * 4
         # global params + per-client local copy + grads + prev-grads(Δ-SGD)
         opt_copies = 4 if fl.client_opt == "delta_sgd" else 3

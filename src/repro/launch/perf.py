@@ -14,7 +14,6 @@ Variants:
                along sequence (row-parallel epilogues -> reduce-scatter)
   softmax_bf16 bf16 softmax-weight storage between the attention matmuls
   quant_kv     int8 KV cache entries + f16 scales (decode shapes)
-  capacity1    MoE capacity factor 1.25 -> 1.0
   flat_fed     flat-parameter Δ-SGD engine (train shapes): client params
                packed into one (C, N) buffer for the whole local scan
   flat_fed_sharded
@@ -76,9 +75,7 @@ VARIANT_KNOBS = {
     "quant_kv": {"quant_kv": True},
     "cache_seq_shard": {"cache_seq_shard": True},
     "quant_kv+cache_seq_shard": {"quant_kv": True, "cache_seq_shard": True},
-    "capacity1": {"capacity": 1.0},
     "expert_2d": {"expert_2d": True},
-    "expert_2d+capacity1": {"expert_2d": True, "capacity": 1.0},
     # flat-parameter Δ-SGD engine: packed (C, N) client-state buffer,
     # 2 fused update ops per local step instead of per-leaf/per-client
     "flat_fed": {"flat_fed": True},
@@ -172,10 +169,6 @@ def measure(arch: str, shape_id: str, variant: str, *, local_steps=2):
     if knobs.pop("expert_2d", False):
         import dataclasses
         spec = dataclasses.replace(spec, expert_2d=True)
-    cap = knobs.pop("capacity", None)
-    if cap is not None:
-        import repro.models.moe as moe
-        moe.CAPACITY_FACTOR = cap
 
     t0 = time.time()
     if shape.kind == "train" or True:
@@ -192,9 +185,6 @@ def measure(arch: str, shape_id: str, variant: str, *, local_steps=2):
                                         knobs.get("compression")))
             rls.append(roofline.analyze(c, mesh.size))
         rl = roofline.extrapolate(rls[0], rls[1], L1, L2, cfg.num_layers)
-    if cap is not None:
-        import repro.models.moe as moe
-        moe.CAPACITY_FACTOR = 1.25
     out = rl.summary()
     out["wall_s"] = round(time.time() - t0, 1)
     if knobs.get("compression"):

@@ -307,6 +307,9 @@ def _run_fused(args, loop, state, rounds, stage_block, on_round,
         fstate = flatten_fl_state(state, layout)
     car = fleet_arena
     base, t = int(state.round), 0
+    # the flat carry replaces the tree: a caller that keeps no reference
+    # of its own gets the tree's device memory back for the run
+    del state
     profile_round = getattr(args, "profile", 0)
     profiled = False
 

@@ -11,6 +11,7 @@ The quadratic jnp path here is the reference; the Pallas flash kernel
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -48,10 +49,14 @@ def init_mla(key, cfg, dtype):
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ks = split_keys(key, 6)
+    if qr:
+        q = {"wq_a": dense_init(ks[0], (D, qr), dtype, fan_in=D),
+             "q_norm": jnp.ones((qr,), dtype),
+             "wq_b": dense_init(ks[1], (qr, H, dn + dr), dtype, fan_in=qr)}
+    else:                   # no query LoRA (DeepSeek-V2-Lite, Kanana-2)
+        q = {"wq": dense_init(ks[0], (D, H, dn + dr), dtype, fan_in=D)}
     return {
-        "wq_a": dense_init(ks[0], (D, qr), dtype, fan_in=D),
-        "q_norm": jnp.ones((qr,), dtype),
-        "wq_b": dense_init(ks[1], (qr, H, dn + dr), dtype, fan_in=qr),
+        **q,
         "wkv_a": dense_init(ks[2], (D, kvr + dr), dtype, fan_in=D),
         "kv_norm": jnp.ones((kvr,), dtype),
         "wk_b": dense_init(ks[3], (kvr, H, dn), dtype, fan_in=kvr),
@@ -108,8 +113,13 @@ def _sdpa_block(qc, k, v, rows, *, causal, window):
     return out.astype(v.dtype)
 
 
-def _sdpa(q, k, v, *, causal=True, window=None, q_offset=0):
+def _sdpa(q, k, v, *, causal=True, window=None, q_offset=0,
+          remat_chunks=False):
     """q: (B,S,KV,G,hd)  k,v: (B,T,KV,hd) -> (B,S,KV,G,hd).
+
+    ``remat_chunks``: the backward recomputes each query chunk's scores
+    instead of keeping every chunk's softmax weights (and mask) for it,
+    so a layer's backward holds one chunk's (B,H,S/NQ,T) at a time.
 
     K/V are repeated to the full H = KV*G heads before the score einsum so
     the heads dim shards cleanly over the tensor-parallel axis (a sharded
@@ -134,12 +144,14 @@ def _sdpa(q, k, v, *, causal=True, window=None, q_offset=0):
         return out.reshape(B, S, KV, G, hv)
     L = S // nq
     qs = jnp.moveaxis(qq.reshape(B, nq, L, H, hd), 1, 0)
+    block = functools.partial(_sdpa_block, causal=causal, window=window)
+    if remat_chunks:
+        block = jax.checkpoint(block)
 
     def body(_, xs):
         qc, ci = xs
         rows = q_offset + ci * L + jnp.arange(L, dtype=jnp.int32)
-        return 0, _sdpa_block(qc, kk, vv, rows, causal=causal,
-                              window=window)
+        return 0, block(qc, kk, vv, rows)
 
     _, out = jax.lax.scan(body, 0, (qs, jnp.arange(nq, dtype=jnp.int32)),
                           unroll=scan_unroll())
@@ -277,30 +289,48 @@ def _quantize(x):
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V3): compressed KV latent cache
 # ---------------------------------------------------------------------------
+def _mla_query(params, x):
+    """(B,S,H,dn+dr) queries: through the query LoRA where the config
+    has one, else one projection ``wq``."""
+    if "wq" in params:
+        return jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    from repro.models.common import rmsnorm
+    cq = rmsnorm(jnp.einsum("bsd,dr->bsr", x, params["wq_a"]),
+                 params["q_norm"])
+    return jnp.einsum("bsr,rhk->bshk", cq, params["wq_b"])
+
+
 def mla_full(params, x, cfg, *, positions, window=None, build_cache=False,
              use_pallas=False):
     """Expanded (training/prefill) form; cache stores the latent only."""
+    with jax.named_scope("mla"):
+        return _mla_full(params, x, cfg, positions=positions, window=window,
+                         build_cache=build_cache)
+
+
+def _mla_full(params, x, cfg, *, positions, window, build_cache):
     B, S, D = x.shape
     H = cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
     from repro.models.common import rmsnorm
-    cq = rmsnorm(jnp.einsum("bsd,dr->bsr", x, params["wq_a"]),
-                 params["q_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, params["wq_b"])     # (B,S,H,dn+dr)
+    q = _mla_query(params, x)                                # (B,S,H,dn+dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])       # (B,S,kvr+dr)
     c_kv = rmsnorm(kv[..., :kvr], params["kv_norm"])         # latent
     k_rope = kv[..., kvr:][:, :, None, :]                    # (B,S,1,dr)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                        cfg.rope_interleave)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta,
+                        cfg.rope_interleave)
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, params["wk_b"])
     v = jnp.einsum("bsr,rhk->bshk", c_kv, params["wv_b"])
     qf = jnp.concatenate([q_nope, q_rope], axis=-1)
     kf = jnp.concatenate([k_nope,
                           jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
     qg = qf.reshape(B, S, H, 1, dn + dr)
-    out = _sdpa(qg, kf, v, causal=True, window=window).reshape(B, S, H, dv)
+    out = _sdpa(qg, kf, v, causal=True, window=window,
+                remat_chunks=cfg.remat).reshape(B, S, H, dv)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     cache = ({"c_kv": c_kv, "k_rope": k_rope[:, :, 0, :]}
              if build_cache else None)
@@ -319,16 +349,15 @@ def mla_step(params, x, cfg, cache, *, t, slot, positions_buf, window=None):
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
     from repro.models.common import rmsnorm
-    cq = rmsnorm(jnp.einsum("bsd,dr->bsr", x, params["wq_a"]),
-                 params["q_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, params["wq_b"])
+    q = _mla_query(params, x)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
     c_new = rmsnorm(kv[..., :kvr], params["kv_norm"])        # (B,1,kvr)
     kr_new = kv[..., kvr:][:, :, None, :]
     pos = t[:, None] if vec else jnp.full((B, 1), t, jnp.int32)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
-    kr_new = apply_rope(kr_new, pos, cfg.rope_theta)[:, :, 0, :]
+    q_rope = apply_rope(q_rope, pos, cfg.rope_theta, cfg.rope_interleave)
+    kr_new = apply_rope(kr_new, pos, cfg.rope_theta,
+                        cfg.rope_interleave)[:, :, 0, :]
     c_kv = _cache_write(cache["c_kv"], c_new, slot, vec)
     k_rope = _cache_write(cache["k_rope"], kr_new, slot, vec)
     # absorb W_uk into the query: q_abs (B,H,kvr)

@@ -89,6 +89,13 @@ def remat_blocks(flag: bool = True):
         set_remat(prev)
 
 
+def counter_total(name: str, values: jax.Array) -> jax.Array:
+    """The total of a model counter over ``values`` (its readings over
+    layers, steps or clients): their sum, or for a ``*_max`` counter the
+    largest."""
+    return jnp.max(values) if name.endswith("_max") else jnp.sum(values)
+
+
 def shard_logical(x: jax.Array, names: Tuple[Optional[str], ...]) -> jax.Array:
     """Apply with_sharding_constraint according to the installed rules."""
     rules = get_logical_rules()
@@ -165,14 +172,26 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               interleave: bool = False) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+
+    Frequency i rotates the pair (x[i], x[i + hd/2]); with
+    ``interleave`` the pair (x[2i], x[2i+1]) instead, written back in
+    place (DeepSeek-V3's ``rope_interleave``)."""
     hd = x.shape[-1]
     freqs = jnp.asarray(rope_freqs(hd, theta))          # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                        # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(xf.shape[:-1] + (hd // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(xf.shape)
+        return out.astype(x.dtype)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 

@@ -90,7 +90,7 @@ class Model:
         x = frames + jnp.asarray(sinusoidal_positions(S, cfg.d_model),
                                  frames.dtype)[None]
         positions = jnp.arange(S)[None]
-        x, _, _ = tfm.stack_full(
+        x, _, _, _ = tfm.stack_full(
             params["encoder"]["stack"], x, cfg,
             layer_types=("attn",) * cfg.encoder_layers,
             positions=positions, causal=False)
@@ -127,6 +127,12 @@ class Model:
     # ----------------------------------------------------------------- train
     def apply(self, params, batch, *, use_pallas=False):
         """Full causal forward. Returns (logits over token positions, aux)."""
+        logits, aux, _ = self._forward(params, batch, use_pallas=use_pallas)
+        return logits, aux
+
+    def _forward(self, params, batch, *, use_pallas=False):
+        """``apply`` and the stack's counters (``moe.COUNTERS`` for a
+        stack with MoE layers)."""
         cfg = self.cfg
         x = self._embed(params, batch)
         B, S = x.shape[0], x.shape[1]
@@ -135,16 +141,17 @@ class Model:
         if cfg.encoder_layers:
             enc_out = self._encode(params, batch)
             enc_kv = self._cross_kv(params, enc_out)
-        x, _, aux = tfm.stack_full(params["stack"], x, cfg,
-                                   positions=positions, enc_kv=enc_kv,
-                                   use_pallas=use_pallas)
+        x, _, aux, counters = tfm.stack_full(params["stack"], x, cfg,
+                                             positions=positions,
+                                             enc_kv=enc_kv,
+                                             use_pallas=use_pallas)
         if cfg.num_image_tokens and "image_embeds" in batch:
             x = x[:, cfg.num_image_tokens:]  # logits for text positions only
         logits = self._head(params, x)
         logits = shard_logical(logits, ("batch", "seq", "vocab"))
         if cfg.mtp_depth and "labels" in batch:
             aux = aux + self._mtp_loss(params, x, batch)
-        return logits, aux
+        return logits, aux, counters
 
     def _mtp_loss(self, params, h, batch, weight: float = 0.3):
         """DeepSeek-V3 style multi-token prediction: predict token t+2 from
@@ -155,8 +162,9 @@ class Model:
         hcat = jnp.concatenate([h[:, :-1], nxt], axis=-1)
         x = jnp.einsum("bsd,dk->bsk", hcat, params["mtp"]["proj"])
         positions = jnp.arange(x.shape[1])[None]
-        x, _, aux = tfm.block_full(params["mtp"]["block"], x, cfg,
-                                   cfg.layer_types[-1], positions=positions)
+        x, _, aux, _ = tfm.block_full(params["mtp"]["block"], x, cfg,
+                                      cfg.layer_types[-1],
+                                      positions=positions)
         x = apply_norm(params["mtp"]["norm"], x, cfg)
         logits = self._project_vocab(params, x)
         # targets: token t+2 == labels shifted by one
@@ -165,9 +173,15 @@ class Model:
         return weight * ll + aux
 
     def loss(self, params, batch, *, use_pallas=False):
-        logits, aux = self.apply(params, batch, use_pallas=use_pallas)
+        """(CE + aux, metrics); a stack with MoE layers adds its
+        counters under ``metrics["counts"]``."""
+        logits, aux, counters = self._forward(params, batch,
+                                              use_pallas=use_pallas)
         ce = _ce(logits, batch["labels"])
-        return ce + aux, {"ce": ce, "aux": aux}
+        metrics = {"ce": ce, "aux": aux}
+        if counters:
+            metrics["counts"] = counters
+        return ce + aux, metrics
 
     # ------------------------------------------------------------- inference
     def cache_len_for(self, seq_len: int, window: Optional[int]) -> int:
@@ -185,7 +199,7 @@ class Model:
         if cfg.encoder_layers:
             enc_out = self._encode(params, batch)
             enc_kv = self._cross_kv(params, enc_out)
-        x, caches, _ = tfm.stack_full(params["stack"], x, cfg,
+        x, caches, _, _ = tfm.stack_full(params["stack"], x, cfg,
                                       positions=positions, window=window,
                                       build_cache=True, enc_kv=enc_kv,
                                       use_pallas=use_pallas)

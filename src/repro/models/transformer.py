@@ -94,8 +94,10 @@ def init_block(key, cfg, btype: str, dtype, *, decoder: bool = False):
 def block_full(params, x, cfg, btype, *, positions, window=None,
                build_cache=False, enc_kv=None, causal=True,
                use_pallas=False):
-    """Returns (x, cache, aux_loss)."""
+    """Returns (x, cache, aux_loss, counters); the counters are the MoE
+    layer's (``moe.COUNTERS``), empty for every other block."""
     aux = jnp.zeros((), jnp.float32)
+    counters = {}
     if btype in ("attn", "shared_attn", "moe"):
         h = apply_norm(params["ln1"], x, cfg)
         if cfg.use_mla:
@@ -118,11 +120,11 @@ def block_full(params, x, cfg, btype, *, positions, window=None,
         h = apply_norm(params["ln2"], x, cfg)
         if btype == "moe":
             from repro.models.moe import apply_moe
-            m, aux = apply_moe(params["moe"], h, cfg)
+            m, aux, counters = apply_moe(params["moe"], h, cfg)
         else:
             m = apply_mlp(params["mlp"], h, cfg)
         x = x + m
-        return x, cache, aux
+        return x, cache, aux, counters
     h = apply_norm(params["ln"], x, cfg)
     fn = {"mamba2": ssm.mamba2_full, "mlstm": ssm.mlstm_full,
           "slstm": ssm.slstm_full}[btype]
@@ -131,7 +133,7 @@ def block_full(params, x, cfg, btype, *, positions, window=None,
                       use_pallas=use_pallas)
     else:
         m, cache = fn(params["mixer"], h, cfg, build_cache=build_cache)
-    return x + m, cache, aux
+    return x + m, cache, aux, counters
 
 
 def _bidir_attn(params, x, cfg, positions):
@@ -173,7 +175,7 @@ def block_step(params, x, cfg, btype, cache, *, t, slot, positions_buf,
         h = apply_norm(params["ln2"], x, cfg)
         if btype == "moe":
             from repro.models.moe import apply_moe
-            m, _ = apply_moe(params["moe"], h, cfg)
+            m, _, _ = apply_moe(params["moe"], h, cfg)
         else:
             m = apply_mlp(params["mlp"], h, cfg)
         return x + m, cache
@@ -211,25 +213,40 @@ def init_stack(key, cfg, dtype, *, layer_types=None, decoder=False):
     return params
 
 
+def add_counters(total, counters):
+    """``total`` with one more layer's ``counters`` taken in
+    (``common.counter_total``)."""
+    from repro.models.common import counter_total
+    out = dict(total)
+    for k, v in counters.items():
+        out[k] = (counter_total(k, jnp.stack([out[k], v])) if k in out
+                  else v)
+    return out
+
+
 def stack_full(params, x, cfg, *, layer_types=None, positions, window=None,
                build_cache=False, enc_kv=None, causal=True,
                use_pallas=False):
-    """Returns (x, cache_dict, total_aux)."""
+    """Returns (x, cache_dict, total_aux, counters): the blocks' counters
+    over the stack (``add_counters``), empty without MoE layers."""
     from repro.models.common import remat_on
     layer_types = layer_types or cfg.layer_types
     runs = segment_runs(layer_types)
     caches = {}
     aux_total = jnp.zeros((), jnp.float32)
+    counters = {}
 
     def make_apply(btype):
-        """Per-block apply; checkpointed when remat is on (production
-        policy: keep only the residual stream between layers)."""
+        """Per-block apply; checkpointed when remat is on, for this call
+        (``remat_blocks``) or for the config (``cfg.remat``): production
+        policy, keep only the residual stream between layers."""
         def apply_one(pl, xx, ekv):
             return block_full(pl, xx, cfg, btype, positions=positions,
                               window=window, build_cache=build_cache,
                               enc_kv=ekv, causal=causal,
                               use_pallas=use_pallas)
-        return jax.checkpoint(apply_one) if remat_on() else apply_one
+        return (jax.checkpoint(apply_one) if remat_on() or cfg.remat
+                else apply_one)
 
     for i, (btype, n) in enumerate(runs):
         x = shard_logical(x, ("batch", "seq", "embed"))  # residual stream
@@ -240,8 +257,9 @@ def stack_full(params, x, cfg, *, layer_types=None, positions, window=None,
             p = params["shared_attn"]
             cs = []
             for _ in range(n):
-                x, c, aux = apply_one(p, x, None)
+                x, c, aux, cnt = apply_one(p, x, None)
                 aux_total += aux
+                counters = add_counters(counters, cnt)
                 cs.append(c)
             if build_cache:
                 caches[f"run{i}"] = jax.tree.map(
@@ -249,23 +267,30 @@ def stack_full(params, x, cfg, *, layer_types=None, positions, window=None,
             continue
         p = params[f"run{i}"]
         if n == 1:
-            x, c, aux = apply_one(p, x, _slice_enc(enc_kv, 0))
+            x, c, aux, cnt = apply_one(p, x, _slice_enc(enc_kv, 0))
             aux_total += aux
+            counters = add_counters(counters, cnt)
             if build_cache:
                 caches[f"run{i}"] = jax.tree.map(lambda y: y[None], c)
         else:
             def body(carry, xs):
-                xx, auxx = carry
+                xx, auxx, cntx = carry
                 pl, ekv = xs
-                xx, c, aux = apply_one(pl, xx, ekv)
-                return (xx, auxx + aux), c
+                xx, c, aux, cnt = apply_one(pl, xx, ekv)
+                return (xx, auxx + aux, add_counters(cntx, cnt)), c
 
-            (x, aux_total), cs = jax.lax.scan(body, (x, aux_total),
-                                              (p, enc_kv),
-                                              unroll=scan_unroll())
+            if btype == "moe":
+                from repro.models.moe import COUNTERS
+                run_cnt = {k: jnp.zeros((), jnp.int32) for k in COUNTERS}
+            else:
+                run_cnt = {}
+            (x, aux_total, run_cnt), cs = jax.lax.scan(
+                body, (x, aux_total, run_cnt), (p, enc_kv),
+                unroll=scan_unroll())
+            counters = add_counters(counters, run_cnt)
             if build_cache:
                 caches[f"run{i}"] = cs
-    return x, (caches if build_cache else None), aux_total
+    return x, (caches if build_cache else None), aux_total, counters
 
 
 def stack_step(params, x, cfg, caches, *, layer_types=None, t, slot,

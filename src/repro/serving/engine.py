@@ -41,10 +41,9 @@ Design (mirrors the training engine's host-sync discipline):
     compiled decode block (params are traced arguments), so per-client
     models cost one axpy + unpack, cached until the next swap.
 
-Caveat: MoE blocks route with batch-global expert capacity, so a
-sequence's tokens can be capacity-dropped differently depending on its
-pool neighbours — continuous batching is exact (vs isolated decode) for
-dense/SSM stacks, best-effort for MoE.
+MoE blocks route each token on its own and drop none (models/moe.py),
+so continuous batching is exact (vs isolated decode) for MoE stacks as
+for dense and SSM ones.
 """
 from __future__ import annotations
 

@@ -198,6 +198,21 @@ register("eta_carry_mean", producer=_FLEET, unit="step size",
          summaries=(("eta_carry_mean", "mean"),),
          doc="mean arena-carried η entering the round")
 
+_MOE = "models.moe counters (core.fed_round._round_counts)"
+register("moe_pairs", dtype="i32", producer=_MOE, unit="pairs",
+         summaries=(("moe_pairs_total", "sum"),),
+         doc="token-expert pairs the MoE layers routed to the experts "
+             "this chip holds, over the round's local steps, clients "
+             "and layers")
+register("moe_load_max", dtype="i32", producer=_MOE, unit="pairs",
+         summaries=(("moe_load_max", "max"),),
+         doc="largest held expert's pairs in one layer of one local step "
+             "this round")
+register("moe_dropped", dtype="i32", producer=_MOE, unit="pairs",
+         summaries=(("moe_dropped_total", "sum"),),
+         doc="held pairs the grouped product left out this round (the "
+             "dispatch is dropless: 0)")
+
 _TELE = "telemetry.spec.round_telemetry"
 register("eta_hist", shape="(B,)", producer=_TELE, unit="clients",
          summaries=(("eta_hist", "sum"),),

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.models.moe as moe
 from repro.configs import ARCH_IDS, get_config
 from repro.models import build_model
 
@@ -30,10 +29,7 @@ def _mk(cfg, rng):
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
-def test_prefill_decode_matches_full(arch, rng, monkeypatch):
-    # ample expert capacity: token dropping is order-dependent and would
-    # make the comparison ill-defined (documented Switch-style behaviour)
-    monkeypatch.setattr(moe, "CAPACITY_FACTOR", 8.0)
+def test_prefill_decode_matches_full(arch, rng):
     cfg = get_config(arch).reduced()
     model = build_model(cfg)
     params = model.init(jax.random.key(1))

@@ -103,8 +103,8 @@ def test_continuous_batching_token_exact_vs_isolated():
                                   "deepseek-v3-671b"])
 def test_continuous_batching_other_archs(arch):
     """Same exactness through SSM (mamba2/mlstm/slstm) state pools and
-    the MLA latent cache. (deepseek's MoE routing is batch-global —
-    exact here at reduced scale because capacity is not contended.)"""
+    the MLA latent cache. (deepseek's MoE routing drops no token, so
+    a request's experts do not depend on what shares its batch.)"""
     cfg, model, params = _setup(arch)
     rng = np.random.default_rng(2)
     eng = DecodeEngine(model, params, slots=2, cache_len=48,

@@ -1,6 +1,6 @@
 """Compile the hot path for a TPU v5e that is described, not attached.
 
-Every Pallas kernel of the six namespaces, at the widths the main path
+Every Pallas kernel of the seven namespaces, at the widths the main path
 runs them, and one whisper-tiny fused training block at the size
 ``chip_smoke.py`` trains it, go through the TPU compiler here on the
 CPU (``jax.experimental.topologies``). Nothing runs: a compile that
@@ -112,6 +112,7 @@ def _kernel_cases():
     from repro.kernels.delta_sgd import delta_sgd as dk
     from repro.kernels.flash_attention.flash_attention import \
         flash_attention
+    from repro.kernels.grouped_matmul import ops as gk
     from repro.kernels.mamba2_scan.mamba2_scan import ssd_chunks
     from repro.kernels.robust_agg import robust_agg as rk
     from repro.kernels.telemetry import telemetry as tk
@@ -123,6 +124,12 @@ def _kernel_cases():
     fa = lambda q, k, v: flash_attention(q, k, v, causal=True, **ip)
     # zamba2-7b's SSD widths: d_inner 7168 over 64-wide heads, state 64
     H, P, N, S = 112, 64, 64, 1024
+    # kanana-2's held experts: 4 x 2048 tokens x 6 choices, 8 held of
+    # width 768 over d 2048, and the trailing group of unheld pairs
+    T, D, F, E = 4 * 2048 * 6, 2048, 768, 8
+
+    def gmm(x, w, g):
+        return gk.grouped_matmul(x, w, g, pallas=True, **ip)
     return {
         "delta_sgd.batched_norms@whisper": (
             lambda g, gp: dk.batched_norms(g, gp, **ip),
@@ -159,6 +166,13 @@ def _kernel_cases():
             fa, *(_sds((1, 448, 6, 64)),) * 3),
         "flash_attention@whisper_encoder_1500": (
             fa, *(_sds((1, 1500, 6, 64)),) * 3),
+        "grouped_matmul.forward@kanana": (
+            gmm, _sds((T, D)), _sds((E, D, F)), _sds((E + 1,), jnp.int32)),
+        "grouped_matmul.backward@kanana": (
+            lambda x, w, g, dy: jax.vjp(lambda a, b: gmm(a, b, g),
+                                        x, w)[1](dy),
+            _sds((T, F)), _sds((E, F, D)), _sds((E + 1,), jnp.int32),
+            _sds((T, D))),
         "mamba2_scan.ssd_chunks@zamba2": (
             lambda x, dt, dA, b, c: ssd_chunks(x, dt, dA, b, c, **ip),
             _sds((1, S, H, P)), _sds((1, S, H)), _sds((1, S, H)),
@@ -176,6 +190,7 @@ KERNELS = [
     "flash_attention@whisper_decoder_448",
     "flash_attention@whisper_encoder_1500",
     "mamba2_scan.ssd_chunks@zamba2",
+    "grouped_matmul.forward@kanana", "grouped_matmul.backward@kanana",
 ]
 
 
