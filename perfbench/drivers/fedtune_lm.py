@@ -14,9 +14,8 @@ batch make the calibration's planted faults.
 Besides the fine-tune's counts, the result carries the program's MoE
 counters over the window (``moe_pairs``: token–expert pairs routed to
 the held experts, ``moe_load_max``: the largest held expert's pairs in
-a local step's layer, ``moe_dropped``: pairs left out, compared with a
-limit of 0 under ``dropped``), and the operations of a round computed
-from the routed pairs.
+a local step's layer), and the operations of a round computed from the
+routed pairs.
 """
 from __future__ import annotations
 
@@ -51,7 +50,9 @@ class Build(fedtune.Build):
     language model's reference. The state between calls of the block
     driver lives in ``self.state`` alone, so that while a block runs the
     device holds its flat carry and not also the parameter tree it was
-    packed from (``launch/train._run_fused`` lets go of the tree)."""
+    packed from (``launch/train._run_fused`` lets go of the tree). The
+    program's matmuls run at the configuration's ``matmul_precision``
+    where it states one, JAX's default for float32 where not."""
 
     state = None
 
@@ -79,9 +80,11 @@ class Build(fedtune.Build):
                 rows.append({k: float(np.asarray(v))
                              for k, v in row.items() if np.ndim(v) == 0})
 
-        self.state = _run_fused(self.driver_args, self.loop,
-                                self._take_state(), rounds, stage_block,
-                                on_round, spans=spans)
+        with jax.default_matmul_precision(
+                self.cfg.get("matmul_precision")):
+            self.state = _run_fused(self.driver_args, self.loop,
+                                    self._take_state(), rounds,
+                                    stage_block, on_round, spans=spans)
         jax.block_until_ready(self.state.params)
 
     def first_block(self, seed, pool, spans):
@@ -129,12 +132,11 @@ class Build(fedtune.Build):
 
 
 def counters(rows):
-    """The MoE counters over rounds' metric rows: pairs and drops
-    summed, the largest load maxed (zeros without MoE layers)."""
+    """The MoE counters over rounds' metric rows: pairs summed, the
+    largest load maxed (zeros without MoE layers)."""
     return {"pairs": sum(r.get("moe_pairs", 0.0) for r in rows),
             "load_max": max((r.get("moe_load_max", 0.0) for r in rows),
-                            default=0.0),
-            "dropped": sum(r.get("moe_dropped", 0.0) for r in rows)}
+                            default=0.0)}
 
 
 def flops_per_round(cell, pairs_per_round):
@@ -174,7 +176,6 @@ def run(cell, args, ctx):
     ref_rows, ref_change, ref_first = b.reference(args.seed, pool)
     nums = fedref.compare(prog_rows, prog_change, ref_rows, ref_change,
                           ref_first)
-    nums["dropped"] = counters(prog_rows + rows)["dropped"]
     moe = counters(rows)
     pairs = moe["pairs"] / rounds
     n_params = int(sum(np.prod(s.shape)
@@ -191,7 +192,6 @@ def run(cell, args, ctx):
                    .expert_flops(cell.config, pairs),
                    "moe_pairs_per_round": pairs,
                    "moe_load_max": moe["load_max"],
-                   "moe_dropped": moe["dropped"],
                    "pair_bytes_per_round": fedtune.pair_bytes_per_round(
                        cell, n_params),
                    "pair_shape": f"f32[{b.mix['clients']},{rows128},128]",

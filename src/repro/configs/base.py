@@ -82,8 +82,9 @@ class ModelConfig:
     # --- activation / norm flavour ---
     mlp_variant: str = "swiglu"      # swiglu | gelu
     norm_variant: str = "rmsnorm"    # rmsnorm | layernorm
-    # per-block rematerialisation in training (models.common.remat_blocks
-    # turns it on for any config)
+    # Model.loss runs under models.common.remat_blocks (per-block remat,
+    # MLA's query chunks recomputed), as a remat_blocks caller can ask
+    # for any config
     remat: bool = False
     citation: str = ""
 

@@ -75,7 +75,6 @@ VARIANT_KNOBS = {
     "quant_kv": {"quant_kv": True},
     "cache_seq_shard": {"cache_seq_shard": True},
     "quant_kv+cache_seq_shard": {"quant_kv": True, "cache_seq_shard": True},
-    "expert_2d": {"expert_2d": True},
     # flat-parameter Δ-SGD engine: packed (C, N) client-state buffer,
     # 2 fused update ops per local step instead of per-leaf/per-client
     "flat_fed": {"flat_fed": True},
@@ -166,9 +165,6 @@ def measure(arch: str, shape_id: str, variant: str, *, local_steps=2):
     spec = get_federation_spec(federation_kind(cfg), mesh)
     fl = FLConfig(local_steps=local_steps)
     knobs = dict(VARIANT_KNOBS[variant])
-    if knobs.pop("expert_2d", False):
-        import dataclasses
-        spec = dataclasses.replace(spec, expert_2d=True)
 
     t0 = time.time()
     if shape.kind == "train" or True:

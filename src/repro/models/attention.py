@@ -313,7 +313,7 @@ def _mla_full(params, x, cfg, *, positions, window, build_cache):
     H = cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
-    from repro.models.common import rmsnorm
+    from repro.models.common import remat_on, rmsnorm
     q = _mla_query(params, x)                                # (B,S,H,dn+dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])       # (B,S,kvr+dr)
@@ -330,7 +330,7 @@ def _mla_full(params, x, cfg, *, positions, window, build_cache):
                           jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
     qg = qf.reshape(B, S, H, 1, dn + dr)
     out = _sdpa(qg, kf, v, causal=True, window=window,
-                remat_chunks=cfg.remat).reshape(B, S, H, dv)
+                remat_chunks=remat_on()).reshape(B, S, H, dv)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     cache = ({"c_kv": c_kv, "k_rope": k_rope[:, :, 0, :]}
              if build_cache else None)

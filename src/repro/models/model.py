@@ -174,9 +174,13 @@ class Model:
 
     def loss(self, params, batch, *, use_pallas=False):
         """(CE + aux, metrics); a stack with MoE layers adds its
-        counters under ``metrics["counts"]``."""
-        logits, aux, counters = self._forward(params, batch,
-                                              use_pallas=use_pallas)
+        counters under ``metrics["counts"]``. A config with ``remat``
+        set turns per-block remat on for its loss
+        (``common.remat_blocks``)."""
+        from repro.models.common import remat_blocks, remat_on
+        with remat_blocks(remat_on() or self.cfg.remat):
+            logits, aux, counters = self._forward(params, batch,
+                                                  use_pallas=use_pallas)
         ce = _ce(logits, batch["labels"])
         metrics = {"ce": ce, "aux": aux}
         if counters:

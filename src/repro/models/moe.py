@@ -23,9 +23,8 @@ share runs without its exchange: what the absent experts would add is
 not computed.
 
 ``apply_moe`` returns (out, aux_loss, counters), the counters per call:
-``moe_pairs`` (pairs routed to held experts), ``moe_load_max`` (the
-largest held expert's pairs) and ``moe_dropped`` (held pairs left
-without a row in the grouped product: 0).
+``moe_pairs`` (pairs routed to held experts) and ``moe_load_max`` (the
+largest held expert's pairs).
 """
 from __future__ import annotations
 
@@ -34,7 +33,7 @@ import jax.numpy as jnp
 
 from repro.models.common import dense_init, split_keys
 
-COUNTERS = ("moe_pairs", "moe_load_max", "moe_dropped")
+COUNTERS = ("moe_pairs", "moe_load_max")
 
 
 def init_moe(key, cfg, dtype):
@@ -105,10 +104,8 @@ def dispatch(idx, cfg):
     gid = jnp.where(held, local, Eh).astype(jnp.int32)
     order = jnp.argsort(gid, stable=True)
     sizes = jnp.zeros((Eh + 1,), jnp.int32).at[gid].add(1)
-    pairs = jnp.sum(held.astype(jnp.int32))
-    counters = {"moe_pairs": pairs,
-                "moe_load_max": jnp.max(sizes[:Eh]),
-                "moe_dropped": pairs - jnp.sum(sizes[:Eh])}
+    counters = {"moe_pairs": jnp.sum(sizes[:Eh]),
+                "moe_load_max": jnp.max(sizes[:Eh])}
     return order, sizes, counters
 
 

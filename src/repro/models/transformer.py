@@ -237,16 +237,15 @@ def stack_full(params, x, cfg, *, layer_types=None, positions, window=None,
     counters = {}
 
     def make_apply(btype):
-        """Per-block apply; checkpointed when remat is on, for this call
-        (``remat_blocks``) or for the config (``cfg.remat``): production
-        policy, keep only the residual stream between layers."""
+        """Per-block apply; checkpointed under ``remat_blocks``:
+        production policy, keep only the residual stream between
+        layers."""
         def apply_one(pl, xx, ekv):
             return block_full(pl, xx, cfg, btype, positions=positions,
                               window=window, build_cache=build_cache,
                               enc_kv=ekv, causal=causal,
                               use_pallas=use_pallas)
-        return (jax.checkpoint(apply_one) if remat_on() or cfg.remat
-                else apply_one)
+        return jax.checkpoint(apply_one) if remat_on() else apply_one
 
     for i, (btype, n) in enumerate(runs):
         x = shard_logical(x, ("batch", "seq", "embed"))  # residual stream

@@ -32,10 +32,6 @@ class FederationSpec:
     client_axes: Tuple[str, ...]
     fsdp_axes: Tuple[str, ...]
     tp_axes: Tuple[str, ...] = ("model",)
-    # Beyond-paper (§Perf): shard the expert dim over tp×fsdp jointly
-    # (1 expert per device for deepseek on 16×16) — expert weights are
-    # never FSDP-gathered; tokens travel via all-to-all instead.
-    expert_2d: bool = False
 
     def clients_on(self, mesh: Mesh) -> int:
         return int(np.prod([mesh.shape[a] for a in self.client_axes])) or 1
@@ -119,12 +115,10 @@ def _param_rules(spec: FederationSpec):
         (r"mlp/b_out$",                (None,)),
         # moe
         (r"moe/router$",               (f, None)),
-        (r"moe/w_(gate|in)$",          (("e2d" if spec.expert_2d else t),
-                                        (None if spec.expert_2d else f),
-                                        None)),
-        (r"moe/w_out$",                (("e2d" if spec.expert_2d else t),
-                                        None,
-                                        (None if spec.expert_2d else f))),
+        # routed experts are stored sharded and gathered whole where the
+        # grouped product runs (models/moe.py: no expert parallelism)
+        (r"moe/w_(gate|in)$",          (t, f, None)),
+        (r"moe/w_out$",                (t, None, f)),
         (r"moe/shared/w_(gate|in)$",   (f, t)),
         (r"moe/shared/w_out$",         (t, f)),
         # mamba2
@@ -182,9 +176,6 @@ def _resolve_conditional(pspec: P, shape, mesh: Mesh, tp_axis: str) -> P:
     for dim, name in zip(shape, pspec):
         if name in ("kv", "heads_t", "hd_t"):
             name = tp_axis
-        if name == "e2d":
-            cand = tuple(a for a in (tp_axis, "data") if a in mesh.shape)
-            name = cand if len(cand) > 1 else (cand[0] if cand else None)
         if name is None:
             out.append(None)
             continue
@@ -317,17 +308,13 @@ class LogicalRules:
         # stream sharded over the tensor axis along SEQUENCE between blocks
         # so row-parallel matmul epilogues lower to reduce-scatter instead
         # of all-reduce (and norms compute on 1/tp of the tokens).
-        ex = tp
-        if getattr(spec, "expert_2d", False):
-            cand = tuple(a for a in (tp, "data") if a in mesh.shape)
-            ex = cand if len(cand) > 1 else ex
         self.map = {"batch": batch, "seq": tp if seq_shard else None,
                     "embed": None, "heads": tp, "kv_heads": None,
-                    "ffn": tp, "experts": ex, "vocab": tp}
+                    "ffn": tp, "vocab": tp}
         if seq_shard:
             # heads/ffn/vocab constraints would conflict with seq on the
             # same axis inside blocks; keep only the residual-stream rule.
-            self.map.update(heads=None, ffn=None, experts=tp, vocab=None)
+            self.map.update(heads=None, ffn=None, vocab=None)
 
     def constrain(self, x, names):
         dims = [self.map.get(n) if n else None for n in names]

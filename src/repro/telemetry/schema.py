@@ -208,10 +208,6 @@ register("moe_load_max", dtype="i32", producer=_MOE, unit="pairs",
          summaries=(("moe_load_max", "max"),),
          doc="largest held expert's pairs in one layer of one local step "
              "this round")
-register("moe_dropped", dtype="i32", producer=_MOE, unit="pairs",
-         summaries=(("moe_dropped_total", "sum"),),
-         doc="held pairs the grouped product left out this round (the "
-             "dispatch is dropless: 0)")
 
 _TELE = "telemetry.spec.round_telemetry"
 register("eta_hist", shape="(B,)", producer=_TELE, unit="clients",

@@ -92,3 +92,31 @@ def test_param_counts_match_configs():
 def test_moe_active_params_smaller():
     cfg = get_config("deepseek-v3-671b")
     assert cfg.active_param_count() < 0.12 * cfg.param_count()
+
+
+def test_remat_has_one_switch():
+    """Per-block remat and MLA's query-chunk recompute both follow
+    ``common.remat_blocks``: a config with ``remat`` set traces the
+    same training program as the config without it called under
+    ``remat_blocks``, and both recompute more than the plain one."""
+    from repro.models.common import remat_blocks
+    cfg = get_config("kanana-2-30b-a3b").reduced()
+    assert cfg.remat
+    off = dataclasses.replace(cfg, remat=False)
+    seq = jnp.zeros((1, 2048), jnp.int32)       # long enough to chunk
+    batch = {"tokens": seq, "labels": seq}
+
+    def program(c):
+        model = build_model(c, jnp.float32)
+        params = jax.eval_shape(model.init, jax.random.key(0))
+        return str(jax.make_jaxpr(
+            jax.grad(lambda p: model.loss(p, batch)[0]))(params))
+
+    on = program(cfg)
+    with remat_blocks(True):
+        asked = program(off)
+    plain = program(off)
+    assert on == asked
+    # a checkpoint a block, and more around MLA's query chunks
+    assert plain.count("remat2[") == 0
+    assert on.count("remat2[") > len(cfg.layer_types)

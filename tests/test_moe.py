@@ -92,7 +92,6 @@ def test_uneven_routing_drops_no_token(cfg, rng):
     K = cfg.num_experts_per_tok
     assert int(cnt["moe_pairs"]) == 2 * 64 * K
     assert int(cnt["moe_load_max"]) == 2 * 64
-    assert int(cnt["moe_dropped"]) == 0
     ref = _dense_reference(params, x, cfg)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3, atol=2e-3)
 
@@ -121,3 +120,45 @@ def test_grads_flow_through_dispatch(cfg, rng):
     gn = sum(float(jnp.abs(l).sum()) for l in jax.tree_util.tree_leaves(g))
     assert np.isfinite(gn) and gn > 0
     assert float(jnp.abs(g["router"]).sum()) > 0  # router receives gradient
+
+
+def test_experts_sharded_over_an_8_device_mesh_match_one_device(rng):
+    """Cross-silo sharding on a (data 2, model 4) mesh: the routed
+    experts stored one per ``model`` device and D over ``data``, tokens
+    over ``data``. The layer gathers the experts where the grouped
+    product runs (no expert parallelism), and its output and gradients
+    equal one device's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.models.common import logical_rules
+    from repro.sharding.spec import (LogicalRules, cross_silo,
+                                     make_param_shardings)
+    cfg = get_config("deepseek-v3-671b").reduced()
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
+                ("data", "model"))
+    spec = cross_silo(mesh)
+    params = {"moe": dict(init_moe(jax.random.key(6), cfg, jnp.float32))}
+    params["moe"]["router_bias"] = jnp.asarray([0.3, -0.2, 0.1, 0.0])
+    x = jnp.asarray(rng.normal(size=(2, 16, cfg.d_model)) * 0.3,
+                    jnp.float32)
+
+    def loss(p, x):
+        out, _, cnt = apply_moe(p["moe"], x, cfg)
+        return jnp.sum(out ** 2), (out, cnt)
+
+    step = jax.jit(jax.value_and_grad(loss, has_aux=True))
+    with jax.default_matmul_precision("highest"):
+        (_, (want, cnt1)), g1 = step(params, x)
+        shard = make_param_shardings(spec, mesh, params)
+        assert shard["moe"]["w_gate"].spec[0] == "model"
+        ps = jax.device_put(params, shard)
+        xs = jax.device_put(x, NamedSharding(mesh, P("data")))
+        with logical_rules(LogicalRules(spec, mesh)):
+            (_, (got, cnt8)), g8 = step(ps, xs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert {k: int(v) for k, v in cnt8.items()} == \
+        {k: int(v) for k, v in cnt1.items()}
+    for a, b in zip(jax.tree.leaves(g8), jax.tree.leaves(g1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
