@@ -37,6 +37,7 @@ import numpy as np
 
 RMS_EPS = 1e-6
 BIAS_STD = 0.05      # correction bias: enough to move selections
+EMBED_STD = 10.0     # token rows: identity outweighs the context means
 
 
 # ---------------------------------------------------------------------------
@@ -52,7 +53,7 @@ def _leaf_init(names, shape, key):
     if leaf == "router_bias":
         return BIAS_STD * normal
     if leaf == "embed":
-        return 0.02 * normal
+        return EMBED_STD * normal
     if leaf == "wo":                    # (H, dv, D)
         fan_in = core[0] * core[1]
     elif "moe" in names and "shared" not in names and leaf != "router":

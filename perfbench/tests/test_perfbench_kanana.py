@@ -187,6 +187,27 @@ def test_one_round_of_the_fused_loop_matches_the_reference(build):
     assert all("moe_pairs" in r for r in rows)
 
 
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 7, 4000000007])
+def test_the_fine_tune_keeps_routing_tokens_by_their_identity(build, seed):
+    """Three blocks of the fused loop from the cell's weights: no held
+    expert ever takes every token of a layer (the embedding's scale
+    keeps each token's identity in the router's input, where at 0.02
+    the first rounds fold every token onto the same experts), and a
+    round routes as many pairs here as the round before it on the same
+    batches, to within 5 %."""
+    from harness.common import Spans
+    pool = build.pool(seed)
+    rows, _ = build.first_block(seed, pool, Spans())
+    build.advance(4, pool, Spans(), rows)
+    build.state = None
+    tokens = build.mix["batch"] * build.mix["seq"]
+    assert max(r["moe_load_max"] for r in rows) < tokens, rows
+    pairs = [r["moe_pairs"] for r in rows]
+    n = len(pool)
+    for a, b in zip(pairs, pairs[n:]):
+        assert abs(b - a) <= 0.05 * a, pairs
+
+
 def test_cell_files_hold_the_catalog_config():
     """Every number of the catalog's config for
     kanana-2-30b-a3b-instruct-2601, under its own key, except the three
