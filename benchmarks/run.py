@@ -189,7 +189,7 @@ def kernels(rounds=None):
     err = abs(float(out[0]) - float(dref.norms_ref(g, gp)[0]))
     emit("kernels/delta_sgd_norms_64k", us, err)
 
-    # ---- flat fused Δ-SGD step: packed (C, N) engine vs per-leaf path ----
+    # ---- flat fused Δ-SGD step: client-slab engine vs per-leaf path ----
     # 16-leaf tree, 64k elements total; one full local step (norms+apply).
     from repro.core import flat as fp
     from repro.core.delta_sgd import (delta_sgd_init, delta_sgd_update,
@@ -218,16 +218,17 @@ def kernels(rounds=None):
         return flat_delta_sgd_step(P, G, S, gamma=GAMMA, delta=DELTA,
                                    eta0=ETA0, interpret=True)
 
-    P1 = fp.pack(tree, layout)[None]
-    G1 = fp.pack(grads, layout)[None]
+    slab1 = fp.slab_shape(1, layout)
+    P1 = fp.pack(tree, layout).reshape(slab1)
+    G1 = fp.pack(grads, layout).reshape(slab1)
     S1 = flat_delta_sgd_init(1, layout, eta0=ETA0, theta0=THETA0)
-    S1 = S1._replace(prev_grads=fp.pack(gprev, layout)[None])
+    S1 = S1._replace(prev_grads=fp.pack(gprev, layout).reshape(slab1))
 
     # launch accounting (trace-time): the packed step must cost exactly
     # 2 pallas launches independent of leaf count and client count
     for C in (1, 4):
-        Pc = jnp.broadcast_to(P1[0], (C, layout.padded_size))
-        Gc = jnp.broadcast_to(G1[0], (C, layout.padded_size))
+        Pc = jnp.broadcast_to(P1, fp.slab_shape(C, layout))
+        Gc = jnp.broadcast_to(G1, fp.slab_shape(C, layout))
         Sc = flat_delta_sgd_init(C, layout, eta0=ETA0, theta0=THETA0)
         dk.reset_launch_count()
         jax.block_until_ready(packed_step(Pc, Gc, Sc)[0])
@@ -244,7 +245,7 @@ def kernels(rounds=None):
     ref_p, ref_s = delta_sgd_update(tree, grads, s_ref, gamma=GAMMA,
                                     delta=DELTA, eta0=ETA0)
     newP, newS = packed_step(P1, G1, S1)
-    got_p = fp.unpack(newP[0], layout)
+    got_p = fp.unpack(newP[0].reshape(-1), layout)
     err = max(float(jnp.max(jnp.abs(got_p[k2] - ref_p[k2])))
               for k2 in ref_p)
     err = max(err, abs(float(newS.eta[0]) - float(ref_s.eta)))

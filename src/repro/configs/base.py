@@ -226,7 +226,7 @@ class FLConfig:
     momentum: float = 0.9
     weighted_agg: bool = False
     # flat-parameter Δ-SGD engine: pack the param pytree + client axis
-    # into one (C, N) buffer for the whole local scan (core/fed_round)
+    # into one client slab for the whole local scan (core/fed_round)
     flat_engine: bool = False
     # federation scenario preset name (repro.federation.scenarios): adds
     # participation scheduling, compute heterogeneity, and/or async

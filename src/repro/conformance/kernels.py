@@ -71,7 +71,7 @@ def _delta_batched_norms(C, N):
         from repro.kernels.delta_sgd import delta_sgd as dk
         from repro.kernels.delta_sgd import ref as dref
         r = _rng(seed)
-        g = jnp.asarray(r.normal(size=(C, N)), jnp.float32)
+        g = jnp.asarray(r.normal(size=(C, N // 128, 128)), jnp.float32)
         gp = g * -0.3 + 0.1
         got = jnp.stack(dk.batched_norms(g, gp, interpret=True))
         want = jnp.stack(dref.batched_norms_ref(g, gp))
@@ -85,13 +85,17 @@ def _delta_batched_apply(C, N, masked):
         from repro.kernels.delta_sgd import delta_sgd as dk
         from repro.kernels.delta_sgd import ref as dref
         r = _rng(seed)
-        p = jnp.asarray(r.normal(size=(C, N)), jnp.float32)
-        g = jnp.asarray(r.normal(size=(C, N)), jnp.float32)
+        slab = (C, N // 128, 128)
+        p = jnp.asarray(r.normal(size=slab), jnp.float32)
+        g = jnp.asarray(r.normal(size=slab), jnp.float32)
         eta = jnp.asarray(r.uniform(0.01, 1.0, C), jnp.float32)
-        mask = (jnp.asarray(r.integers(0, 2, N), jnp.float32)
+        valid = jnp.asarray(r.uniform(size=C) > 0.2)
+        mask = (jnp.asarray(r.integers(0, 2, slab[1:]), jnp.float32)
                 if masked else None)
-        return (dk.batched_apply(p, g, eta, mask=mask, interpret=True),
-                dref.batched_apply_ref(p, g, eta, mask=mask), 1e-5, 1e-6)
+        return (dk.batched_apply(p, g, eta, valid=valid, mask=mask,
+                                 interpret=True),
+                dref.batched_apply_ref(p, g, eta, mask=mask, valid=valid),
+                1e-5, 1e-6)
     return run
 
 

@@ -41,9 +41,8 @@ def _pallas_apply_eps():
     from repro.kernels.delta_sgd import delta_sgd as dk
     orig = dk.batched_apply
 
-    def perturbed(p, g, eta, *, mask=None, interpret=False):
-        out = orig(p, g, eta, mask=mask, interpret=interpret)
-        return out + 1e-3
+    def perturbed(p, g, eta, **kw):
+        return orig(p, g, eta, **kw) + 1e-3
 
     dk.batched_apply = perturbed
 

@@ -21,25 +21,27 @@ The fused Pallas kernel (repro/kernels/delta_sgd) performs the update +
 both norm accumulations in a single HBM pass; ``use_pallas`` switches it in.
 
 Flat engine: ``FlatDeltaSGDState`` + ``flat_delta_sgd_step`` run the SAME
-rule for all C participating clients at once on packed ``(C, N)`` buffers
-(repro.core.flat) — two kernel launches per local step total, independent
-of leaf count and client count. ``backend="pallas"`` uses the batched
-Pallas kernels (interpret mode off-TPU, ``repro.kernels.interpret_mode``);
-``backend="xla"`` lowers the identical math through plain jnp on the
-flat buffers, which is what meshed/pjit callers use.
+rule for all C participating clients at once on ``(C, N/128, 128)``
+client slabs (repro.core.flat) — two kernel launches per local step
+total, independent of leaf count and client count. The slab is the
+kernels' own operand form, so the step relayouts nothing.
+``backend="pallas"`` uses the batched Pallas kernels (interpret mode
+off-TPU, ``repro.kernels.interpret_mode``); ``backend="xla"`` lowers the
+identical math through plain jnp on the slabs, which is what meshed/pjit
+callers use.
 
 Sharded flat engine: ``flat_delta_sgd_step_sharded`` is the mesh-native
-variant — the (C, N) buffer stays sharded per
-``FederationSpec.flat_spec(mesh)`` (clients over the client axes, N over
-fsdp/tp axes) and the kernels run inside ``shard_map`` on each device's
+variant — the slab stays sharded like ``FederationSpec.flat_spec(mesh)``
+(clients over the client axes, the N/128 rows over fsdp/tp axes, the
+lanes whole) and the kernels run inside ``shard_map`` on each device's
 local slab. The dual norm reduction completes with ONE psum of the two
 partial sums over the N-shard axes (2·C_local floats on the wire); the
-(C, N) buffer itself is never gathered, and the apply is purely
-shard-local.
+slab itself is never gathered, and the apply is purely shard-local.
 
 The three flat entry points run under the named scope ``delta_sgd``: on a
-device trace it holds the kernel pair and the jnp around it (the
-``where(valid, G, 0)`` select, the η/θ rule and guards).
+device trace it holds the kernel pair and the per-client scalar math
+around it (the η/θ rule and guards). The valid-lane zeroing happens
+inside ``batched_apply``; no pass over the slab runs beside the pair.
 """
 from __future__ import annotations
 
@@ -52,8 +54,8 @@ from repro.core import flat as flatlib
 
 # Numerical guard ceiling on η (flat engines): Eq. (4)'s cand1 can blow
 # up when ‖∇̃f(x_k) − ∇̃f(x_{k−1})‖ underflows on a flat local landscape,
-# and a non-finite η from a corrupted gradient would poison the packed
-# (C, N) buffer irreversibly. η is clamped to this ceiling (counted per
+# and a non-finite η from a corrupted gradient would poison the client
+# slab irreversibly. η is clamped to this ceiling (counted per
 # client in FlatDeltaSGDState.clips) and non-finite norms drop the lane
 # to η=0 + latch FlatDeltaSGDState.valid off for the rest of the round.
 # fp32 min against a finite ceiling is exact, so healthy trajectories
@@ -168,11 +170,13 @@ def delta_sgd_update(params, grads, state: DeltaSGDState, *, gamma: float,
 
 
 # --------------------------------------------------------------------------
-# flat engine: all C clients' Δ-SGD state on packed (C, N) buffers
+# flat engine: all C clients' Δ-SGD state on (C, N/128, 128) slabs
 # --------------------------------------------------------------------------
 
 class FlatDeltaSGDState(NamedTuple):
-    prev_grads: jax.Array       # (C, N) packed previous gradients, f32
+    prev_grads: jax.Array       # (C, N/128, 128) previous gradients,
+                                # f32, raw (a latched lane's may be
+                                # non-finite: it reaches nothing)
     eta: jax.Array              # (C,) per-client step size
     theta: jax.Array            # (C,) η_k / η_{k-1}
     prev_grad_norm: jax.Array   # (C,)
@@ -187,9 +191,9 @@ class FlatDeltaSGDState(NamedTuple):
 def flat_delta_sgd_init(num_clients: int, layout: flatlib.FlatLayout, *,
                         eta0: float, theta0: float) -> FlatDeltaSGDState:
     with jax.named_scope("delta_sgd"):
-        C, N = num_clients, layout.padded_size
+        C = num_clients
         return FlatDeltaSGDState(
-            jnp.zeros((C, N), jnp.float32),
+            jnp.zeros(flatlib.slab_shape(C, layout), jnp.float32),
             jnp.full((C,), eta0, jnp.float32),
             jnp.full((C,), theta0, jnp.float32),
             jnp.zeros((C,), jnp.float32),
@@ -220,7 +224,7 @@ def _mask_inactive(active, eta, theta, grad_norm, state):
     mask is idempotent on already-rounded lanes) and keeps its scalar
     state frozen. ``prev_grads`` is NOT re-selected: inactivity is a
     terminal prefix within the round, so a frozen client's stale norm
-    state can never reach an applied update — skipping the (C, N) select
+    state can never reach an applied update — skipping the slab select
     keeps the step at exactly two fused kernel launches.
 
     Returns (eta_applied, eta, theta, grad_norm)."""
@@ -238,14 +242,25 @@ def flat_delta_sgd_step(P: jax.Array, G: jax.Array,
                         active: Optional[jax.Array] = None,
                         backend: str = "pallas",
                         interpret: Optional[bool] = None):
-    """One Δ-SGD local step for ALL clients on packed buffers.
+    """One Δ-SGD local step for ALL clients on client slabs.
 
-    P, G: (C, N) packed params/grads. Exactly two Pallas launches
+    P, G: (C, N/128, 128) client slabs of params and grads (the form
+    ``flat.pack_batched`` writes and the scan carries), ``mask`` the
+    optional (N/128, 128) round mask. Exactly two Pallas launches
     (batched_norms + batched_apply) regardless of leaf count and client
-    count; ``backend="xla"`` runs the same math as fused jnp ops for
-    meshed callers. ``active`` is an optional (C,) bool lane mask for
-    heterogeneous step counts: inactive clients apply η=0 and keep their
-    state frozen, at no extra launch cost. Returns (new_P, new_state).
+    count, and no other pass over the slab; ``backend="xla"`` runs the
+    same math as fused jnp ops for meshed callers. ``active`` is an
+    optional (C,) bool lane mask for heterogeneous step counts: inactive
+    clients apply η=0 and keep their state frozen, at no extra launch
+    cost.
+
+    A lane whose norms go non-finite latches ``valid`` off; the apply
+    zeroes its gradient inside the kernel (η = 0 alone cannot stop a
+    NaN: 0·NaN = NaN), so its P stays bit-identical. ``prev_grads``
+    rolls to the raw G: a latched lane's η stays 0 and its scalars stay
+    frozen for the rest of the round, so its non-finite ``prev_grads``
+    reaches no applied update and no state. Healthy lanes are untouched
+    by either. Returns (new_P, new_state).
     """
     with jax.named_scope("delta_sgd"):
         first = (state.k == 0)
@@ -271,22 +286,18 @@ def flat_delta_sgd_step(P: jax.Array, G: jax.Array,
             act, eta, theta, grad_norm, state)
         clips = (jnp.zeros_like(valid, jnp.int32) if state.clips is None
                  else state.clips) + (clip_hit & act).astype(jnp.int32)
-        # sanitize: η=0 alone can't stop a NaN gradient (0·NaN = NaN in the
-        # apply), so invalid lanes are zeroed before both the apply and the
-        # prev_grads roll. where(True, G, 0) is G bitwise on healthy lanes,
-        # and it is an XLA select — the step stays at two kernel launches.
-        G_safe = jnp.where(valid[:, None], G, jnp.float32(0.0))
         if backend == "pallas":
-            new_P = k.batched_apply(P, G_safe, eta_applied, mask=mask,
-                                    interpret=interpret)
+            new_P = k.batched_apply(P, G, eta_applied, valid=valid,
+                                    mask=mask, interpret=interpret)
         else:
-            new_P = kref.batched_apply_ref(P, G_safe, eta_applied, mask)
-        return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
+            new_P = kref.batched_apply_ref(P, G, eta_applied, mask,
+                                           valid=valid)
+        return new_P, FlatDeltaSGDState(G, eta, theta, grad_norm,
                                         state.k + 1, valid, clips)
 
 
 # --------------------------------------------------------------------------
-# sharded flat engine: the (C, N) buffer stays mesh-sharded end to end
+# sharded flat engine: the client slab stays mesh-sharded end to end
 # --------------------------------------------------------------------------
 
 def _axis_names(entry):
@@ -303,23 +314,24 @@ def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
                                 active: Optional[jax.Array] = None,
                                 backend: str = "xla",
                                 interpret: Optional[bool] = None):
-    """One Δ-SGD local step on a mesh-sharded packed (C, N) buffer.
+    """One Δ-SGD local step on a mesh-sharded (C, N/128, 128) slab.
 
     ``pspec`` is ``FederationSpec.flat_spec(mesh)`` — clients over
-    ``pspec[0]``, the flat param dim over ``pspec[1]`` (the layout must
-    have been built with ``shards=FederationSpec.flat_shards(mesh)`` so
-    each local slab stays lane/row-block aligned). Per device: the kernel
-    pair runs on the local (C_loc, N_loc) slab; the per-client dual norms
-    finish with a single psum over the N-shard axes, so η is exact while
-    N is never gathered. ``active`` is the optional (C,) heterogeneous-K
-    lane mask (sharded like the other per-client vectors). Returns
-    (new_P, new_state) with unchanged shardings.
+    ``pspec[0]``, the flat param dim over ``pspec[1]``; the slab's rows
+    are sharded like N and its lanes are whole (the layout must have
+    been built with ``shards=FederationSpec.flat_shards(mesh)`` so each
+    local slab stays row-block aligned). Per device: the kernel pair
+    runs on the local (C_loc, N_loc/128, 128) slab; the per-client dual
+    norms finish with a single psum over the N-shard axes, so η is exact
+    while N is never gathered. ``active`` is the optional (C,)
+    heterogeneous-K lane mask (sharded like the other per-client
+    vectors). Returns (new_P, new_state) with unchanged shardings.
     """
     from jax.sharding import PartitionSpec as PS
     ca = pspec[0] if len(pspec) > 0 else None
     na = pspec[1] if len(pspec) > 1 else None
     na_names = _axis_names(na)
-    buf, vec, rep = PS(ca, na), PS(ca), PS()
+    buf, vec, rep = PS(ca, na, None), PS(ca), PS()
     if backend == "pallas":
         from repro.kernels import interpret_mode
         interpret = interpret_mode(interpret)
@@ -355,14 +367,13 @@ def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
         eta_applied, eta_n, theta_n, grad_norm = _mask_inactive(
             act, eta_n, theta_n, grad_norm, st)
         clips_n = clips_p + (clip_hit & act).astype(jnp.int32)
-        G_safe = jnp.where(valid_n[:, None], G_l, jnp.float32(0.0))
         if backend == "pallas":
-            new_P = k.batched_apply(P_l, G_safe, eta_applied, mask=mask_l,
-                                    interpret=interpret)
+            new_P = k.batched_apply(P_l, G_l, eta_applied, valid=valid_n,
+                                    mask=mask_l, interpret=interpret)
         else:
-            new_P = kref.batched_apply_ref(P_l, G_safe, eta_applied,
-                                           mask_l)
-        return new_P, G_safe, eta_n, theta_n, grad_norm, valid_n, clips_n
+            new_P = kref.batched_apply_ref(P_l, G_l, eta_applied, mask_l,
+                                           valid=valid_n)
+        return new_P, eta_n, theta_n, grad_norm, valid_n, clips_n
 
     with jax.named_scope("delta_sgd"):
         C = P.shape[0]
@@ -375,14 +386,14 @@ def flat_delta_sgd_step_sharded(P: jax.Array, G: jax.Array,
         specs = [buf, buf, buf, vec, vec, vec, rep, vec, vec]
         if with_mask:
             ins.append(mask)
-            specs.append(PS(na))
+            specs.append(PS(na, None))
         if with_active:
             ins.append(active)
             specs.append(vec)
         # replication checking off: the Pallas kernels carry no rules for it
         fn = jax.shard_map(local_step, mesh=mesh, in_specs=tuple(specs),
-                           out_specs=(buf, buf, vec, vec, vec, vec, vec),
+                           out_specs=(buf, vec, vec, vec, vec, vec),
                            check_vma=False)
-        new_P, G_safe, eta, theta, grad_norm, valid, clips = fn(*ins)
-        return new_P, FlatDeltaSGDState(G_safe, eta, theta, grad_norm,
+        new_P, eta, theta, grad_norm, valid, clips = fn(*ins)
+        return new_P, FlatDeltaSGDState(G, eta, theta, grad_norm,
                                         state.k + 1, valid, clips)

@@ -13,8 +13,9 @@ computation:
 
   * the carried state is a ``FlatFLState`` — the param pytree packed
     into the (N,) flat buffer (repro.core.flat) and the EF21
-    error-feedback tree packed to (C, N). Packing happens once per
-    R-round block (``flatten_fl_state``); unpacking only at
+    error-feedback tree packed to (C, N); inside a round the local-step
+    scan carries the (C, N/128, 128) client slab. Packing happens once
+    per R-round block (``flatten_fl_state``); unpacking only at
     eval/checkpoint cadence (``unflatten_fl_state``).
   * a ``lax.scan`` chains R rounds of the SAME flat round body the
     single-round engine runs (``fed_round`` attaches it to the returned
@@ -108,6 +109,7 @@ def flatten_fl_state(state: FLState, layout: flatlib.FlatLayout
     ef = state.ef
     if ef is not None:
         ef = flatlib.pack_batched(ef, layout)
+        ef = ef.reshape(ef.shape[0], layout.padded_size)
     fstate = FlatFLState(flatlib.pack(state.params, layout),
                          state.server_state, state.round, state.buffer, ef)
     # donation hygiene: jax caches scalar constants, so two zero-valued
@@ -274,8 +276,8 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
     """One shard_map around the whole R-round scan (client-axes-only
     sharding). Each device runs its C_loc clients' full local math —
     grad eval, the fused Δ-SGD kernel pair, delta compression — on a
-    local (C_loc, N) slab; the mesh is entered once per BLOCK, and the
-    client-crossing traffic is 2 collectives per round — one packed
+    local (C_loc, N/128, 128) slab; the mesh is entered once per BLOCK,
+    and the client-crossing traffic is 2 collectives per round — one packed
     psum carrying the (compressed) aggregate plus every scalar metric
     sum ((N+5,), widening to (N+5+B,) when telemetry appends its B
     η-histogram bin counts), and one (2,) pmin for the η extrema.
@@ -394,9 +396,13 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                 batches = (gather(arena_l, data_r) if gather is not None
                            else data_r)
                 gp = flatlib.unpack(st.P, layout)
+                slab = flatlib.slab_shape(C_loc, layout)
                 with jax.named_scope("flat"):
-                    P = jnp.broadcast_to(st.P[None], (C_loc, N))
-                P_start = P if (is_async or comp is not None) else None
+                    P = jnp.broadcast_to(st.P.reshape(slab[1:])[None],
+                                         slab)
+                # the delta-space tails read (C_loc, N) rows
+                P_start = (jnp.broadcast_to(st.P[None], (C_loc, N))
+                           if (is_async or comp is not None) else None)
                 S = flat_delta_sgd_init(C_loc, layout, eta0=eta0,
                                         theta0=theta0)
                 k_full = d_r.get("k")
@@ -428,6 +434,9 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                 # everything past the local steps is the round tail
                 with jax.named_scope("round_tail"):
                     losses = losses.T       # (C_loc, K)
+                    # one conversion a round, never one a local step
+                    P_rows = (P.reshape(C_loc, N) if P_start is not None
+                              else None)
 
                     # collective budget: every client-crossing SUM rides
                     # ONE packed (N+5,) psum together with the round's
@@ -476,7 +485,7 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                         lev_full = d_r.get("lev")
                         lev_loc = (local_cols(lev_full)
                                    if lev_full is not None else None)
-                        delta_c = P - P_start
+                        delta_c = P_rows - P_start
                         resid = (delta_c - st.ef) if use_ef else delta_c
                         chat = compress_flat(resid, comp, levels=lev_loc,
                                              backend=backend)
@@ -509,7 +518,8 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                         else:
                             agg_local = jnp.sum(P_agg, axis=0)
                             agg_div = Cf
-                        packed = cpsum(jnp.concatenate([agg_local, scal]))
+                        packed = cpsum(jnp.concatenate(
+                            [agg_local.reshape(N), scal]))
                         scal_g = packed[N:]
                         agg = flatlib.unpack(packed[:N] / agg_div, layout)
                         new_params, sstate = server_opt.update(
@@ -525,10 +535,10 @@ def _make_block_loop(loss_fn, client_opt, server_opt, *, params_like,
                                                 scenario.staleness_exp)
                         if weighted and has_w:
                             wst = wst * w_r.astype(jnp.float32)
-                        agg_local = jnp.tensordot(
-                            local_cols(wst),
-                            delta_hat if comp is not None else (P - P_start),
-                            axes=(0, 0))
+                        d = (delta_hat if comp is not None
+                             else P_rows - P_start)
+                        agg_local = jnp.tensordot(local_cols(wst), d,
+                                                  axes=(0, 0))
                         packed = cpsum(jnp.concatenate([agg_local, scal]))
                         scal_g = packed[N:]
                         delta_tree = flatlib.unpack(packed[:N], layout,

@@ -14,20 +14,21 @@ micro-batches of the client's *local* data.
 
 Flat engine (``flat=`` argument, Δ-SGD only): instead of vmapping the
 optimizer over C, the param pytree is packed ONCE at round start into a
-lane-aligned flat buffer broadcast to (C, N) (repro.core.flat), the
-K-step scan runs entirely on flat buffers — per step: one vmapped grad
-eval on the unpacked view, then exactly two fused kernel launches
+lane-aligned flat buffer broadcast to a (C, N/128, 128) client slab
+(repro.core.flat), the K-step scan runs entirely on slabs in the kernel
+pair's own form — per step: one vmapped grad eval on the unpacked view,
+then exactly two fused kernel launches
 (batched norms + batched apply) for all leaves and all clients —
 aggregation is a single mean over the packed C axis, and the result is
 unpacked once at round end. ``flat="pallas"``/``True`` uses the batched
 Pallas kernels, ``flat="xla"`` the same math as fused jnp ops (for
 meshed/pjit callers).
 
-Sharded flat engine (``mesh=`` + ``federation=`` arguments): the packed
-(C, N) buffer is mesh-sharded end to end per
-``FederationSpec.flat_spec(mesh)`` — clients over the client axes, N over
-the fsdp/tp axes, with a per-shard padded layout
-(``layout_of(..., shards=...)``) so every device's slab stays
+Sharded flat engine (``mesh=`` + ``federation=`` arguments): the
+(C, N/128, 128) client slab is mesh-sharded end to end like
+``FederationSpec.flat_spec(mesh)`` — clients over the client axes, the
+N/128 rows over the fsdp/tp axes and the lanes whole, with a per-shard
+padded layout (``layout_of(..., shards=...)``) so every device's slab stays
 lane-aligned. Pack/unpack run under ``with_sharding_constraint``, the
 per-step kernel pair runs inside ``shard_map`` with a psum dual-norm
 reduction (repro.core.delta_sgd.flat_delta_sgd_step_sharded), and the
@@ -97,10 +98,11 @@ class RoundAux(NamedTuple):
     state — what the fleet arena (repro.federation.arena) scatters into
     per-registered-client storage after a round.
 
-    ``P_locals`` (C, N): round-end local params (the flat form of the
-    vmap engine's ``new_locals``). ``etas`` (C,): round-end Δ-SGD step
-    sizes — the per-client adaptive state that persists across the
-    rounds a client sits out when an arena carries it. ``valid`` (C,)
+    ``P_locals`` (C, N/128, 128): round-end local params (the client
+    slab form of the vmap engine's ``new_locals``). ``etas`` (C,):
+    round-end Δ-SGD step sizes — the per-client adaptive state that
+    persists across the rounds a client sits out when an arena carries
+    it. ``valid`` (C,)
     bool: NaN-guard survivors (all True on fault-free rounds)."""
     P_locals: jax.Array
     etas: jax.Array
@@ -221,8 +223,8 @@ def make_fl_round(loss_fn, client_opt: ClientOpt, server_opt: ServerOpt, *,
     rule).
 
     ``mesh`` + ``federation`` (FederationSpec): flat engine only — keep
-    the packed (C, N) buffer sharded per ``federation.flat_spec(mesh)``
-    for the whole round (see module docstring). Both or neither.
+    the client slab sharded per ``federation.flat_spec(mesh)`` for the
+    whole round (see module docstring). Both or neither.
 
     ``scenario`` (repro.federation.Scenario): heterogeneous step counts
     (both engines) and async buffered aggregation (flat engine only).
@@ -365,12 +367,15 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                      mesh=None, federation=None, scenario=None,
                      num_clients=None, client_sizes=None,
                      compression=None, telemetry=None):
-    """Flat-parameter Δ-SGD engine: one packed (C, N) buffer carries every
-    leaf of every client's params through the K-step scan; two fused
-    kernel launches per local step total. With ``mesh``/``federation``
-    the buffer additionally stays sharded per ``federation.flat_spec``
-    for the whole round. With a ``scenario`` the K-step scan carries the
-    per-client step-count lane mask, and async scenarios route the
+    """Flat-parameter Δ-SGD engine: one (C, N/128, 128) client slab
+    carries every leaf of every client's params through the K-step scan
+    in the kernel pair's own operand form; two fused kernel launches per
+    local step total. With ``mesh``/``federation`` the slab additionally
+    stays sharded like ``federation.flat_spec(mesh)`` (rows like N) for
+    the whole round. Tail consumers that take (C, N) (compression,
+    robust aggregation, the async buffer) read it converted once a
+    round. With a ``scenario`` the K-step scan carries the per-client
+    step-count lane mask, and async scenarios route the
     aggregate through the FedBuff delta buffer instead of the direct
     server update.
 
@@ -422,8 +427,10 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
     if sharded:
         from jax.sharding import NamedSharding, PartitionSpec as PS
         pspec = federation.flat_spec(mesh)          # (C, N) buffers
+        sspec = PS(pspec[0], pspec[1], None)        # (C, N/128, 128)
         cspec = federation.flat_client_spec(mesh)   # (C,) vectors
         nspec = PS(pspec[1])                        # (N,) buffers
+        mspec = PS(pspec[1], None)                  # (N/128, 128) mask
         shards = federation.flat_shards(mesh)
 
         def constrain(x, ps):
@@ -435,7 +442,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
         def constrain(x, ps):
             return x
 
-        pspec = cspec = nspec = None
+        pspec = sspec = cspec = nspec = mspec = None
 
     def flat_step(P, G, S, mask, active, eta0_step=None):
         """``eta0_step`` optionally overrides the scalar η₀ with a (C,)
@@ -474,7 +481,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
             return constrain(flatlib.pack(tree, layout), nspec)
         mask = flatlib.round_mask(layout)
         if mask is not None:
-            mask = constrain(mask, nspec)
+            mask = constrain(mask, mspec)
         leaves = jax.tree_util.tree_leaves(client_batches)
         C, K = leaves[0].shape[0], leaves[0].shape[1]
         step_counts = (scenario.draw_step_counts(fstate.round, C, K)
@@ -496,16 +503,19 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
         else:
             budget = mcounts = step_counts
 
-        # broadcast the round-start params to the client axis; the carry
+        # broadcast the round-start params to the client slab; the carry
         # is already flat, so no per-round pytree re-pack happens here
+        slab = flatlib.slab_shape(C, layout)
         with jax.named_scope("flat"):
-            P = constrain(jnp.broadcast_to(fstate.P[None],
-                                           (C, layout.padded_size)), pspec)
-        P_start = P if (is_async or comp is not None or guard_tail) \
-            else None
+            P = constrain(jnp.broadcast_to(
+                fstate.P.reshape(slab[1:])[None], slab), sspec)
+        # the tails that work in delta space read (C, N) rows
+        P_start = (constrain(jnp.broadcast_to(
+            fstate.P[None], (C, layout.padded_size)), pspec)
+            if (is_async or comp is not None or guard_tail) else None)
         S = flat_delta_sgd_init(C, layout, eta0=eta0, theta0=theta0)
         if sharded:
-            S = S._replace(prev_grads=constrain(S.prev_grads, pspec),
+            S = S._replace(prev_grads=constrain(S.prev_grads, sspec),
                            eta=constrain(S.eta, cspec),
                            theta=constrain(S.theta, cspec),
                            prev_grad_norm=constrain(S.prev_grad_norm,
@@ -527,15 +537,15 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                                       0 if prev_local_params is not None
                                       else None)
                 )(params_c, batch_k, gp, prev_local_params)
-            G = constrain(flatlib.pack_batched(g, layout), pspec)
+            G = constrain(flatlib.pack_batched(g, layout), sspec)
             if faults_on and fm.nan_rate > 0.0:
                 # NaN/Inf gradient corruption: from the drawn step on,
                 # the client's packed lanes go non-finite. Injected on
                 # the WIRE side of the guard — the in-step guard must
-                # catch it (valid latches off, η=0, lane sanitized).
+                # catch it (valid latches off, η=0, lane held).
                 bad = k_idx >= lanes.nan_step
-                G = constrain(jnp.where(bad[:, None],
-                                        jnp.float32(jnp.nan), G), pspec)
+                G = constrain(jnp.where(bad[:, None, None],
+                                        jnp.float32(jnp.nan), G), sspec)
             active = (k_idx < budget) if budget is not None else None
             P, S = flat_step(P, G, S, mask, active, eta0_c)
             return (P, S), (l, lm.get("counts", {}))
@@ -547,6 +557,10 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
         # everything past the local steps is the round tail
         with jax.named_scope("round_tail"):
             losses = losses.T  # (K, C) -> (C, K), same layout as vmap engine
+            # (C, N) rows of the round-end slab for the delta-space tails:
+            # one conversion a round, never one a local step
+            P_rows = (constrain(P.reshape(C, layout.padded_size), pspec)
+                      if P_start is not None else None)
 
             extra = _scenario_extras(scenario, fstate.round, C, num_clients,
                                      client_sizes, step_counts)
@@ -594,7 +608,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                                                    compress_flat_sharded)
                 levels = (scenario.draw_compression_levels(fstate.round, C)
                           if bw_hetero else None)
-                delta = P - P_start
+                delta = P_rows - P_start
                 if byz is not None:
                     # byzantine corruption happens CLIENT-side, before the
                     # (honest) compression transport — the server only ever
@@ -647,7 +661,9 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                 # aggregate: single (weighted) mean over the packed client
                 # axis — under the sharded engine XLA lowers this to the
                 # FedAvg all-reduce over the client mesh axes; the (N,)
-                # result keeps the flat-dim sharding.
+                # result keeps the flat-dim sharding. Without compression
+                # the mean runs over the slab itself and its leaves are
+                # read from the (N/128, 128) result as from a slab.
                 if weighted and client_weights is not None:
                     w = client_weights / jnp.sum(client_weights)
                     agg_flat = jnp.tensordot(w.astype(jnp.float32), P_agg,
@@ -656,7 +672,8 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                     agg_flat = _halving_mean(P_agg)
                 else:
                     agg_flat = jnp.mean(P_agg, axis=0)
-                agg = flatlib.unpack(constrain(agg_flat, nspec), layout)
+                agg = flatlib.unpack(constrain(
+                    agg_flat, nspec if agg_flat.ndim == 1 else mspec), layout)
                 new_params, sstate = server_opt.update(gp, agg,
                                                        fstate.server_state)
                 metrics = _round_metrics(losses, S.eta, step_counts)
@@ -674,7 +691,8 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                 # aggregates ever cross the client shard boundary.
                 from repro.federation.faults import (robust_aggregate,
                                                      robust_aggregate_sharded)
-                delta_eff = delta_hat if comp is not None else (P - P_start)
+                delta_eff = (delta_hat if comp is not None
+                             else P_rows - P_start)
                 if byz is not None and comp is None:
                     delta_eff = delta_eff * byz[:, None]
                 w_raw = (client_weights.astype(jnp.float32)
@@ -741,9 +759,8 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                 w = staleness_weights(stale, scenario.staleness_exp)
                 if weighted and client_weights is not None:
                     w = w * client_weights.astype(jnp.float32)
-                delta_flat = jnp.tensordot(
-                    w, delta_hat if comp is not None else (P - P_start),
-                    axes=(0, 0))
+                d = delta_hat if comp is not None else (P_rows - P_start)
+                delta_flat = jnp.tensordot(w, d, axes=(0, 0))
                 delta_tree = flatlib.unpack(constrain(delta_flat, nspec),
                                             layout, cast=False)
                 buf = buffer_merge(fstate.buffer, delta_tree, jnp.sum(w), C,
@@ -779,7 +796,7 @@ def _make_flat_round(grad_fn, client_opt: ClientOpt, server_opt: ServerOpt,
                 w = staleness_weights(stale, scenario.staleness_exp)
                 if weighted and client_weights is not None:
                     w = w * client_weights.astype(jnp.float32)
-                d = delta_hat if comp is not None else (P - P_start)
+                d = delta_hat if comp is not None else (P_rows - P_start)
                 if byz is not None and comp is None:
                     d = d * byz[:, None]
                 if sharded:

@@ -4,7 +4,7 @@ Real cohorts do not run in lockstep — "the computing power of each client
 can greatly vary" is half of the paper's motivation for a tuning-free
 client optimizer. The scenario engine models it as a per-round draw of
 step counts ``K_c ∈ [K_min, K_max]`` per client, lowered onto the round
-engines as **per-step lane masks**: the (C, N) flat buffer keeps its
+engines as **per-step lane masks**: the flat client slab keeps its
 fixed shape through the K_max-step ``lax.scan``, and a client that has
 finished its K_c steps simply rides along with η forced to 0 — its lanes
 are dead but cost no extra kernel launches (the fused apply already takes

@@ -6,10 +6,12 @@ The paper's step size needs two global reductions per local step
 so the update itself is a second pass.
 
 Flat packed layout (the fast path — see ``repro.core.flat``): the whole
-param pytree is ONE lane-aligned f32 buffer and the client axis is the
-leading dim of a dense ``(C, N)`` buffer, ``N = M·128`` with ``M`` an
-exact multiple of the row-block. The kernel pair runs a 2-D grid over
-(client, row-block):
+param pytree is ONE lane-aligned f32 buffer and the client axis leads a
+client slab of shape ``(C, M, 128)``, ``N = M·128`` with ``M`` an exact
+multiple of the row-block. The slab is the kernels' operand form and the
+form the local-step scan carries, so no relayout copy sits between the
+scan and the pair. The kernel pair runs a 2-D grid over (client,
+row-block):
 
   batched_norms  — ONE HBM pass over (G, G_prev) producing BOTH partial
                    sums per block, accumulated across the sequential
@@ -17,9 +19,13 @@ exact multiple of the row-block. The kernel pair runs a 2-D grid over
                    in SMEM (a TPU stores scalars there, not in VMEM).
                    No vmap, no per-leaf loop: the client axis is a grid
                    dimension, so the kernel is vmap-free by construction.
-  batched_apply  — P ← P − η_c·G with per-client η, tiled through VMEM;
-                   P is aliased to the output so the update is in-place.
-                   An optional per-element round mask reproduces the
+  batched_apply  — P ← P − η_c·where(valid_c, G, 0) with per-client η
+                   and validity, tiled through VMEM; P is aliased to the
+                   output so the update is in-place. A lane whose
+                   gradient went non-finite is zeroed here, inside the
+                   kernel (0·NaN is NaN, so η = 0 alone cannot hold P),
+                   and never as a separate pass over the slab. An
+                   optional per-element round mask reproduces the
                    reference path's per-step bf16 rounding for sub-f32
                    leaves packed into the f32 buffer.
 
@@ -64,7 +70,7 @@ def launch_count() -> int:
 
 
 # --------------------------------------------------------------------------
-# packed (C, N) kernels — one launch per op for all leaves and all clients
+# packed (C, M, 128) kernels — one launch per op for all leaves and clients
 # --------------------------------------------------------------------------
 
 # Scalar results live in SMEM as whole (C,) arrays: a TPU cannot store a
@@ -88,45 +94,49 @@ def _batched_norms_kernel(g_ref, gp_ref, dg_ref, gg_ref):
     gg_ref[c] += jnp.sum(g * g)
 
 
-def _batched_apply_kernel(eta_ref, p_ref, g_ref, out_ref):
+def _applied(eta_ref, valid_ref, p_ref, g_ref):
+    """p − η·where(valid, g, 0) in f32 for one (client, row-block)."""
     eta = eta_ref[0, 0, 0]
     p = p_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
-    out_ref[...] = (p - eta * g).astype(out_ref.dtype)
+    g = jnp.where(valid_ref[0, 0, 0] > 0.0, g, jnp.float32(0.0))
+    return p - eta * g
 
 
-def _batched_apply_masked_kernel(eta_ref, p_ref, g_ref, mask_ref, out_ref):
-    eta = eta_ref[0, 0, 0]
-    p = p_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    r = p - eta * g
+def _batched_apply_kernel(eta_ref, valid_ref, p_ref, g_ref, out_ref):
+    out_ref[...] = _applied(eta_ref, valid_ref, p_ref,
+                            g_ref).astype(out_ref.dtype)
+
+
+def _batched_apply_masked_kernel(eta_ref, valid_ref, p_ref, g_ref,
+                                 mask_ref, out_ref):
+    r = _applied(eta_ref, valid_ref, p_ref, g_ref)
     # mask=1 elements belong to bf16 leaves: round exactly like the
     # per-leaf reference's astype(bf16) so flat K-step scans stay on par
     rounded = r.astype(jnp.bfloat16).astype(jnp.float32)
     out_ref[...] = jnp.where(mask_ref[...] > 0.0, rounded, r)
 
 
-def _grid_shapes(n: int):
-    """(M, rows, blocks) for a lane-aligned flat length n (no re-padding:
+def _grid_shapes(slab: jax.Array):
+    """(C, rows, blocks) of a (C, M, 128) client slab (no re-padding:
     FlatLayout guarantees M % rows == 0)."""
-    assert n % LANES == 0, f"flat length {n} not lane-aligned"
-    m = n // LANES
+    assert slab.ndim == 3 and slab.shape[2] == LANES, (
+        f"client slab must be (C, M, {LANES}), got {slab.shape}")
+    C, m, _ = slab.shape
     rows = min(BLOCK_ROWS, m)
-    assert m % rows == 0, f"flat length {n} not row-block aligned"
-    return m, rows, m // rows
+    assert m % rows == 0, f"{m} slab rows not row-block aligned"
+    return C, rows, m // rows
 
 
 def batched_norms(g: jax.Array, g_prev: jax.Array, *,
                   interpret: bool = False):
-    """Per-client (sum((g-gp)^2), sum(g^2)) over packed (C, N) buffers.
+    """Per-client (sum((g-gp)^2), sum(g^2)) over (C, M, 128) client
+    slabs.
 
     ONE pallas launch for all clients and all (packed) leaves; returns a
     pair of (C,) f32 vectors.
     """
-    C, n = g.shape
-    m, rows, blocks = _grid_shapes(n)
-    g3 = g.reshape(C, m, LANES)
-    gp3 = g_prev.reshape(C, m, LANES)
+    C, rows, blocks = _grid_shapes(g)
     LAUNCHES["batched_norms"] += 1
     dg, gg = pl.pallas_call(
         _batched_norms_kernel,
@@ -137,49 +147,48 @@ def batched_norms(g: jax.Array, g_prev: jax.Array, *,
         out_shape=[jax.ShapeDtypeStruct((C,), jnp.float32),
                    jax.ShapeDtypeStruct((C,), jnp.float32)],
         interpret=interpret,
-    )(g3, gp3)
+    )(g, g_prev)
     return dg, gg
 
 
 def batched_apply(p: jax.Array, g: jax.Array, eta: jax.Array, *,
+                  valid: jax.Array | None = None,
                   mask: jax.Array | None = None,
                   interpret: bool = False) -> jax.Array:
-    """P ← P − η_c·G on packed (C, N) buffers with per-client η (C,).
+    """P ← P − η_c·where(valid_c, G, 0) on (C, M, 128) client slabs with
+    per-client η (C,) and validity (C,) bool (None: every lane valid).
 
     ONE pallas launch; P is donated to the output (in-place on TPU).
-    ``mask`` is the optional (N,) round mask from FlatLayout.round_mask.
+    ``mask`` is the optional (M, 128) round mask from
+    FlatLayout.round_mask.
     """
-    C, n = p.shape
-    m, rows, blocks = _grid_shapes(n)
-    p3 = p.reshape(C, m, LANES)
-    g3 = g.reshape(C, m, LANES)
-    eta3 = eta.astype(jnp.float32).reshape(C, 1, 1)
+    C, rows, blocks = _grid_shapes(p)
+    scal = lambda x: x.astype(jnp.float32).reshape(C, 1, 1)
+    eta3 = scal(eta)
+    valid3 = scal(jnp.ones((C,), bool) if valid is None else valid)
     LAUNCHES["batched_apply"] += 1
     common = dict(
         grid=(C, blocks),
         out_specs=pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, m, LANES), p.dtype),
+        out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
         interpret=interpret,
     )
-    eta_spec = pl.BlockSpec((1, 1, 1), lambda c, j: (c, 0, 0))
+    scal_spec = pl.BlockSpec((1, 1, 1), lambda c, j: (c, 0, 0))
     buf_spec = pl.BlockSpec((1, rows, LANES), lambda c, j: (c, j, 0))
     if mask is None:
-        out = pl.pallas_call(
+        return pl.pallas_call(
             _batched_apply_kernel,
-            in_specs=[eta_spec, buf_spec, buf_spec],
-            input_output_aliases={1: 0},
+            in_specs=[scal_spec, scal_spec, buf_spec, buf_spec],
+            input_output_aliases={2: 0},
             **common,
-        )(eta3, p3, g3)
-    else:
-        mask2 = mask.reshape(m, LANES)
-        mask_spec = pl.BlockSpec((rows, LANES), lambda c, j: (j, 0))
-        out = pl.pallas_call(
-            _batched_apply_masked_kernel,
-            in_specs=[eta_spec, buf_spec, buf_spec, mask_spec],
-            input_output_aliases={1: 0},
-            **common,
-        )(eta3, p3, g3, mask2)
-    return out.reshape(C, n)
+        )(eta3, valid3, p, g)
+    mask_spec = pl.BlockSpec((rows, LANES), lambda c, j: (j, 0))
+    return pl.pallas_call(
+        _batched_apply_masked_kernel,
+        in_specs=[scal_spec, scal_spec, buf_spec, buf_spec, mask_spec],
+        input_output_aliases={2: 0},
+        **common,
+    )(eta3, valid3, p, g, mask)
 
 
 # --------------------------------------------------------------------------

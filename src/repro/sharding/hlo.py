@@ -1,14 +1,15 @@
 """Post-SPMD HLO inspection for sharding assertions.
 
 After SPMD partitioning, every instruction in ``compiled.as_text()``
-carries PER-DEVICE (local) shapes. If the packed flat (C, N) buffer of
-the sharded flat engine (core/flat.py + FederationSpec.flat_spec) is
-kept sharded end to end, its full global shape can never appear in the
-compiled module — any ``f32[C,N]`` hit means some op (an all-gather, a
-resharding copy, a rematerialized concatenate) rebuilt the unsharded
-buffer on one device. ``flat_buffer_report`` counts those hits, which
-is the machine-checkable form of the ROADMAP open item "the packed
-(C, N) buffer stays client-sharded end to end".
+carries PER-DEVICE (local) shapes. If the client buffer of the sharded
+flat engine (core/flat.py + FederationSpec.flat_spec) is kept sharded
+end to end, its full global shape can never appear in the compiled
+module, in either of its forms — any ``f32[C,N]`` or
+``f32[C,N/128,128]`` (the client slab) hit means some op (an
+all-gather, a resharding copy, a rematerialized concatenate) rebuilt
+the unsharded buffer on one device. ``flat_buffer_report`` counts those
+hits, which is the machine-checkable form of the ROADMAP open item "the
+packed (C, N) buffer stays client-sharded end to end".
 """
 from __future__ import annotations
 
@@ -25,14 +26,17 @@ def full_shape_lines(hlo_text: str, shape: Sequence[int],
 
 
 def flat_buffer_report(hlo_text: str, C: int, N: int) -> Dict:
-    """Count involuntary rematerializations of the packed (C, N) buffer.
+    """Count involuntary rematerializations of the packed client buffer.
 
-    Returns {"full_shape": #lines with the global f32[C,N] shape,
+    Returns {"full_shape": #lines with the global f32[C,N] or
+             f32[C,N/128,128] shape,
              "gather_or_copy": #those lines that are all-gather/copy ops,
              "sample": first few offending lines}. A sharded round must
     report full_shape == 0 (the replicated engine reports dozens).
     """
-    lines = full_shape_lines(hlo_text, (C, N))
+    full = set(full_shape_lines(hlo_text, (C, N)))
+    full |= set(full_shape_lines(hlo_text, (C, N // 128, 128)))
+    lines = [ln for ln in hlo_text.splitlines() if ln in full]
     bad = [ln for ln in lines
            if "all-gather" in ln or re.search(r"\bcopy\(", ln)]
     return {"full_shape": len(lines), "gather_or_copy": len(bad),

@@ -176,11 +176,13 @@ def test_flat_step_hetero_matches_literal_kc_reference(backend, rng):
         for _ in range(K)] for _ in range(C)]
     ref_params, ref_etas = _literal_reference(tree, grad_seq, step_counts)
 
-    P = jnp.stack([fp.pack(tree, layout)] * C)
+    slab = fp.slab_shape(C, layout)
+    P = jnp.stack([fp.pack(tree, layout)] * C).reshape(slab)
     S = flat_delta_sgd_init(C, layout, eta0=ETA0, theta0=THETA0)
     sc = jnp.asarray(step_counts, jnp.int32)
     for k in range(K):
-        G = jnp.stack([fp.pack(grad_seq[c][k], layout) for c in range(C)])
+        G = jnp.stack([fp.pack(grad_seq[c][k], layout)
+                       for c in range(C)]).reshape(slab)
         P, S = flat_delta_sgd_step(
             P, G, S, gamma=GAMMA, delta=DELTA, eta0=ETA0, mask=mask,
             active=(k < sc), backend=backend,

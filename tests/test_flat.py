@@ -48,7 +48,14 @@ def test_pack_unpack_batched_roundtrip(rng):
             "b": jnp.asarray(rng.normal(size=(C, 40)), jnp.bfloat16)}
     layout = fp.layout_of(tree, batched=True)
     buf = fp.pack_batched(tree, layout)
-    assert buf.shape == (C, layout.padded_size)
+    assert buf.shape == fp.slab_shape(C, layout)
+    assert buf.shape == (C, layout.padded_size // fp.LANES, fp.LANES)
+    # every element sits at its flat offset: row r, lane l holds element
+    # r·128 + l of the client's (N,) buffer
+    one = fp.layout_of(jax.tree.map(lambda x: x[0], tree))
+    want = np.stack([np.asarray(fp.pack(jax.tree.map(lambda x: x[c], tree),
+                                        one)) for c in range(C)])
+    np.testing.assert_array_equal(np.asarray(buf).reshape(C, -1), want)
     back = fp.unpack_batched(buf, layout)
     for k in tree:
         assert back[k].shape == tree[k].shape
@@ -98,15 +105,50 @@ def test_batched_apply_per_client_eta_and_mask(rng):
     g = p * 0.2 + 0.05
     eta = jnp.asarray([0.1, 0.5, 1.3], jnp.float32)
     mask = fp.round_mask(layout)
+    assert mask.shape == p.shape[1:]
     out = dk.batched_apply(p, g, eta, mask=mask, interpret=True)
     ref = dref.batched_apply_ref(p, g, eta, mask)
+    assert out.shape == p.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
     # masked segments are exactly bf16-representable
     seg = fp.unpack_batched(out, layout)["a"]
+    rows = out.reshape(C, -1)
     np.testing.assert_array_equal(
-        np.asarray(out[:, :200].astype(jnp.bfloat16), np.float32),
+        np.asarray(rows[:, :200].astype(jnp.bfloat16), np.float32),
         np.asarray(seg, np.float32))
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_batched_apply_invalid_lane_holds_p(masked, rng):
+    """A lane whose ``valid`` is off keeps its P bit for bit even with
+    NaN in its G (the zeroing happens inside the kernel); every other
+    lane is p − η·g bit for bit, as with no flag at all."""
+    C = 4
+    tree = {"a": jnp.asarray(rng.normal(size=(C, 300)), jnp.bfloat16),
+            "b": jnp.asarray(rng.normal(size=(C, 77)), jnp.float32)}
+    layout = fp.layout_of(tree, batched=True)
+    p = fp.pack_batched(tree, layout)
+    g = p * 0.2 + 0.05
+    g = g.at[2].set(jnp.nan)
+    eta = jnp.asarray([0.1, 0.5, 0.0, 1.3], jnp.float32)
+    valid = jnp.asarray([True, True, False, True])
+    mask = fp.round_mask(layout) if masked else None
+    out = dk.batched_apply(p, g, eta, valid=valid, mask=mask,
+                           interpret=True)
+    ref = jax.jit(lambda p, g, e, v: dref.batched_apply_ref(
+        p, g, e, mask, valid=v))(p, g, eta, valid)
+    np.testing.assert_array_equal(np.asarray(out[2]), np.asarray(p[2]))
+    np.testing.assert_array_equal(np.asarray(ref[2]), np.asarray(p[2]))
+    # p − η·g as the platform computes it, on a gradient with no NaN
+    g_clean = jnp.nan_to_num(g)
+    want = jax.jit(lambda p, g, e: dref.batched_apply_ref(p, g, e, mask))(
+        p, g_clean, eta)
+    no_flag = dk.batched_apply(p, g_clean, eta, mask=mask, interpret=True)
+    healthy = [0, 1, 3]
+    for got in (out, ref, no_flag):
+        np.testing.assert_array_equal(np.asarray(got)[healthy],
+                                      np.asarray(want)[healthy])
 
 
 # -------------------------------------------------- full-round parity oracle
@@ -138,13 +180,15 @@ def test_flat_step_matches_oracle_multi_round_mixed_dtype(rng):
         ref_params.append(p)
         ref_etas.append(float(s.eta))
 
-    # flat engine: one (C, N) buffer, two launches per step
-    P = jnp.stack([fp.pack(tree, layout)] * C)
+    # flat engine: one (C, N/128, 128) slab, two launches per step
+    slab = fp.slab_shape(C, layout)
+    P = jnp.stack([fp.pack(tree, layout)] * C).reshape(slab)
     for r in range(R):
         S = flat_delta_sgd_init(C, layout, eta0=ETA0, theta0=THETA0)
+        assert S.prev_grads.shape == slab
         for k in range(K):
             G = jnp.stack([fp.pack(grad_seq[c][k], layout)
-                           for c in range(C)])
+                           for c in range(C)]).reshape(slab)
             P, S = flat_delta_sgd_step(P, G, S, gamma=GAMMA, delta=DELTA,
                                        eta0=ETA0, mask=mask,
                                        backend="pallas", interpret=True)
@@ -316,8 +360,10 @@ def test_sharded_step_matches_replicated_flat(backend, rng):
     tree = _mixed_tree(rng)
     lay_s = fp.layout_of(tree, shards=spec.flat_shards(mesh))
     lay_r = fp.layout_of(tree)
-    Ps = jnp.stack([fp.pack(tree, lay_s)] * C)
-    Pr = jnp.stack([fp.pack(tree, lay_r)] * C)
+    Ps = jnp.stack([fp.pack(tree, lay_s)] * C).reshape(
+        fp.slab_shape(C, lay_s))
+    Pr = jnp.stack([fp.pack(tree, lay_r)] * C).reshape(
+        fp.slab_shape(C, lay_r))
     Ss = flat_delta_sgd_init(C, lay_s, eta0=ETA0, theta0=THETA0)
     Sr = flat_delta_sgd_init(C, lay_r, eta0=ETA0, theta0=THETA0)
     kw = dict(gamma=GAMMA, delta=DELTA, eta0=ETA0)
@@ -526,8 +572,9 @@ def test_pack_unpack_batched_roundtrip_property(sizes, bf16_mask, shards,
     tree = _prop_tree(sizes, bf16_mask, cdim=cdim)
     layout = fp.layout_of(tree, batched=True, shards=shards)
     buf = fp.pack_batched(tree, layout)
-    assert buf.shape == (cdim, layout.padded_size)
-    assert float(jnp.sum(jnp.abs(buf[:, layout.size:]))) == 0.0
+    assert buf.shape == fp.slab_shape(cdim, layout)
+    rows = buf.reshape(cdim, -1)
+    assert float(jnp.sum(jnp.abs(rows[:, layout.size:]))) == 0.0
     back = fp.unpack_batched(buf, layout)
     raw = fp.unpack_batched(buf, layout, cast=False)
     for k in tree:

@@ -14,6 +14,7 @@ The persistent compilation cache is off around these compiles (an entry
 written for a described chip cannot be read back without one).
 """
 import os
+import re
 import sys
 
 import jax
@@ -69,6 +70,11 @@ def tpu_compile(topo):
 
 def _sds(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+
+def _slab(C, N):
+    """The Δ-SGD pair's operand shape: the (C, N/128, 128) client slab."""
+    return (C, N // 128, 128)
 
 
 def _padded_n(params_like):
@@ -133,16 +139,18 @@ def _kernel_cases():
     return {
         "delta_sgd.batched_norms@whisper": (
             lambda g, gp: dk.batched_norms(g, gp, **ip),
-            _sds((Cw, Nw)), _sds((Cw, Nw))),
+            _sds(_slab(Cw, Nw)), _sds(_slab(Cw, Nw))),
         "delta_sgd.batched_apply@whisper": (
-            lambda p, g, e: dk.batched_apply(p, g, e, **ip),
-            _sds((Cw, Nw)), _sds((Cw, Nw)), _sds((Cw,))),
+            lambda p, g, e, v: dk.batched_apply(p, g, e, valid=v, **ip),
+            _sds(_slab(Cw, Nw)), _sds(_slab(Cw, Nw)), _sds((Cw,)),
+            _sds((Cw,), jnp.bool_)),
         "delta_sgd.batched_norms@fleet": (
             lambda g, gp: dk.batched_norms(g, gp, **ip),
-            _sds((Cf, Nf)), _sds((Cf, Nf))),
+            _sds(_slab(Cf, Nf)), _sds(_slab(Cf, Nf))),
         "delta_sgd.batched_apply@fleet": (
-            lambda p, g, e: dk.batched_apply(p, g, e, **ip),
-            _sds((Cf, Nf)), _sds((Cf, Nf)), _sds((Cf,))),
+            lambda p, g, e, v: dk.batched_apply(p, g, e, valid=v, **ip),
+            _sds(_slab(Cf, Nf)), _sds(_slab(Cf, Nf)), _sds((Cf,)),
+            _sds((Cf,), jnp.bool_)),
         "delta_sgd.norms@whisper": (
             lambda g, gp: dk.norms(g, gp, **ip), _sds((Nw,)), _sds((Nw,))),
         "delta_sgd.apply_update@whisper": (
@@ -201,10 +209,12 @@ def test_kernel_compiles_for_v5e(name, tpu_compile):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_whisper_fused_block_fits_v5e(tpu_compile):
+@pytest.fixture(scope="module")
+def whisper_block(tpu_compile):
     """The lm phase's fused block — R rounds of C clients × K local
     steps of whisper-tiny at its published widths, Δ-SGD kernel pair
-    compiled in — is one program that fits the chip's HBM."""
+    compiled in — compiled once for both tests below. Returns
+    (compiled, C, padded N)."""
     from repro.core import (flatten_fl_state, get_client_opt,
                             get_server_opt, init_fl_state, make_fl_loop,
                             make_loss)
@@ -223,9 +233,55 @@ def test_whisper_fused_block_fits_v5e(tpu_compile):
             "labels": _sds(lead + (a.seq,), jnp.int32),
             "frames": _sds(lead + (cfg.encoder_seq, cfg.d_model))}
     compiled = tpu_compile(loop, fst, data, donate_argnums=0)
+    return compiled, a.clients_per_round, loop.layout.padded_size
+
+
+def test_whisper_fused_block_fits_v5e(whisper_block):
+    """The fused block is one program that fits the chip's HBM."""
+    compiled, _, _ = whisper_block
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     print(mem)
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < V5E_HBM, total
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([0-9,]*)\]\S* "
+                    r"([\w-]+)\(")
+
+
+def test_whisper_fused_block_keeps_the_slab_layout(whisper_block):
+    """The local-step scan carries the client slabs in the Δ-SGD pair's
+    own (C, N/128, 128) form: the optimized HLO holds no copy, select
+    or fusion in the ``delta_sgd`` scope that writes a whole client
+    buffer (a relayout between a (C, N) form and the pair's, or a
+    valid-lane select beside the pair), and the pair's two custom calls
+    take the slab itself. Interpret mode cannot see either: only the
+    TPU compiler's layouts make a reshape a copy."""
+    compiled, C, N = whisper_block
+    txt = compiled.as_text()
+    slab = f"f32[{C},{N // 128},128]"
+    passes, pair = [], []
+    for ln in txt.splitlines():
+        scope = re.search(r'op_name="([^"]*)"', ln)
+        if not scope or "/delta_sgd/" not in scope.group(1):
+            continue
+        if 'custom_call_target="tpu_custom_call"' in ln:
+            pair.append(ln)
+            continue
+        m = _INSTR.match(ln)
+        if not m:
+            continue
+        name, _, dims, op = m.groups()
+        elems = 1
+        for d in filter(None, dims.split(",")):
+            elems *= int(d)
+        if elems == C * N and op in ("copy", "select", "fusion"):
+            passes.append(name)
+    assert not passes, passes
+    assert len(pair) == 2, [ln.strip()[:120] for ln in pair]
+    for ln in pair:
+        operands = ln.split("operand_layout_constraints=", 1)[1]
+        operands = operands.split("frontend_attributes", 1)[0]
+        assert operands.count(slab) == 2, (slab, operands)
