@@ -101,7 +101,9 @@ def test_fault_and_robust_specs_validated():
 def test_nan_guard_latches_and_freezes_lane(backend, rng):
     """A non-finite gradient drops the lane (η=0, params untouched),
     latches ``valid`` off for the rest of the round, and never leaks NaN
-    into the packed buffer or the rolled prev_grads."""
+    into the client slab or the lane's scalar state (the rolled
+    prev_grads keep the raw gradient, which the latched lane never
+    applies)."""
     params = {"x": jnp.asarray(rng.normal(size=40), jnp.float32)}
     layout = fp.layout_of(params)
     C = 4
